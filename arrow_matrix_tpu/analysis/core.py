@@ -138,7 +138,7 @@ def _waived(f: Finding, per_line: dict, file_level) -> bool:
 #: Wrappers whose function-valued arguments execute under a JAX trace.
 TRACE_WRAPPERS = frozenset({
     "jax.jit", "jax.pjit", "jax.experimental.pjit.pjit",
-    "jax.shard_map", "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "jax.vmap", "jax.pmap", "jax.grad", "jax.value_and_grad",
     "jax.checkpoint", "jax.remat", "jax.eval_shape", "jax.make_jaxpr",
     "jax.lax.scan", "jax.lax.fori_loop", "jax.lax.while_loop",
@@ -201,9 +201,7 @@ class ModuleContext:
             return None
         head, _, rest = d.partition(".")
         full = self.aliases.get(head, head) + (("." + rest) if rest else "")
-        for src, dst in (("jax.experimental.shard_map.shard_map",
-                          "jax.shard_map"),
-                         ("jax.experimental.pjit.pjit", "jax.pjit"),
+        for src, dst in (("jax.experimental.pjit.pjit", "jax.pjit"),
                          ("jax.ad_checkpoint.checkpoint", "jax.checkpoint")):
             if full == src:
                 full = dst

@@ -18,7 +18,7 @@ over them:
   witness in which every slot points at the LAST feature row;
 * **KC2** the sum of double-buffered VMEM BlockSpec blocks plus
   ``scratch_shapes`` fits the declared VMEM budget, and the
-  scalar-prefetch bytes fit the SMEM budget, statically per
+  SMEM column-block bytes fit the SMEM budget, statically per
   (row_block, ring, k) point;
 * **KC3** DMA ring discipline — extracted from the builder source by
   AST (the ``copy``/``issue``/``wait`` schedule convention of
@@ -281,7 +281,7 @@ def check_meta(meta: dict) -> List[Finding]:
         sbytes = int(smem.get("bytes", 0))
         sbudget = int(smem["budget"])
         if sbytes > sbudget and not smem.get("single_block"):
-            fail("KC2", f"scalar-prefetch bytes {sbytes} exceed the "
+            fail("KC2", f"SMEM column bytes {sbytes} exceed the "
                         f"SMEM budget {sbudget} and the slab is not "
                         f"already minimal")
 
@@ -766,7 +766,8 @@ def certify_candidate_opts(kernel_opts: Optional[dict], k: int, *,
     stream = not interpret
     if stream and not cc.supports_k(k):
         return (f"kcert: streaming pallas_sell needs k % "
-                f"{cc.stream_k_multiple} == 0 on chip (k={k})")
+                f"{cc.stream_k_multiple} == 0 and k | {cc.line_k} on "
+                f"chip (k={k})")
     if feature_dtype is None:
         feature_dtype = opts.get("feature_dtype")
     try:
@@ -780,15 +781,15 @@ def certify_candidate_opts(kernel_opts: Optional[dict], k: int, *,
     def _point(rb, wave, ring, budget, pt_carriage, pt_m_t):
         # Mimic the runtime's rb/wave normalization; ring and budgets
         # are taken literally (they are what the plan executes with).
-        rb = max(cc.granule, int(rb) - int(rb) % cc.granule)
+        rb = ps._tier_row_block(1 << 30, int(rb),
+                                ps.out_rows_per_line(k, pt_carriage))
         w = min(int(wave), rb)
         while w > 1 and rb % w:
             w -= 1
         try:
             meta = ps.slab_call_meta(
                 pt_m_t, ps.slab_rows(pt_m_t, rb, budget), k, rb, True,
-                stream, w, int(ring), carriage=pt_carriage,
-                smem_cols_budget=budget)
+                stream, w, int(ring), carriage=pt_carriage)
         except (ValueError, ZeroDivisionError) as exc:
             return f"kcert: {exc}"
         findings = check_meta(meta)

@@ -34,9 +34,9 @@ def build_parser() -> argparse.ArgumentParser:
                              "(the reference hardcodes 10, "
                              "decomposition_main.py:184).")
     parser.add_argument("--block_diagonal", type=str2bool, nargs="?",
-                        default=True,
+                        default=True, const=True,
                         help="Block-diagonal (vs banded) edge criterion.")
-    parser.add_argument("--directed", type=str2bool, nargs="?", default=False,
+    parser.add_argument("--directed", type=str2bool, nargs="?", default=False, const=True,
                         help="Accepted for reference flag parity; the "
                              "decomposer handles asymmetric inputs "
                              "automatically (structural symmetrization "
@@ -44,18 +44,18 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--seed", type=int, default=0,
                         help="Linearization RNG seed.")
     parser.add_argument("--visualize", type=str2bool, nargs="?",
-                        default=False,
+                        default=False, const=True,
                         help="Save a spy plot of each level "
                              "(decomposition_main.py:83-106).")
     parser.add_argument("--save_input_graph", type=str2bool, nargs="?",
-                        default=False,
+                        default=False, const=True,
                         help="Pickle the parsed input graph next to the "
                              "artifact to skip re-parsing "
                              "(decomposition_main.py:157-162).")
     parser.add_argument("--out_dir", type=str, default=None,
                         help="Output directory (default: dataset_dir).")
     parser.add_argument("--band_detect", type=str2bool, nargs="?",
-                        default=True,
+                        default=True, const=True,
                         help="Detect banded/bandable inputs (identity "
                              "or RCM order) and emit ONE level with "
                              "zero routing; false restores the plain "

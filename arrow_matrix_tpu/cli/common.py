@@ -33,7 +33,8 @@ def add_device_args(parser: argparse.ArgumentParser) -> None:
         "-i", "--device", type=str, default="auto",
         choices=["auto", "cpu", "tpu"],
         help="Compute platform (the reference's cpu/gpu gate, "
-             "spmm_arrow_main.py:18; 'auto' uses the default backend).")
+             "spmm_arrow_main.py:18; 'auto' uses the default backend, "
+             "the TPU where one is attached).")
     parser.add_argument(
         "--devices", type=int, default=0,
         help="Force an N-device virtual CPU platform (multi-chip layouts "
@@ -60,11 +61,18 @@ def add_distributed_args(parser: argparse.ArgumentParser) -> None:
 def setup_platform(args: argparse.Namespace) -> None:
     """Pin the JAX platform per --device/--devices, and join the
     multi-process runtime when --coordinator is given (must run before
-    anything initializes a JAX backend)."""
-    from arrow_matrix_tpu.utils.platform import force_cpu_devices
+    anything initializes a JAX backend).  Runs that may reach the chip
+    share the persistent compile cache; CPU-pinned runs (tests,
+    rehearsals) compile fresh."""
+    from arrow_matrix_tpu.utils.platform import (
+        enable_compile_cache,
+        force_cpu_devices,
+    )
 
     coordinator = getattr(args, "coordinator", None)
     cpu = args.device == "cpu" or args.devices > 0
+    if not cpu:
+        enable_compile_cache()
     if coordinator is not None:
         from arrow_matrix_tpu.parallel.mesh import initialize_multihost
 
@@ -76,18 +84,19 @@ def setup_platform(args: argparse.Namespace) -> None:
             force_cpu_devices(args.devices if args.devices > 0 else None)
             jax.config.update("jax_cpu_collectives_implementation",
                               "gloo")
-        elif args.device == "tpu":
-            # Same platform pin as the single-process path: with
-            # multiple registered PJRT plugins the default priority
-            # may initialize the wrong backend.
-            os.environ.setdefault("JAX_PLATFORMS", "tpu")
         initialize_multihost(coordinator, args.num_processes,
                              args.process_id)
-        return
-    if cpu:
+    elif cpu:
         force_cpu_devices(args.devices if args.devices > 0 else None)
-    elif args.device == "tpu":
-        os.environ.setdefault("JAX_PLATFORMS", "tpu")
+    if args.device == "tpu":
+        # An explicit TPU request is a requirement, not a preference:
+        # never run it on whatever backend came up instead.
+        import jax
+
+        found = jax.devices()[0].platform
+        if found != "tpu":
+            raise SystemExit(f"--device tpu but JAX's backend is "
+                             f"{found!r}: no TPU here")
 
 
 def add_heal_args(parser: argparse.ArgumentParser,

@@ -53,7 +53,7 @@ def build_parser() -> argparse.ArgumentParser:
                         help="Barabasi-Albert attachment count "
                              "(spmm_arrow_main.py:22).")
     parser.add_argument("-s", "--slim", type=str2bool, nargs="?",
-                        default=True,
+                        default=True, const=True,
                         help="Layout (reference spmm_arrow_main.py:25-26): "
                              "true = slim (one block-row group per "
                              "device, the default); false = wide (the "
@@ -173,7 +173,7 @@ def build_parser() -> argparse.ArgumentParser:
                              "graphio.py:283-294 + streaming "
                              "distribution arrow_dec_mpi.py:629-887).")
     parser.add_argument("--validate", type=str2bool, nargs="?",
-                        default=False,
+                        default=False, const=True,
                         help="Compare each iteration against the host "
                              "scipy golden (spmm_15d_main.py --validate "
                              "analog).")
@@ -287,7 +287,9 @@ def main(argv=None) -> int:
     import jax
 
     from arrow_matrix_tpu.decomposition import arrow_decomposition
-    from arrow_matrix_tpu.decomposition.decompose import decomposition_spmm
+    from arrow_matrix_tpu.decomposition.decompose import (
+        decomposition_matrix,
+    )
     from arrow_matrix_tpu.io import (
         as_levels,
         load_decomposition,
@@ -406,6 +408,10 @@ def main(argv=None) -> int:
     # golden (a >RAM run validates offline instead).
     golden_levels = (as_levels(loaded, widths)
                      if args.memmap and args.validate else levels)
+    # The validation golden: the recomposed operator as one scipy CSR
+    # (one product per iteration; equal to decomposition_spmm).
+    golden = (decomposition_matrix(golden_levels) if args.validate
+              else None)
     from arrow_matrix_tpu.io.graphio import num_rows
 
     n = num_rows(levels[0].matrix)
@@ -549,7 +555,8 @@ def main(argv=None) -> int:
     # spmm_time (the sibling baseline CLIs warm up the same way).
     warm = multi.set_features(
         graphs.random_dense(n, args.features, seed=args.seed))
-    jax.block_until_ready(multi.step(warm))
+    with wb.segment("first_call_time"):
+        jax.block_until_ready(multi.step(warm))
 
     from arrow_matrix_tpu import obs
 
@@ -608,6 +615,13 @@ def main(argv=None) -> int:
                 multi, args.features, itemsize=itemsize),
             registry=obs_reg)
         print(obs.format_memory_report(mem))
+        print(obs.format_placement(multi.step_operands()))
+        if hasattr(multi, "gather_budget"):
+            from arrow_matrix_tpu.parallel.sell_slim import (
+                format_tier_chunks,
+            )
+
+            print(format_tier_chunks(multi, args.features, itemsize))
         imb = obs.account_imbalance("spmm_arrow", multi,
                                     registry=obs_reg)
         if imb is not None:
@@ -665,7 +679,7 @@ def main(argv=None) -> int:
             from arrow_matrix_tpu.utils import numerics
 
             got = multi.gather_result(y)
-            want = decomposition_spmm(golden_levels, x_host)
+            want = golden @ x_host
             err = numerics.relative_error(got, want)
             # One step separates the compared states (X is fresh per
             # iteration); tolerance per the documented accumulation-

@@ -67,8 +67,8 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("-e", "--edges", type=int, default=1_000_000)
     parser.add_argument("-c", "--columns", type=int, default=32)
     parser.add_argument("-z", "--iterations", type=int, default=3)
-    parser.add_argument("--validate", type=str2bool, nargs="?", default=True)
-    parser.add_argument("--dryrun", type=str2bool, nargs="?", default=False,
+    parser.add_argument("--validate", type=str2bool, nargs="?", default=True, const=True)
+    parser.add_argument("--dryrun", type=str2bool, nargs="?", default=False, const=True,
                         help="Build the exchange tables, print their "
                              "stats, skip the benchmark.")
     parser.add_argument("-m", "--memory", type=float, default=0.5,

@@ -2,6 +2,7 @@ from arrow_matrix_tpu.decomposition.decompose import (
     ArrowLevel,
     achieved_width,
     arrow_decomposition,
+    decomposition_matrix,
     decomposition_spmm,
     reconstruct,
 )
@@ -12,6 +13,7 @@ __all__ = [
     "ArrowLevel",
     "achieved_width",
     "arrow_decomposition",
+    "decomposition_matrix",
     "decomposition_spmm",
     "reconstruct",
     "bfs_order",

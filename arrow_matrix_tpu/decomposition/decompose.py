@@ -394,6 +394,28 @@ def reconstruct(levels: list[ArrowLevel]) -> sparse.csr_matrix:
     return total.tocsr()
 
 
+def decomposition_matrix(levels: list[ArrowLevel]) -> sparse.csr_matrix:
+    """The operator a decomposition represents, recomposed in original
+    row order: entry (a, b) of level i's matrix lands at
+    ``(sigma_i[a], sigma_i[b])``.  ``decomposition_matrix(levels) @ X``
+    equals :func:`decomposition_spmm` (up to f32 summation order) at
+    one CSR product per call instead of two row gathers per level — the
+    validation golden at 2^22 rows."""
+    n = levels[0].matrix.shape[0]
+    rows, cols, vals = [], [], []
+    for lvl in levels:
+        coo = sparse.coo_matrix(lvl.matrix)
+        perm = np.asarray(lvl.permutation)
+        rows.append(perm[coo.row])
+        cols.append(perm[coo.col])
+        vals.append(coo.data)
+    a = sparse.coo_matrix((np.concatenate(vals),
+                           (np.concatenate(rows), np.concatenate(cols))),
+                          shape=(n, n)).tocsr()
+    a.sum_duplicates()
+    return a
+
+
 def decomposition_spmm(levels: list[ArrowLevel], x: np.ndarray) -> np.ndarray:
     """Golden host-side SpMM through the decomposition:
     ``A @ X = sum_i (B_i @ X[sigma_i])[inv sigma_i]``

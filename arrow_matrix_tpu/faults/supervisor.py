@@ -7,8 +7,7 @@ validation, metrics), while the supervisor owns everything the paper's
 
   * a per-iteration **watchdog** (``watchdog_s``): the body runs on a
     worker thread and a stalled iteration raises
-    :class:`WatchdogTimeout` instead of wedging the run forever — the
-    in-process analog of tools/tunnel_watcher.py's job-level timeout;
+    :class:`WatchdogTimeout` instead of wedging the run forever;
   * **bounded retry with exponential backoff**: transient failures
     (device errors, injected faults) re-run the same iteration from
     its entry state; ``max_retries`` consecutive failures end the run

@@ -186,7 +186,14 @@ def spawn_worker(worker_id: str, *, vertices: int, width: int,
 
     log_path = (os.path.join(obs_dir, "worker.log")
                 if obs_dir else os.devnull)
-    log_fh = open(log_path, "w", encoding="utf-8")
+    if log_path != os.devnull:
+        open(log_path, "w").close()    # a fresh log per spawn
+    # Append mode for the child's stderr: the drained stdout lines are
+    # appended by this process through another handle, and a plain "w"
+    # fd would write from its own offset over them (a stderr warning
+    # erased the survivor's "resumed request" line — the doctor's
+    # graft-host probe then reported a recompute that never happened).
+    log_fh = open(log_path, "a", encoding="utf-8")
     proc = subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
                             stderr=log_fh, text=True)
     log_fh.close()   # the child holds the stderr fd now

@@ -299,13 +299,18 @@ def serve_worker(worker: FleetWorker, *, host: str = "127.0.0.1",
                 msg = wire.recv_msg(self.request, role="server")
             except (OSError, wire.WireError):
                 return
+            if done.is_set():
+                # Shut down: the listener may still accept until the
+                # serve loop stops, but nothing is served — the caller
+                # sees a dead worker and requeues.
+                return
             if isinstance(msg, dict) and msg.get("op") == "shutdown":
+                done.set()
                 reply = {"ok": True, "worker_id": worker.worker_id}
                 try:
                     wire.send_msg(self.request, reply, role="server")
                 except (OSError, wire.WireError):
                     pass
-                done.set()
                 return
             reply = worker.handle(msg)
             # Mirror the transport the router asked for: shm replies

@@ -79,6 +79,7 @@ from arrow_matrix_tpu.obs.imbalance import (
 from arrow_matrix_tpu.obs.memview import (
     account_memory,
     format_memory_report,
+    format_placement,
     memory_report,
     predicted_bytes_for,
     tree_device_bytes,
@@ -144,6 +145,7 @@ __all__ = [
     "fit_from_profile",
     "format_imbalance_report",
     "format_memory_report",
+    "format_placement",
     "get_registry",
     "hbm_budget_bytes",
     "ideal_bytes_for",

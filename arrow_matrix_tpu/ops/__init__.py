@@ -20,21 +20,11 @@ from arrow_matrix_tpu.ops.sell import (
     sell_from_csr,
     sell_spmm_t,
 )
-# Pallas is optional: JAX builds without pallas/tpu support must still
-# import the (default, XLA-path) ops package.
-try:
-    from arrow_matrix_tpu.ops.pallas_blocks import (
-        arrow_spmm_pallas,
-        column_spmm_pallas,
-        head_spmm_pallas,
-    )
-except ImportError as _pallas_err:  # pragma: no cover - env dependent
-    _msg = f"pallas kernels unavailable: {_pallas_err}"
-
-    def _unavailable(*_a, **_k):
-        raise RuntimeError(_msg)
-
-    arrow_spmm_pallas = column_spmm_pallas = head_spmm_pallas = _unavailable
+from arrow_matrix_tpu.ops.pallas_blocks import (
+    arrow_spmm_pallas,
+    column_spmm_pallas,
+    head_spmm_pallas,
+)
 
 __all__ = [
     "csr_flat_pack",

@@ -114,6 +114,28 @@ def ell_pack_stack(mats: list[sparse.spmatrix], dtype=np.float32,
     return cols, data
 
 
+def feature_major_chunk(rows: int, k: int, m: int, budget_bytes: int,
+                        itemsize: int = 4) -> Optional[int]:
+    """Slot chunk bounding the feature-major ``(k, chunk, rows)`` gather
+    intermediate of :func:`ell_spmm_t` to ``budget_bytes``; ``None``
+    when the whole slot axis fits.  The chunk axis is second-minor, so
+    TPU pads it to a whole sublane tile (SLOT_ALIGN): chunks below it
+    cost a full tile and are replaced by single-slot 2-D gathers
+    (chunk 1), the only bound left when one tile of slots is already
+    over budget (a 2^22-row tier at k=128: 9.7 GB per tile).
+    """
+    if m == 0 or rows <= 0 or k <= 0:
+        return None
+    per_slot = k * rows * itemsize
+    if align_up(m, SLOT_ALIGN) * per_slot <= budget_bytes:
+        return None
+    c = int(budget_bytes // per_slot)
+    c -= c % SLOT_ALIGN
+    if c < SLOT_ALIGN:
+        return 1
+    return None if c >= m else c
+
+
 def auto_chunk(rows: int, k: int, m: int, budget_bytes: int,
                itemsize: int = 4,
                lanes: Optional[int] = None) -> Optional[int]:
@@ -264,6 +286,12 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
             data = jnp.pad(data, ((0, pad), (0, 0)))
 
     def contribution(cols_c, w_c):
+        if c == 1:
+            # One slot per step: a 2-D (k, rows) gather.  A (k, 1, rows)
+            # intermediate would pad its slot axis to a whole sublane
+            # tile on TPU — 8x the bytes this chunking exists to bound.
+            g = jnp.take(x_t, cols_c[0], axis=1)
+            return (g * w_c[0][None]).astype(jnp.float32)
         g = jnp.take(x_t, cols_c.reshape(-1), axis=1)
         g = g.reshape(k, c, rows)
         # f32 accumulation whatever the carried feature dtype: bf16

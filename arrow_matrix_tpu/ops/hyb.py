@@ -42,7 +42,6 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
-from arrow_matrix_tpu.utils.transfer import chunked_asarray
 import numpy as np
 from flax import struct
 from scipy import sparse
@@ -194,18 +193,18 @@ def hyb_from_csr(matrix: CsrLike, pad_rows_to: Optional[int] = None,
         heavy_deg = np.zeros((0,), dtype=np.int32) if is_binary else None
 
     def dev(a):
-        return None if a is None else chunked_asarray(a)
+        return None if a is None else jnp.asarray(a)
 
     if is_binary:
         light_pad = np.zeros(total - n, dtype=np.int32)
         light_deg = np.concatenate([light_deg, light_pad])
 
     return HybLevel(
-        light_cols=chunked_asarray(light_cols),
+        light_cols=jnp.asarray(light_cols),
         light_data=dev(light_data),
         light_deg=dev(light_deg),
         heavy_idx=jnp.asarray(heavy_rows.astype(np.int32)),
-        heavy_cols=chunked_asarray(heavy_cols),
+        heavy_cols=jnp.asarray(heavy_cols),
         heavy_data=dev(heavy_data),
         heavy_deg=dev(heavy_deg),
         n_rows=total)

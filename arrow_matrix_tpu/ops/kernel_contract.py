@@ -56,13 +56,14 @@ class KernelContract:
     kind: str                 # "sell_stream" | "dense_blocks"
     granule: int = 1          # rows per packed feature line (C)
     stream_k_multiple: int = 1  # streaming gate: k % this == 0
+    line_k: int = 0           # streaming gate: k divides this (0: off)
     row_blocks: Tuple[int, ...] = ()
     rings: Tuple[int, ...] = ()
     waves: Tuple[int, ...] = ()
     ks: Tuple[int, ...] = (16, 128)
     carriage_dtypes: Tuple[str, ...] = ("f32",)
     accum_dtype: str = "f32"
-    smem_cols_budget: int = 0       # scalar-prefetch budget (bytes)
+    smem_cols_budget: int = 0       # column bytes per call (slab)
     vmem_budget_bytes: int = 0      # KC2 budget for blocks + scratch
     #: Grid axes allowed to revisit the SAME output block (the
     #: matmul k-innermost accumulation pattern, head_spmm_pallas);
@@ -73,7 +74,9 @@ class KernelContract:
         """The streaming-gate predicate BOTH
         ``pallas_sell.supported_feature_width`` and the ``tune/space``
         pruning read — one predicate, one answer."""
-        return int(k) >= 1 and int(k) % self.stream_k_multiple == 0
+        k = int(k)
+        return (k >= 1 and k % self.stream_k_multiple == 0
+                and (not self.line_k or self.line_k % k == 0))
 
     def to_json(self) -> dict:
         return asdict(self)

@@ -3,29 +3,30 @@
 The XLA fold kernel (``ops/sell.py`` -> ``ops/ell.py ell_spmm_t``) pays
 for a materialized ``(k, chunk, rows)`` gather intermediate per tier —
 one full HBM round trip of every gathered feature row before the
-weighted reduction touches it.  At the measured 0.976-of-roofline
-headline that intermediate IS the remaining cost.  This kernel fuses
-gather -> multiply -> accumulate in VMEM:
+weighted reduction touches it.  This kernel fuses gather -> multiply
+-> accumulate in VMEM (on the chip it is correct but, so far, ~4.7x
+slower than the XLA fold — PERF.md, PR 21):
 
-  * features are packed into **granule lines**: ``C = 8`` consecutive
-    rows of the row-major ``(n, k)`` view form one contiguous
-    ``C*k``-float line (512 B at k=16), so every gather is a full-lane
-    line fetch instead of a 64 B sub-transaction column pick
-    (the ``tools/pallas_gather_probe.py`` design, productionized);
-  * column indices ride in twice: the whole slab via
-    ``pltpu.PrefetchScalarGridSpec`` **scalar prefetch** (SMEM — DMA
-    address computation ``granule = col // C`` needs scalar access),
-    and the row tile's block in VMEM for the vectorized sub-row select
-    (``off = col % C``);
+  * features are packed into **word lines**: 128 32-bit words (512 B)
+    holding ``C`` consecutive rows of the row-major ``(n, k)`` view in
+    the carriage dtype (C = 8 at f32 k=16, 1 at f32 k=128; bf16 and
+    int8 pack 2 and 4 features per word), so every gather is one
+    single-row DMA of a full-lane line — the only row DMA Mosaic takes
+    from an HBM table (v5e, PR 21);
+  * column indices ride in twice per row block: an SMEM block for the
+    DMA addresses (``line = col // C`` needs scalar reads) and a VMEM
+    block, transposed in-kernel to per-row columns, for the
+    vectorized sub-row select (``off = col % C``);
   * the streaming path issues ``wave``-sized groups of
-    ``pltpu.make_async_copy`` granule fetches with **two waves in
+    ``pltpu.make_async_copy`` line fetches with **two waves in
     flight** (double-buffered DMA: wave w+1's copies are started
-    before wave w is awaited), accumulating each slot's weighted
-    contribution into a VMEM accumulator — the ``(k, chunk, rows)``
+    before wave w is awaited); the select masks each row's segment,
+    lane rotations fold it onto every segment, and the weighted
+    contribution accumulates in f32 — the ``(k, chunk, rows)``
     intermediate never exists;
-  * slot-major slabs: a tier whose column array exceeds the scalar
-    (SMEM) budget is streamed through the kernel in row slabs, each
-    slab one ``pallas_call``.
+  * wide tiers walk their slots in chunks along an inner grid axis
+    that accumulates into the row block's output; long tiers are cut
+    into row slabs, one ``pallas_call`` each.
 
 Two statically-selected bodies share the select/accumulate math:
 
@@ -52,6 +53,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
 from arrow_matrix_tpu.ops.ell import align_up
 from arrow_matrix_tpu.ops.kernel_contract import (
@@ -61,19 +63,33 @@ from arrow_matrix_tpu.ops.kernel_contract import (
 from arrow_matrix_tpu.ops.pallas_blocks import VMEM_BUDGET, _interpret
 from arrow_matrix_tpu.ops.sell import SellMatrix
 
-GRANULE = 8          # rows per packed feature line (C): 8*k floats each
+GRANULE = 8          # rows per packed line at the f32 k=16 protocol point
 
-# Streaming lane constraint: a granule line spans C*k lanes, and the
-# Mosaic vector unit wants the minor dimension in whole 128-lane tiles.
-STREAM_K_MULTIPLE = 16   # C * 16 = 128
+#: Every packed feature line is 128 32-bit words (512 B): one full-lane
+#: row, the unit a single-row DMA moves from HBM on TPU (Mosaic refuses
+#: a one-row DMA out of a wider, (8, 128)-tiled table — measured on
+#: v5e, PR 21).  ``line_geometry`` derives how many feature rows share
+#: a line for each (k, carriage).
+LINE_WORDS = 128
 
-#: The contract-declared scalar-prefetch budget (the certified value —
+# Streaming lane constraint: a feature row must fill whole words and
+# tile a 128-word line, and the output lines hold 128 // k rows.
+STREAM_K_MULTIPLE = 16
+
+#: SMEM bytes the double-buffered (slots, row_block) column block of
+#: one grid step may take: half of v5e's 1 MiB SMEM (a 1 MiB block
+#: overflows it — AOT compile for v5e, PR 21).
+SMEM_BLOCK_BUDGET = 1 << 19
+
+#: The contract-declared slab budget (the certified value —
 #: ``KERNEL_CONTRACT`` and the committed kernel_manifest pin THIS one,
-#: independent of the env override below).
-DEFAULT_SMEM_COLS_BUDGET = 1 << 20
+#: independent of the env override below): column-index bytes one
+#: ``pallas_call`` streams.  SMEM only ever holds one row block's
+#: columns, so this bounds call size, not on-chip memory.
+DEFAULT_SMEM_COLS_BUDGET = 1 << 26
 
-#: Scalar-prefetch (SMEM) budget for one slab's column array.  Tiers
-#: whose cols exceed it are streamed through the kernel in row slabs.
+#: Column bytes per slab; tiers whose cols exceed it are streamed
+#: through the kernel in row slabs, one ``pallas_call`` each.
 #: ``AMT_PALLAS_SELL_SMEM`` is the *default only*, read once at import
 #: (R9: no per-call env reads); callers — and graft-tune plans — pass
 #: ``smem_cols_budget=`` explicitly to override.
@@ -98,27 +114,67 @@ DEFAULT_RING = 2         # DMA waves in flight (VMEM ring depth)
 def slab_rows(m_t: int, rb: int,
               smem_cols_budget: Optional[int] = None) -> int:
     """Rows per slot-major slab: as many ``rb``-row blocks as fit the
-    scalar-prefetch budget (``m_t * 4`` bytes of int32 cols per row),
+    slab budget (4 bytes of int32 cols per padded slot and row),
     never less than one row block — a tier whose per-row cols alone
     exceed the budget still streams, one block at a time."""
     budget = (SMEM_COLS_BUDGET if smem_cols_budget is None
               else smem_cols_budget)
-    per_row = m_t * 4
+    per_row = align_up(max(m_t, 1), _slot_rows(m_t)) * 4
     return max(rb, (budget // max(per_row, 1)) // rb * rb)
 
 
-def pack_features_t(x_t: jax.Array) -> jax.Array:
-    """Pack feature-major ``(k, n)`` features into granule lines
-    ``(n_pad // C, C*k)``: line g holds rows ``[g*C, (g+1)*C)`` of the
-    row-major view, contiguous — one full-lane DMA per gathered row
-    group.  Zero-pads n up to a GRANULE multiple."""
-    k, n = x_t.shape
-    n_pad = align_up(max(n, 1), GRANULE)
-    x = x_t.T                                     # (n, k) row-major view
-    if n_pad != n:
-        x = jnp.pad(x, ((0, n_pad - n), (0, 0)))
-    return x.reshape(n_pad // GRANULE, GRANULE * k)
+def line_geometry(k: int, carriage: str = "f32") -> tuple:
+    """``(words_per_row, rows_per_line, planes, k_pad)`` of the packed
+    feature table.
 
+    A feature row of ``k`` carriage elements (zero-padded to ``k_pad``,
+    a whole number of words) is ``words_per_row`` 32-bit words;
+    ``planes = 4 / itemsize`` elements share one word (plane p of word
+    q holds feature ``q + p * words_per_row``, so a decoded plane is a
+    contiguous feature run).  When a row tiles a 128-word line, a line
+    holds ``rows_per_line`` consecutive rows (the streaming layout);
+    other widths — interpret-mode only — fall back to ``planes`` rows
+    per line."""
+    if k < 1:
+        raise ValueError(f"pallas_sell needs k >= 1, got {k}")
+    item = CARRIAGE_ITEMSIZE[carriage]
+    planes = 4 // item
+    k_pad = align_up(k, planes)
+    wpr = k_pad // planes
+    if LINE_WORDS % wpr == 0 and (LINE_WORDS // wpr) % planes == 0:
+        return wpr, LINE_WORDS // wpr, planes, k_pad
+    return wpr, planes, planes, k_pad
+
+
+def pack_features_t(x_t: jax.Array, feature_dtype=None) -> jax.Array:
+    """Pack feature-major ``(k, n)`` features into int32 word lines
+    ``(n_pad // C, C * words_per_row)`` in the carriage dtype
+    (``feature_dtype``, default: the input's): line g holds rows
+    ``[g*C, (g+1)*C)`` of the row-major view — one single-row DMA per
+    gathered row group.  Zero-pads n up to a ``C`` multiple.  An int8
+    carriage expects an already-quantized table
+    (:func:`quantize_features_t`)."""
+    k, n = x_t.shape
+    carriage, dt = resolve_carriage_dtype(feature_dtype,
+                                          default=x_t.dtype)
+    wpr, c, planes, k_pad = line_geometry(k, carriage)
+    n_pad = align_up(max(n, 1), c)
+    x = x_t.T                                     # (n, k) row-major view
+    if n_pad != n or k_pad != k:
+        x = jnp.pad(x, ((0, n_pad - n), (0, k_pad - k)))
+    if planes == 1:
+        words = jax.lax.bitcast_convert_type(x.astype(jnp.float32),
+                                             jnp.int32)
+    else:
+        bits = jax.lax.bitcast_convert_type(
+            x.astype(dt), jnp.uint16 if planes == 2 else jnp.uint8
+        ).astype(jnp.uint32)
+        acc = bits[:, :wpr]
+        for p in range(1, planes):
+            acc = acc | (bits[:, p * wpr:(p + 1) * wpr]
+                         << (32 // planes * p))
+        words = jax.lax.bitcast_convert_type(acc, jnp.int32)
+    return words.reshape(n_pad // c, c * wpr)
 
 def quantize_features_t(x_t: jax.Array):
     """Symmetric per-feature-row int8 quantization of the feature-major
@@ -169,18 +225,67 @@ def _schedule_overrides(schedule) -> dict:
     return out
 
 
-def _select_accumulate(lines, cols_j, w_j, r, k):
-    """Shared select/accumulate math of both kernel bodies: mask each
-    row's granule line down to its ``col % C`` sub-row, fold the C
-    segments, weight, and return the (r//C, C, k) f32 contribution."""
-    c = GRANULE
-    off = (cols_j % c).astype(jnp.int32)                      # (r,)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (r, c * k), 1) // k
-    masked = jnp.where(lane == off[:, None],
-                       lines.astype(jnp.float32), 0.0)
-    picked = masked.reshape(r // c, c, c, k).sum(axis=2)      # (r//C, C, k)
-    return picked * w_j.reshape(r // c, c, 1)
+def _decode_planes(lines, planes: int) -> list:
+    """int32 word lines -> ``planes`` f32 arrays of the same shape
+    (plane p of word q = feature ``q + p * words_per_row``)."""
+    if planes == 1:
+        return [jax.lax.bitcast_convert_type(lines, jnp.float32)]
+    if planes == 2:
+        return [jax.lax.bitcast_convert_type(lines << 16, jnp.float32),
+                jax.lax.bitcast_convert_type(lines & jnp.int32(-65536),
+                                             jnp.float32)]
+    return [((lines << (24 - 8 * p)) >> 24).astype(jnp.float32)
+            for p in range(planes)]
 
+
+def _select_accumulate(lines, off, w, k: int, carriage: str,
+                       interpret: bool):
+    """Shared select/accumulate math of both kernel bodies.
+
+    ``lines`` (r, L) int32 holds each row's gathered line; ``off``
+    (r, 1) its row's position in the line; ``w`` (r, 1) f32 the slot
+    weight.  Masks every plane to the row's word segment, folds the
+    segments onto each other with lane rotations (after the fold every
+    segment holds the row), merges the planes so lane l carries feature
+    ``l % k``, and weights: (r, L) f32, each row's k features
+    replicated ``L // k`` times."""
+    wpr, c, planes, k = line_geometry(k, carriage)
+    r, lanes = lines.shape
+    roll = jnp.roll if interpret else pltpu.roll
+    lane = jax.lax.broadcasted_iota(jnp.int32, (r, lanes), 1)
+    seg = lane // wpr
+    total = None
+    for p, plane in enumerate(_decode_planes(lines, planes)):
+        v = jnp.where(seg == off, plane, 0.0)
+        s = c // 2
+        while s >= 1:
+            v = v + roll(v, s * wpr, 1)
+            s //= 2
+        if planes > 1:
+            v = jnp.where((lane % k) // wpr == p, v, 0.0)
+        total = v if total is None else total + v
+    return total * w
+
+
+def out_rows_per_line(k: int, carriage: str = "f32") -> int:
+    """Rows per kernel output line (``G``): the line width over the
+    padded feature row."""
+    wpr, c, _planes, k_pad = line_geometry(k, carriage)
+    return c * wpr // k_pad
+
+
+def _pack_rows(acc, k: int):
+    """(r, L) row-replicated accumulator -> (r // G, L) output lines of
+    ``G = L // k`` consecutive rows each (row-major (r, k) after a
+    reshape): keep row i's copy in segment ``i % G``, sum row groups."""
+    r, lanes = acc.shape
+    g = lanes // k
+    if g == 1:
+        return acc
+    rowseg = jax.lax.broadcasted_iota(jnp.int32, (r, lanes), 0) % g
+    laneseg = jax.lax.broadcasted_iota(jnp.int32, (r, lanes), 1) // k
+    return jnp.where(rowseg == laneseg, acc, 0.0).reshape(
+        r // g, g, lanes).sum(axis=1)
 
 def resolve_carriage_dtype(feature_dtype, default=jnp.float32):
     """Normalize a carriage-dtype request to ``(key, jnp dtype)``.
@@ -216,27 +321,50 @@ def resolve_carriage_dtype(feature_dtype, default=jnp.float32):
         f"the kernel contract serves {tuple(CARRIAGE_DTYPES)}")
 
 
+#: Rows per grid program are a multiple of this: rows are the lane
+#: (minor) axis of the (slots, rows) column blocks, which Mosaic tiles
+#: in whole 128-lane vregs (a 64-row block is refused — v5e AOT, PR 21).
+ROW_ALIGN = 128
+
+#: Slots per grid step: a wider tier (hub rows) walks its slots in
+#: chunks along the inner grid axis, accumulating into the same output
+#: block, so the (slots, row_block) column/weight blocks stay small in
+#: SMEM and VMEM whatever the degree.
+SLOT_CHUNK = 128
+
+
+def _slot_rows(m_t: int) -> int:
+    """Slot rows of one grid step's column/weight blocks (a whole
+    number of sublanes, at most SLOT_CHUNK)."""
+    return min(SLOT_CHUNK, align_up(max(m_t, 1), 8))
+
+
 def slab_call_meta(m_t: int, slab: int, k: int, row_block: int,
                    binary: bool, stream: bool, wave: int, ring: int,
                    n_lines: Optional[int] = None,
-                   carriage: str = "f32",
-                   smem_cols_budget: Optional[int] = None) -> dict:
+                   carriage: str = "f32") -> dict:
     """The literal description of one concretized slab ``pallas_call``
     — grid, BlockSpecs, scratch, budgets — in the graft-kcert meta
     schema.  :func:`_make_slab_call` derives its real grid/block/
     scratch numbers FROM this dict, so the certified description and
     the executed call cannot drift apart."""
-    c = GRANULE
     if ring < 1:
         raise ValueError(f"ring depth must be >= 1, got {ring}")
     if m_t < 1:
         raise ValueError(f"meta needs m_t >= 1, got {m_t}")
     if k < 1:
         raise ValueError(f"meta needs k >= 1, got {k}")
-    if row_block < c or row_block % c:
+    if carriage not in CARRIAGE_ITEMSIZE:
         raise ValueError(
-            f"row_block must be a positive GRANULE ({c}) multiple, "
-            f"got {row_block}")
+            f"unknown carriage dtype key {carriage!r}; contract "
+            f"serves {tuple(CARRIAGE_ITEMSIZE)}")
+    g = out_rows_per_line(k, carriage)
+    wpr, c, _planes, _k_pad = line_geometry(k, carriage)
+    lanes = c * wpr
+    if row_block < ROW_ALIGN or row_block % ROW_ALIGN or row_block % g:
+        raise ValueError(
+            f"row_block must be a positive ROW_ALIGN ({ROW_ALIGN}) "
+            f"multiple, got {row_block}")
     if wave < 1 or row_block % wave:
         raise ValueError(
             f"wave must divide row_block ({row_block}), got {wave}")
@@ -244,47 +372,45 @@ def slab_call_meta(m_t: int, slab: int, k: int, row_block: int,
         raise ValueError(
             f"slab must be a positive row_block ({row_block}) "
             f"multiple, got {slab}")
-    if carriage not in CARRIAGE_ITEMSIZE:
-        raise ValueError(
-            f"unknown carriage dtype key {carriage!r}; contract "
-            f"serves {tuple(CARRIAGE_ITEMSIZE)}")
-    lanes = c * k
     n_lines = (max(1, (1 << 12) // c) if n_lines is None
                # host-side meta builder: the argument is a static
                # shape, never a traced value
                else int(n_lines))  # graft-lint: disable=R1
-    budget = (SMEM_COLS_BUDGET if smem_cols_budget is None
-              else smem_cols_budget)
-    item = CARRIAGE_ITEMSIZE[carriage]
-    w_rows = 1 if binary else m_t
+    m_rows = _slot_rows(m_t)
+    n_chunks = -(-m_t // m_rows)
+    weights = ({"name": "weights", "shape": [1, slab],
+                "block": [1, row_block], "index": [0, "i"],
+                "space": "vmem", "itemsize": 4} if binary else
+               {"name": "weights", "shape": [n_chunks * m_rows, slab],
+                "block": [m_rows, row_block], "index": ["s", "i"],
+                "space": "vmem", "itemsize": 4})
     meta = {
         "kernel": "sell_tier_spmm_packed",
         "kind": "sell_stream" if stream else "sell_vectorized",
-        "grid": [["i", slab // row_block]],
-        "out": {"shape": [slab // c, lanes],
-                "block": [row_block // c, lanes],
+        "grid": [["i", slab // row_block], ["s", n_chunks]],
+        "out": {"shape": [slab // g, lanes],
+                "block": [row_block // g, lanes],
                 "index": ["i", 0], "itemsize": 4},
         "ins": [
-            {"name": "cols_vmem", "shape": [m_t, slab],
-             "block": [m_t, row_block], "index": [0, "i"],
+            {"name": "cols_vmem", "shape": [n_chunks * m_rows, slab],
+             "block": [m_rows, row_block], "index": ["s", "i"],
              "space": "vmem", "itemsize": 4},
-            {"name": "weights", "shape": [w_rows, slab],
-             "block": [w_rows, row_block], "index": [0, "i"],
-             "space": "vmem", "itemsize": 4},
+            weights,
             {"name": "x_packed", "shape": [n_lines, lanes],
              "block": None, "index": None, "space": "any",
-             "itemsize": item},
+             "itemsize": 4},
         ],
-        "smem": {"name": "cols_prefetch", "bytes": m_t * 4 * slab,
-                 "budget": budget, "single_block": slab == row_block},
+        "smem": {"name": "cols_smem", "bytes": 2 * m_rows * 4 * row_block,
+                 "budget": SMEM_BLOCK_BUDGET, "single_block": False},
         "scratch": ([{"name": "dma_scratch",
-                      "shape": [row_block, lanes], "itemsize": item}]
+                      "shape": [row_block, lanes], "itemsize": 4}]
                     if stream else []),
         "sems": ({"shape": [ring, wave]} if stream else None),
         "vmem_budget": VMEM_BUDGET,
         "accum_dtype": "f32",
         "carriage_dtype": carriage,
-        "revisit_axes": [],
+        # Slot chunks of one row block accumulate into its output block.
+        "revisit_axes": ["s"],
     }
     if stream:
         meta["stream"] = {
@@ -300,66 +426,96 @@ def _make_slab_call(m_t: int, slab: int, k: int, row_block: int,
                     interpret: bool, ring: int = DEFAULT_RING,
                     n_lines: Optional[int] = None,
                     carriage: str = "f32"):
-    """One ``pallas_call`` over a (m_t, slab) column slab -> packed
-    (slab // C, C*k) f32 partial output (accumulation is f32 whatever
-    the carriage dtype of ``x_packed`` — KC4)."""
+    """One ``pallas_call`` over a (slots, slab) column slab -> packed
+    (slab // G, L) f32 output (accumulation is f32 whatever the
+    carriage dtype of ``x_packed`` — KC4).  Grid: row blocks outer,
+    slot chunks inner."""
     import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     meta = slab_call_meta(m_t, slab, k, row_block, binary, stream,
                           wave, ring, n_lines=n_lines,
                           carriage=carriage)
-    c = GRANULE
-    lanes = c * k
+    c = line_geometry(k, carriage)[1]
+    k_pad = line_geometry(k, carriage)[3]
+    lanes = meta["out"]["shape"][1]
+    m_rows = meta["ins"][0]["block"][0]
     grid = tuple(size for _axis, size in meta["grid"])
     n_waves = meta["stream"]["n_waves"] if stream else row_block // wave
-    carriage_dt = CARRIAGE_DTYPES[carriage]
 
-    def _weight(w_all, cols_all, j, r):
+    def _row_columns(cols_vmem, w_vmem):
+        """This row block's slot columns as per-row (R, slots) arrays:
+        line offsets ``col % C`` (f32, exact) and weights (binary: the
+        (R, 1) degree).  Mosaic transposes the (slots, R) blocks; a
+        value-level dynamic row index would not lower."""
+        offs = jnp.transpose(cols_vmem[...] % c).astype(jnp.float32)
         if binary:
-            # Slot-validity mask (j < deg), generated in registers —
-            # same addends as the golden's iota-vs-degree compare.
-            return (j < w_all[0]).astype(jnp.float32)
-        return jax.lax.dynamic_index_in_dim(
-            w_all, j, axis=0, keepdims=False).astype(jnp.float32)
+            deg = jnp.broadcast_to(w_vmem[...], (8, row_block))
+            return offs, jnp.transpose(deg)[:, :1]
+        return offs, jnp.transpose(w_vmem[...].astype(jnp.float32))
+
+    def _slot(j, offs, w_rows, chunk):
+        """Slot j (of slot chunk ``chunk``)'s (R, 1) line offset and
+        weight."""
+        pick = jax.lax.broadcasted_iota(jnp.int32, offs.shape, 1) == j
+        off = jnp.sum(jnp.where(pick, offs, 0.0), axis=1,
+                      keepdims=True).astype(jnp.int32)
+        if binary:
+            # Slot-validity mask (global slot < deg), generated in
+            # registers — same addends as the golden's iota-vs-degree
+            # compare.
+            return off, (chunk * m_rows + j < w_rows).astype(jnp.float32)
+        return off, jnp.sum(jnp.where(pick, w_rows, 0.0), axis=1,
+                            keepdims=True)
+
+    def _n_slots():
+        """Real slots of this chunk (the last one may be short)."""
+        return jnp.minimum(m_rows, m_t - pl.program_id(1) * m_rows)
+
+    def _store(out_ref, acc):
+        packed = _pack_rows(acc, k_pad)
+
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            out_ref[...] = packed
+
+        @pl.when(pl.program_id(1) > 0)
+        def _():
+            out_ref[...] += packed
 
     def kernel_vectorized(cols_smem, cols_vmem, w_vmem, x_any, out_ref):
         # interpret-only body: wholesale read + take stands in for the
         # DMA engine; grid, masking and accumulation order are shared
         # with the streaming body, so tier-1 pins both.
         del cols_smem
+        chunk = pl.program_id(1)
         xg = x_any[...]
-        cols_all = cols_vmem[...].astype(jnp.int32)            # (m_t, R)
-        w_all = w_vmem[...]
-        g_all = cols_all // c
+        lines_all = jnp.transpose(cols_vmem[...] // c)      # (R, slots)
+        offs, w_rows = _row_columns(cols_vmem, w_vmem)
 
         def slot_body(j, acc):
-            g_j = jax.lax.dynamic_index_in_dim(g_all, j, axis=0,
-                                               keepdims=False)
-            cols_j = jax.lax.dynamic_index_in_dim(cols_all, j, axis=0,
-                                                  keepdims=False)
-            lines = jnp.take(xg, g_j, axis=0)                 # (R, C*k)
-            w_j = _weight(w_all, cols_all, j, row_block)
-            return acc + _select_accumulate(lines, cols_j, w_j,
-                                            row_block, k)
+            pick = jax.lax.broadcasted_iota(
+                jnp.int32, lines_all.shape, 1) == j
+            g_j = jnp.sum(jnp.where(pick, lines_all, 0), axis=1)
+            lines = jnp.take(xg, g_j, axis=0)                # (R, L)
+            off, w_j = _slot(j, offs, w_rows, chunk)
+            return acc + _select_accumulate(lines, off, w_j, k,
+                                            carriage, interpret)
 
-        acc0 = jnp.zeros((row_block // c, c, k), dtype=jnp.float32)
-        acc = jax.lax.fori_loop(0, m_t, slot_body, acc0)
-        out_ref[...] = acc.reshape(row_block // c, lanes)
+        acc0 = jnp.zeros((row_block, lanes), dtype=jnp.float32)
+        _store(out_ref, jax.lax.fori_loop(0, _n_slots(), slot_body, acc0))
 
     def kernel_stream(cols_smem, cols_vmem, w_vmem, x_any, out_ref,
                       scratch, sems):
-        row0 = pl.program_id(0) * row_block
-        cols_all = cols_vmem[...].astype(jnp.int32)
-        w_all = w_vmem[...]
+        chunk = pl.program_id(1)
+        offs, w_rows = _row_columns(cols_vmem, w_vmem)
 
         def copy(j, w, r):
-            """The (slot j, wave w, lane r) granule fetch: address from
-            SMEM (scalar prefetch), destination its own scratch row,
+            """The (slot j, wave w, lane r) line fetch: address from
+            the SMEM column block, destination its own scratch row,
             semaphore by wave modulo the ring depth — up to ``ring``
             waves in flight."""
             rr = w * wave + r
-            g = cols_smem[j, row0 + rr] // c
+            g = cols_smem[j, rr] // c
             return pltpu.make_async_copy(
                 x_any.at[g], scratch.at[rr], sems.at[w % ring, r])
 
@@ -387,61 +543,61 @@ def _make_slab_call(m_t: int, slab: int, k: int, row_block: int,
                 return carry
 
             jax.lax.fori_loop(0, n_waves, wave_body, 0)
-            cols_j = jax.lax.dynamic_index_in_dim(cols_all, j, axis=0,
-                                                  keepdims=False)
-            w_j = _weight(w_all, cols_all, j, row_block)
-            return acc + _select_accumulate(scratch[...], cols_j, w_j,
-                                            row_block, k)
+            off, w_j = _slot(j, offs, w_rows, chunk)
+            return acc + _select_accumulate(scratch[...], off, w_j, k,
+                                            carriage, interpret)
 
-        acc0 = jnp.zeros((row_block // c, c, k), dtype=jnp.float32)
-        acc = jax.lax.fori_loop(0, m_t, slot_body, acc0)
-        out_ref[...] = acc.reshape(row_block // c, lanes)
+        acc0 = jnp.zeros((row_block, lanes), dtype=jnp.float32)
+        _store(out_ref, jax.lax.fori_loop(0, _n_slots(), slot_body, acc0))
 
     cols_block = tuple(meta["ins"][0]["block"])
     w_block = tuple(meta["ins"][1]["block"])
+    w_index = ((lambda i, s: (0, i)) if binary
+               else (lambda i, s: (s, i)))
     out_block = tuple(meta["out"]["block"])
-    gs = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,            # cols -> SMEM, whole slab
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(cols_block, lambda i, sc: (0, i),
-                         memory_space=pltpu.VMEM),   # cols, vector math
-            pl.BlockSpec(w_block, lambda i, sc: (0, i),
-                         memory_space=pltpu.VMEM),   # data / deg
-            pl.BlockSpec(memory_space=pl.ANY),       # packed x: HBM
-        ],
-        out_specs=pl.BlockSpec(out_block, lambda i, sc: (i, 0),
-                               memory_space=pltpu.VMEM),
-        # DMA scratch carries the FEATURE dtype (a bf16 line must land
-        # in a bf16 slab: async copies cannot convert); the accumulator
-        # in the kernel body stays f32.
-        scratch_shapes=([pltpu.VMEM(tuple(meta["scratch"][0]["shape"]),
-                                    carriage_dt),
-                         pltpu.SemaphoreType.DMA(
-                             tuple(meta["sems"]["shape"]))]
-                        if stream else []),
-    )
+    # Columns ride in twice, per block: an SMEM block for the DMA
+    # addresses (scalar reads) and a VMEM block for the vector offsets.
+    in_specs = [
+        pl.BlockSpec(cols_block, lambda i, s: (s, i),
+                     memory_space=pltpu.SMEM),   # cols, DMA addresses
+        pl.BlockSpec(cols_block, lambda i, s: (s, i),
+                     memory_space=pltpu.VMEM),   # cols, vector math
+        pl.BlockSpec(w_block, w_index,
+                     memory_space=pltpu.VMEM),   # data / deg
+        pl.BlockSpec(memory_space=pl.ANY),       # packed x: HBM
+    ]
+    # DMA scratch holds the packed int32 words of any carriage; the
+    # kernel body decodes to f32 before it accumulates.
+    scratch = ([pltpu.VMEM(tuple(meta["scratch"][0]["shape"]), jnp.int32),
+                pltpu.SemaphoreType.DMA(tuple(meta["sems"]["shape"]))]
+               if stream else [])
     kernel = kernel_stream if stream else kernel_vectorized
 
     def call(cols_slab, w_slab, x_packed):
         return pl.pallas_call(
             kernel,
-            out_shape=jax.ShapeDtypeStruct((slab // c, lanes),
+            out_shape=jax.ShapeDtypeStruct(tuple(meta["out"]["shape"]),
                                            jnp.float32),
-            grid_spec=gs,
+            grid=grid, in_specs=in_specs,
+            out_specs=pl.BlockSpec(out_block, lambda i, s: (i, 0),
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=scratch,
             interpret=interpret,
         )(cols_slab, cols_slab, w_slab, x_packed)
 
     return call
 
 
-def _tier_row_block(n_t: int, row_block: int) -> int:
+def _tier_row_block(n_t: int, row_block: int, g: int = 1) -> int:
     """Rows per grid program: the requested block, shrunk to the tier
-    (GRANULE-aligned) so a tiny tier doesn't pad to a full block."""
-    return min(row_block, align_up(max(n_t, 1), GRANULE))
+    so a tiny tier doesn't pad to a full block, and aligned to
+    ROW_ALIGN and to the ``g`` rows of one output line."""
+    align = max(ROW_ALIGN, g)
+    rb = min(row_block, align_up(max(n_t, 1), align))
+    return max(align, rb - rb % align)
 
 
-def sell_tier_spmm_packed(cols: jax.Array, x_packed: jax.Array,
+def sell_tier_spmm_packed(cols: jax.Array, x_packed: jax.Array, k: int,
                           data: Optional[jax.Array] = None,
                           deg: Optional[jax.Array] = None,
                           row_block: int = DEFAULT_ROW_BLOCK,
@@ -451,20 +607,21 @@ def sell_tier_spmm_packed(cols: jax.Array, x_packed: jax.Array,
                           smem_cols_budget: Optional[int] = None,
                           ring: int = DEFAULT_RING,
                           feature_dtype=None) -> jax.Array:
-    """One tier's fused SpMM against granule-packed features.
+    """One tier's fused SpMM against packed features.
 
-    cols: (m_t, n_t) slot-major int32; x_packed: (n_gran, C*k) from
-    :func:`pack_features_t`; ``data`` (m_t, n_t) weighted or ``deg``
-    (n_t,) binary.  Returns (n_t, k) f32 — row-major (the caller
-    re-majors per call, see :func:`sell_spmm_t_pallas`).
+    cols: (m_t, n_t) slot-major int32; x_packed: the int32 word lines
+    of :func:`pack_features_t` for ``k`` features in the carriage
+    ``feature_dtype`` ("f32", "bf16" or "int8"; default f32 — it must
+    be the dtype the table was packed in); ``data`` (m_t, n_t)
+    weighted or ``deg`` (n_t,) binary.  Returns (n_t, k) f32 —
+    row-major (the caller re-majors per call, see
+    :func:`sell_spmm_t_pallas`).
 
-    ``smem_cols_budget`` bounds one slab's scalar-prefetch bytes
-    (default: module-level :data:`SMEM_COLS_BUDGET`); ``ring`` is the
-    DMA ring depth of the streaming path (waves in flight);
-    ``feature_dtype`` picks the carriage dtype ("f32"/"bf16") the
-    gathered features travel in — accumulation stays f32 either way
-    (the certified KC4 contract), so bf16 carriage halves DMA bytes
-    without narrowing the reduction.
+    ``smem_cols_budget`` bounds one slab's column bytes (default:
+    module-level :data:`SMEM_COLS_BUDGET`); ``ring`` is the DMA ring
+    depth of the streaming path (waves in flight).  Accumulation stays
+    f32 for every carriage (the certified KC4 contract), so bf16
+    carriage halves DMA bytes without narrowing the reduction.
     """
     if interpret is None:
         interpret = _interpret()
@@ -473,20 +630,18 @@ def sell_tier_spmm_packed(cols: jax.Array, x_packed: jax.Array,
     if ring < 1:
         raise ValueError(f"ring depth must be >= 1, got {ring}")
     m_t, n_t = cols.shape
-    k = x_packed.shape[1] // GRANULE
-    carriage, carriage_dt = resolve_carriage_dtype(
-        feature_dtype, default=x_packed.dtype)
-    if x_packed.dtype != jnp.dtype(carriage_dt):
-        x_packed = x_packed.astype(carriage_dt)
+    carriage, _dt = resolve_carriage_dtype(feature_dtype,
+                                           default=jnp.float32)
     if data is None and deg is None and m_t > 0:
         raise ValueError("binary SELL tier (data=None) requires deg")
     if m_t == 0 or n_t == 0:
         return jnp.zeros((n_t, k), dtype=jnp.float32)
-    if stream and k % STREAM_K_MULTIPLE != 0:
+    if stream and not supported_feature_width(k):
         raise ValueError(
             f"streaming pallas_sell needs k % {STREAM_K_MULTIPLE} == 0 "
-            f"(granule lines must fill whole 128-lane tiles), got k={k}; "
-            f"use the XLA fold kernel for this feature width")
+            f"and k | {LINE_WORDS} (a feature row must tile one "
+            f"{LINE_WORDS}-word line), got k={k}; use the XLA fold "
+            f"kernel for this feature width")
     if not stream and not interpret:
         raise ValueError(
             "the vectorized pallas_sell body is interpret-only (it "
@@ -494,24 +649,24 @@ def sell_tier_spmm_packed(cols: jax.Array, x_packed: jax.Array,
             "must use stream=True")
 
     binary = data is None
-    rb = _tier_row_block(n_t, row_block)
-    rb = max(GRANULE, rb - rb % GRANULE)
+    k_pad = line_geometry(k, carriage)[3]
+    rb = _tier_row_block(n_t, row_block, out_rows_per_line(k, carriage))
     w = min(wave, rb)
     while rb % w:
         w -= 1
     rows_pad = align_up(n_t, rb)
-    pad = rows_pad - n_t
-    if pad:
-        cols = jnp.pad(cols, ((0, 0), (0, pad)))
-        if binary:
-            deg = jnp.pad(deg, (0, pad))
-        else:
-            data = jnp.pad(data, ((0, 0), (0, pad)))
-    weights = (deg.astype(jnp.int32).reshape(1, rows_pad) if binary
-               else data)
+    m_rows = _slot_rows(m_t)
+    slots_pad = align_up(m_t, m_rows)
+    cols = jnp.pad(cols, ((0, slots_pad - m_t), (0, rows_pad - n_t)))
+    if binary:
+        weights = jnp.pad(deg.astype(jnp.int32),
+                          (0, rows_pad - n_t)).reshape(1, rows_pad)
+    else:
+        weights = jnp.pad(data.astype(jnp.float32),
+                          ((0, slots_pad - m_t), (0, rows_pad - n_t)))
 
-    # Slot-major slab streaming: bound each call's scalar-prefetch
-    # (SMEM) bytes; every slab is a whole number of row blocks.
+    # Slot-major slab streaming: bound each call's column bytes; every
+    # slab is a whole number of row blocks.
     slab = slab_rows(m_t, rb, smem_cols_budget)
     outs = []
     for lo in range(0, rows_pad, slab):
@@ -524,8 +679,8 @@ def sell_tier_spmm_packed(cols: jax.Array, x_packed: jax.Array,
             jax.lax.slice_in_dim(cols, lo, hi, axis=1),
             jax.lax.slice_in_dim(weights, lo, hi, axis=1),
             x_packed))
-    packed = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-    return packed.reshape(rows_pad, k)[:n_t]
+    packed = outs[0] if len(outs) == 1 else jnp.concatenate(outs, 0)
+    return packed.reshape(rows_pad, k_pad)[:n_t, :k]
 
 
 def sell_spmm_t_pallas(m: SellMatrix, x_t: jax.Array,
@@ -573,10 +728,14 @@ def sell_spmm_t_pallas(m: SellMatrix, x_t: jax.Array,
                 "int8 (q, scale) carriage quantizes the whole feature "
                 "table; per-tier schedule carriage overrides cannot "
                 "apply on top of it")
-        q, scale = quantize_features_t(x_t)
-        x_packed = pack_features_t(q)
-    else:
-        x_packed = pack_features_t(x_t)
+        x_t, scale = quantize_features_t(x_t)
+    packs = {}   # carriage -> packed table, built once per call
+
+    def packed(carriage):
+        if carriage not in packs:
+            packs[carriage] = pack_features_t(x_t, carriage)
+        return packs[carriage]
+
     outs = []
     for t, cols in enumerate(m.cols):
         ov = sched.get(t, {})
@@ -585,10 +744,10 @@ def sell_spmm_t_pallas(m: SellMatrix, x_t: jax.Array,
                 "per-tier carriage 'int8' is not schedulable: the "
                 "(q, scale) pair quantizes the whole feature table "
                 "(pass feature_dtype='int8' instead)")
-        fd_t = ov.get("carriage", feature_dtype)
+        fd_t = resolve_carriage_dtype(ov.get("carriage", carriage_key))[0]
         budget_t = ov.get("smem_cols_budget")
         out_t = sell_tier_spmm_packed(
-            cols, x_packed,
+            cols, packed(fd_t), k,
             data=None if m.data is None else m.data[t],
             deg=None if m.deg is None else m.deg[t],
             row_block=ov.get("row_block", row_block),
@@ -647,7 +806,8 @@ KERNEL_CONTRACT = KernelContract(
     kind="sell_stream",
     granule=GRANULE,
     stream_k_multiple=STREAM_K_MULTIPLE,
-    row_blocks=(64, 128, 256),
+    line_k=LINE_WORDS,
+    row_blocks=(128, 256),
     rings=(1, 2, 3, 4),
     waves=(8, 16),
     ks=(16, 128),
@@ -671,21 +831,19 @@ def kcert_metas():
         # (row_block, ring, wave, k, m_t, binary, carriage)
         (256, 2, 16, 16, 16, True, "f32"),    # the defaults
         (256, 2, 16, 128, 8, False, "f32"),   # wide k, weighted
-        (64, 1, 8, 16, 5, True, "f32"),       # serial ring, small tier
+        (128, 1, 8, 16, 5, True, "f32"),      # serial ring, small tier
         (128, 3, 8, 128, 3, True, "bf16"),    # deep ring, bf16 carriage
         (256, 4, 16, 16, 16, False, "bf16"),  # deepest ring, weighted
-        (64, 4, 8, 16, 4, False, "int8"),     # fused (q, scale) carriage
+        (128, 4, 8, 16, 4, False, "int8"),    # fused (q, scale) carriage
     ]
     metas = []
     for rb, ring, wave, k, m_t, binary, carriage in points:
         metas.append(slab_call_meta(
             m_t, slab_rows(m_t, rb, budget), k, rb, binary, True,
-            wave, ring, n_lines=lines, carriage=carriage,
-            smem_cols_budget=budget))
+            wave, ring, n_lines=lines, carriage=carriage))
     # The interpret-only vectorized twin (tier-1 correctness path).
     metas.append(slab_call_meta(
-        8, 256, 16, 256, True, False, 16, 1, n_lines=lines,
-        smem_cols_budget=budget))
+        8, 256, 16, 256, True, False, 16, 1, n_lines=lines))
     return metas
 
 
@@ -700,15 +858,15 @@ def kcert_witness():
     x_t = jnp.asarray(
         np.linspace(-1.0, 1.0, k * n_table, dtype=np.float32)
         .reshape(k, n_table))
-    x_packed = pack_features_t(x_t)
     try:
         for fd in ("f32", "bf16"):
+            x_packed = pack_features_t(x_t, fd)
             vec = sell_tier_spmm_packed(
-                cols, x_packed, deg=deg, stream=False, interpret=True,
-                row_block=32, wave=8, feature_dtype=fd)
+                cols, x_packed, k, deg=deg, stream=False,
+                interpret=True, row_block=64, wave=8, feature_dtype=fd)
             st = sell_tier_spmm_packed(
-                cols, x_packed, deg=deg, stream=True, interpret=True,
-                row_block=32, wave=8, ring=2, feature_dtype=fd)
+                cols, x_packed, k, deg=deg, stream=True, interpret=True,
+                row_block=64, wave=8, ring=2, feature_dtype=fd)
             vec, st = np.asarray(vec), np.asarray(st)
             if not np.array_equal(vec, st):
                 return False, (f"stream/vectorized mismatch at the "
@@ -725,13 +883,13 @@ def kcert_witness():
         # Witness feature table: provably tiny host fetch.
         q = jnp.asarray(np.round(np.asarray(x_t) * 127.0)  # graft-lint: disable=R6
                         .astype(np.int8))
-        q_packed = pack_features_t(q)
+        q_packed = pack_features_t(q, "int8")
         vec = sell_tier_spmm_packed(
-            cols, q_packed, deg=deg, stream=False, interpret=True,
-            row_block=32, wave=8, feature_dtype="int8")
+            cols, q_packed, k, deg=deg, stream=False, interpret=True,
+            row_block=64, wave=8, feature_dtype="int8")
         st = sell_tier_spmm_packed(
-            cols, q_packed, deg=deg, stream=True, interpret=True,
-            row_block=32, wave=8, ring=2, feature_dtype="int8")
+            cols, q_packed, k, deg=deg, stream=True, interpret=True,
+            row_block=64, wave=8, ring=2, feature_dtype="int8")
         vec, st = np.asarray(vec), np.asarray(st)
         if not np.array_equal(vec, st):
             return False, ("stream/vectorized mismatch at the "

@@ -36,7 +36,6 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from arrow_matrix_tpu.utils.transfer import chunked_asarray
 import numpy as np
 from flax import struct
 from scipy import sparse
@@ -169,11 +168,11 @@ def sell_from_csr(matrix: CsrLike, pad_rows_to: Optional[int] = None,
             cols[slot, tloc] = all_cols[src]
             if not is_binary:
                 vals[slot, tloc] = all_data[src]
-        cols_t.append(chunked_asarray(cols))
+        cols_t.append(jnp.asarray(cols))
         if is_binary:
             deg_t.append(jnp.asarray(degs.astype(np.int32)))
         else:
-            data_t.append(chunked_asarray(vals))
+            data_t.append(jnp.asarray(vals))
 
     sell = SellMatrix(
         cols=tuple(cols_t),
@@ -200,7 +199,7 @@ def sell_spmm_t(m: SellMatrix, x_t: jax.Array,
     spmm_petsc.py:323-395); an explicit ``chunk`` overrides it for
     every tier.
     """
-    from arrow_matrix_tpu.ops.ell import auto_chunk
+    from arrow_matrix_tpu.ops.ell import feature_major_chunk
 
     k = x_t.shape[0]
     outs = []
@@ -211,7 +210,8 @@ def sell_spmm_t(m: SellMatrix, x_t: jax.Array,
             continue
         c = chunk
         if c is None and gather_budget is not None:
-            c = auto_chunk(n_t, k, m_t, gather_budget)
+            c = feature_major_chunk(n_t, k, m_t, gather_budget,
+                                    jnp.dtype(x_t.dtype).itemsize)
         outs.append(ell_spmm_t(
             cols, x_t,
             data=None if m.data is None else m.data[t],

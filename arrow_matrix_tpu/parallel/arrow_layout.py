@@ -38,12 +38,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from arrow_matrix_tpu.ops.arrow_blocks import (
     ArrowBlocks,
@@ -53,8 +49,7 @@ from arrow_matrix_tpu.ops.arrow_blocks import (
     head_block_spmm,
 )
 from arrow_matrix_tpu.parallel.mesh import (blocks_sharding,
-                                             shard_arrow_blocks,
-                                             shard_map_check_kwargs)
+                                             shard_arrow_blocks)
 
 
 @functools.lru_cache(maxsize=None)
@@ -208,7 +203,7 @@ def slim_step_shard_map(blocks: ArrowBlocks, mesh: Mesh,
         mesh=mesh,
         in_specs=(spec_blocks, P(axis)),
         out_specs=P(axis),
-        **shard_map_check_kwargs(),
+        check_vma=False,
     )
     if overlap_slabs <= 1:
         return step
@@ -358,7 +353,7 @@ def wide_step_shard_map(blocks: ArrowBlocks, mesh: Mesh,
         mesh=mesh,
         in_specs=(spec_blocks, P(block_axis)),
         out_specs=P(arm_axis, block_axis),
-        **shard_map_check_kwargs(),
+        check_vma=False,
     )
 
 

@@ -16,38 +16,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-@functools.lru_cache(maxsize=1)
-def _callback_takes_dtype() -> bool:
-    """Whether this jax's make_array_from_callback accepts ``dtype=``
-    (newer jax only) — computed once; put_global runs per-leaf in
-    executor-construction tree_maps."""
-    import inspect
-
-    return "dtype" in inspect.signature(
-        jax.make_array_from_callback).parameters
-
-
-@functools.lru_cache(maxsize=1)
-def _shard_map_check_kwarg() -> str:
-    """Name of shard_map's replication-check kwarg on this jax: it was
-    renamed ``check_rep`` -> ``check_vma`` and the installed jax is
-    unpinned (detect-once idiom, same as _callback_takes_dtype)."""
-    import inspect
-
-    try:
-        from jax import shard_map as sm  # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    return "check_vma" if "check_vma" in params else "check_rep"
-
-
-def shard_map_check_kwargs(check: bool = False) -> dict:
-    """Portable kwargs dict for shard_map's replication check; splat
-    into any shard_map call instead of spelling check_vma/check_rep."""
-    return {_shard_map_check_kwarg(): check}
-
-
 def make_mesh(shape: Optional[Sequence[int]] = None,
               axis_names: Sequence[str] = ("blocks",),
               devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
@@ -146,16 +114,12 @@ def put_global(x, sharding: NamedSharding) -> jax.Array:
            for d in sharding.device_set):
         return jax.device_put(x, sharding)
     x = np.asarray(x)
-    # dtype explicitly when the installed jax accepts it (feature-
-    # detected like jax.distributed.initialize's kwargs in
-    # initialize_multihost — pyproject leaves jax unpinned): a process
-    # holding NO shard of this array (e.g. a replicated table on a
-    # sub-mesh owned by other processes) cannot infer it from its
-    # (empty) shard list.
-    kwargs = {"dtype": x.dtype} if _callback_takes_dtype() else {}
+    # dtype explicitly: a process holding NO shard of this array (e.g.
+    # a replicated table on a sub-mesh owned by other processes) cannot
+    # infer it from its (empty) shard list.
     return jax.make_array_from_callback(
         x.shape, sharding, lambda idx: np.ascontiguousarray(x[idx]),
-        **kwargs)
+        dtype=x.dtype)
 
 
 def build_global(global_shape, sharding: NamedSharding, builder,
@@ -172,12 +136,11 @@ def build_global(global_shape, sharding: NamedSharding, builder,
     host memory is O(one shard) beyond the builder's own inputs.
     """
     dtype = np.dtype(dtype)
-    kwargs = {"dtype": dtype} if _callback_takes_dtype() else {}
     return jax.make_array_from_callback(
         tuple(global_shape), sharding,
         lambda idx: np.ascontiguousarray(
             np.asarray(builder(idx), dtype=dtype)),
-        **kwargs)
+        dtype=dtype)
 
 
 def build_global_parts(global_shape, sharding: NamedSharding, builder,
@@ -310,18 +273,11 @@ def initialize_multihost(coordinator_address: Optional[str] = None,
 
         force_cpu_devices(cpu_devices)
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    kwargs = {}
-    import inspect
-
-    if ("heartbeat_timeout_seconds"
-            in inspect.signature(jax.distributed.initialize).parameters):
-        kwargs["heartbeat_timeout_seconds"] = heartbeat_timeout_seconds
-    # else: older jax without the knob — join with its default rather
-    # than failing every caller that never touched the parameter.
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
-        process_id=process_id, **kwargs)
+        process_id=process_id,
+        heartbeat_timeout_seconds=heartbeat_timeout_seconds)
     return jax.process_index()
 
 

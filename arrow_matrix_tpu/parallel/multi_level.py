@@ -66,7 +66,6 @@ from arrow_matrix_tpu.parallel.mesh import (
     put_global,
     shard_arrow_blocks,
 )
-from arrow_matrix_tpu.utils.transfer import chunked_asarray
 
 
 def gather_budget_for(dense_budget: int) -> int:
@@ -345,24 +344,10 @@ class MultiLevelArrow:
         self.dense_budget = dense_budget
         if kernel not in ("xla", "pallas", "pallas_sell"):
             raise ValueError(f"unknown kernel {kernel!r}")
-        if kernel == "pallas":
-            try:
-                from arrow_matrix_tpu.ops import pallas_blocks  # noqa: F401
-            except ImportError as e:
-                raise ValueError(
-                    f"kernel='pallas' but pallas is unavailable in this "
-                    f"JAX build: {e}") from e
-        if kernel == "pallas_sell":
-            if fmt != "fold":
-                raise ValueError(
-                    "kernel='pallas_sell' is the fused fold kernel "
-                    "(ops/pallas_sell.py); it requires fmt='fold'")
-            try:
-                from arrow_matrix_tpu.ops import pallas_sell  # noqa: F401
-            except ImportError as e:
-                raise ValueError(
-                    f"kernel='pallas_sell' but pallas is unavailable in "
-                    f"this JAX build: {e}") from e
+        if kernel == "pallas_sell" and fmt != "fold":
+            raise ValueError(
+                "kernel='pallas_sell' is the fused fold kernel "
+                "(ops/pallas_sell.py); it requires fmt='fold'")
         self.kernel = kernel
         if overlap_slabs < 1:
             raise ValueError(f"overlap_slabs must be >= 1, got "
@@ -877,7 +862,7 @@ class MultiLevelArrow:
         self.feature_dtype = resolve_feature_dtype(feature_dtype)
         self.perm0 = np.load(os.path.join(in_dir, "perm0.npy"))
         self.inv_perm0 = np.argsort(self.perm0)
-        put = chunked_asarray if device_put else \
+        put = jnp.asarray if device_put else \
             (lambda a: np.asarray(a))
         cols_t, deg_t, data_t = [], [], []
         for t in range(meta["n_tiers"]):
@@ -909,7 +894,7 @@ class MultiLevelArrow:
         """Host (total_rows, k) features *already in level-0 order* ->
         flat sharded device array."""
         if self.mesh is None:
-            return chunked_asarray(x_level0)
+            return jnp.asarray(x_level0)
         return put_global(x_level0, self._rows_sharding())
 
     def set_features(self, x_original: np.ndarray) -> jax.Array:
@@ -918,13 +903,15 @@ class MultiLevelArrow:
         arrow_bench.py:114-116).  Folded mode returns (and ``step``/
         ``run`` carry) the feature-major (k, total_rows) layout — the
         padding-free device layout; ``gather_result`` undoes it."""
+        x_original = np.asarray(x_original)
         n, k = x_original.shape
         if n != self.n:
             raise ValueError(f"expected {self.n} rows, got {n}")
-        padded = np.zeros((self.total_rows, k), dtype=x_original.dtype)
-        padded[:n] = x_original
+        # Level-0 order straight from the input: perm0's identity tail
+        # (indices >= n) names padding rows, zeroed after the gather.
+        feat = x_original[np.minimum(self.perm0, n - 1)]
+        feat[self.perm0 >= n] = 0
         if self.folded:
-            feat = padded[self.perm0]
             if self.feature_dtype is not None \
                     and np.dtype(self.feature_dtype) == np.dtype(np.int8):
                 # graft-classes int8 carriage: symmetric per-feature-row
@@ -937,12 +924,14 @@ class MultiLevelArrow:
                             -127.0, 127.0).astype(np.int8)
                 scale = np.where(amax > 0.0, amax / 127.0,
                                  0.0).astype(np.float32)
-                return (chunked_asarray(q), chunked_asarray(scale))
+                return (jnp.asarray(q), jnp.asarray(scale))
             if self.feature_dtype is not None:
-                feat = feat.astype(self.feature_dtype)  # before the big
-                # transpose copy: half the bytes at 2^24-row scale
-            return chunked_asarray(np.ascontiguousarray(feat.T))
-        return self.place_features(padded[self.perm0])
+                feat = feat.astype(self.feature_dtype)  # before the
+                # upload: half the bytes at 2^24-row scale
+            # The transpose runs on the device: a host transpose of a
+            # 2^22 x 128 block costs seconds per call.
+            return jnp.asarray(feat).T
+        return self.place_features(feat)
 
     def real_row_mask(self, dtype=np.float32) -> jax.Array:
         """(total_rows, 1) device mask: 1 for rows backed by an original
@@ -970,8 +959,9 @@ class MultiLevelArrow:
                 return arr.T[self.inv_perm0][:self.n]
             # bf16-carried results come back as f32 numpy (downstream
             # host math — goldens, norms — has no bf16 arithmetic).
-            return np.asarray(c, dtype=np.float32).T[
-                self.inv_perm0][:self.n]
+            # Transposed on the device, so the host gathers whole rows.
+            return fetch_replicated(jnp.asarray(c, jnp.float32).T)[
+                self.inv_perm0[:self.n]]
         return fetch_replicated(c)[self.inv_perm0][:self.n]
 
     # -- iteration ---------------------------------------------------------
@@ -1187,7 +1177,7 @@ class MultiLevelArrow:
         the jitted step): a single dispatch regardless of iteration
         count — the iteration loop itself is compiler-friendly control
         flow on device, not a host loop of dispatches (which pays
-        dispatch latency per step, badly over remote/tunneled devices).
+        dispatch latency per step).
 
         ``donate=True`` donates the input buffer to the scan carry, so
         only ONE carried feature buffer is resident during the loop

@@ -32,13 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 
-from arrow_matrix_tpu.parallel.mesh import shard_map_check_kwargs
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 @struct.dataclass
@@ -395,13 +390,13 @@ def routed_take(x: jax.Array, route: RouteTables, mesh: Mesh,
         fn = shard_map(
             lambda xl, a, b, c, d: local_fn(xl, None, a, b, c, d),
             mesh=mesh, in_specs=(x_spec, spec, spec, spec, spec),
-            out_specs=x_spec, **shard_map_check_kwargs())
+            out_specs=x_spec, check_vma=False)
         return fn(x, route.local_src, route.local_dst, route.send_idx,
                   route.recv_dst)
     fn = shard_map(local_fn, mesh=mesh,
                    in_specs=(x_spec, x_spec, spec, spec, spec, spec),
                    out_specs=x_spec,
-                   **shard_map_check_kwargs())
+                   check_vma=False)
     return fn(x, init, route.local_src, route.local_dst,
               route.send_idx, route.recv_dst)
 
@@ -558,7 +553,7 @@ def repl_slab_take_t(xt: jax.Array, mesh: Mesh, axis: str,
 
     return shard_map(local_fn, mesh=mesh, in_specs=(P(None, axis),),
                      out_specs=P(None, axis),
-                     **shard_map_check_kwargs())(xt)
+                     check_vma=False)(xt)
 
 
 def repl_slab_scatter_t(slab: jax.Array, k: int, mesh: Mesh, axis: str,
@@ -581,7 +576,7 @@ def repl_slab_scatter_t(slab: jax.Array, k: int, mesh: Mesh, axis: str,
 
     return shard_map(local_fn, mesh=mesh, in_specs=(P(None, axis),),
                      out_specs=P(None, axis),
-                     **shard_map_check_kwargs())(slab)
+                     check_vma=False)(slab)
 
 
 def repl_merge_t(ct: jax.Array, mesh: Mesh, axis: str,
@@ -606,7 +601,7 @@ def repl_merge_t(ct: jax.Array, mesh: Mesh, axis: str,
 
     return shard_map(local_fn, mesh=mesh, in_specs=(P(None, axis),),
                      out_specs=P(None, axis),
-                     **shard_map_check_kwargs())(ct)
+                     check_vma=False)(ct)
 
 
 def routed_take_t(xt: jax.Array, route: RouteTables, mesh: Mesh,
@@ -658,7 +653,7 @@ def routed_take_t(xt: jax.Array, route: RouteTables, mesh: Mesh,
     fn = shard_map(local_fn, mesh=mesh,
                    in_specs=(P(feat_axis, axis), spec, spec, spec, spec),
                    out_specs=P(feat_axis, axis),
-                   **shard_map_check_kwargs())
+                   check_vma=False)
     return fn(xt, route.local_src, route.local_dst, route.send_idx,
               route.recv_dst)
 

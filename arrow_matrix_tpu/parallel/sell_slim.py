@@ -46,25 +46,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import struct
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from scipy import sparse
 
 from arrow_matrix_tpu.io.graphio import CsrLike, num_rows
-from arrow_matrix_tpu.parallel.mesh import (fetch_replicated, put_global,
-                                             shard_map_check_kwargs)
+from arrow_matrix_tpu.parallel.mesh import fetch_replicated, put_global
 from arrow_matrix_tpu.parallel.multi_level import resolve_feature_dtype
 from arrow_matrix_tpu.ops.ell import (
     SLOT_ALIGN,
     align_up,
     block_index_dtype,
     ell_spmm_t,
+    feature_major_chunk,
 )
-
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def degree_ladder(max_deg: int, growth: float = 1.5,
@@ -249,20 +244,66 @@ def _pack_shard_tiers(shares: list[sparse.csr_matrix], ladder: list[int],
     return stack, order, rows_out
 
 
-def _stack_spmm_t(stack: SellShardStack, z_t: jax.Array) -> jax.Array:
+def mesh_gather_budget(mesh: Mesh) -> int:
+    """Per-device bound on one tier's (k, chunk, rows) gather
+    intermediate: the fold's rule (``gather_budget_for``) over one
+    device's memory budget.  Unbounded, a 2^22-row decomposition at
+    k=128 on four v5e chips needs ~19 GB of temporaries per device
+    (CPU compile of the a2a step, PR 21) — over the 16 GB HBM."""
+    from arrow_matrix_tpu.parallel.multi_level import gather_budget_for
+    from arrow_matrix_tpu.utils.platform import device_memory_budget
+
+    return gather_budget_for(device_memory_budget(mesh.devices.flat[0]))
+
+
+def tier_chunks(stack: SellShardStack, k: int, itemsize: int,
+                gather_budget: Optional[int]) -> list:
+    """Per tier ``(slots, rows, chunk)`` as :func:`_stack_spmm_t` will
+    run it (chunk None = the whole slot axis in one gather)."""
+    out = []
+    for cols in stack.cols:
+        m_t, n_t = int(cols.shape[1]), int(cols.shape[2])
+        chunk = (None if gather_budget is None or m_t == 0 else
+                 feature_major_chunk(n_t, k, m_t, gather_budget,
+                                     itemsize))
+        out.append((m_t, n_t, chunk))
+    return out
+
+
+def format_tier_chunks(multi, k: int, itemsize: int) -> str:
+    """``--mem_report`` lines for a mesh executor (SellSlim,
+    SellMultiLevel, SellSpaceShared — the last holds all levels in one
+    stacked body/head): per level, each tier's ``slots x rows:chunk``."""
+    budget = multi.gather_budget
+    ops = getattr(multi, "ops", multi)
+    levels = ops if isinstance(ops, list) else [ops]
+    lines = [f"tier gather chunks (k={k}, budget {budget} B/device; "
+             f"None = whole slot axis, slots x rows:chunk):"]
+    for i, ops in enumerate(levels):
+        for part, stack in (("body", ops.body), ("head", ops.head)):
+            tiers = tier_chunks(stack, k, itemsize, budget)
+            lines.append(f"  level {i} {part}: " + " ".join(
+                f"{m}x{n}:{c}" for m, n, c in tiers if m and n))
+    return "\n".join(lines)
+
+
+def _stack_spmm_t(stack: SellShardStack, z_t: jax.Array,
+                  gather_budget: Optional[int] = None) -> jax.Array:
     """One device's tiered SpMM: operands carry a leading device axis of
-    size 1 inside shard_map.  Returns (k, rows_out)."""
+    size 1 inside shard_map.  Returns (k, rows_out).  ``gather_budget``
+    bounds each tier's gather intermediate (None: unbounded)."""
+    chunks = tier_chunks(stack, z_t.shape[0],
+                         jnp.dtype(z_t.dtype).itemsize, gather_budget)
     outs = []
-    for t, cols in enumerate(stack.cols):
-        m_t = cols.shape[1]
-        n_t = cols.shape[2]
+    for t, (cols, (m_t, n_t, chunk)) in enumerate(zip(stack.cols,
+                                                       chunks)):
         if m_t == 0:
             outs.append(jnp.zeros((z_t.shape[0], n_t), dtype=z_t.dtype))
             continue
         outs.append(ell_spmm_t(
             cols[0], z_t,
             data=None if stack.data is None else stack.data[t][0],
-            deg=stack.deg[t][0]))
+            deg=stack.deg[t][0], chunk=chunk))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -757,12 +798,13 @@ def build_slim_level(matrix: CsrLike, width: int, mesh: Mesh,
 
 
 def _slim_local_step(axis: str, w: int, rows_out: int, hops: int,
-                     rem: int, n_dev: int, body, head, head_unsort,
-                     orig_pos, xt):
+                     rem: int, n_dev: int, gather_budget, body, head,
+                     head_unsort, orig_pos, xt):
     """One device's slim step body, shared by the time-shared
     (make_sharded_step) and space-shared (sell_space) orchestrations —
     masked-psum X_0 broadcast, halo ppermute chains, tiered SpMM, head
-    psum + device-0 overwrite.  All collectives name only ``axis``, so
+    psum + device-0 overwrite (tier gathers bounded by
+    ``gather_budget``).  All collectives name only ``axis``, so
     under a 2-D (lvl, blocks) shard_map they stay within each level
     group by construction.  ``head_unsort``: (w,) tiered head position
     of each head row, already resolved by the caller."""
@@ -813,9 +855,9 @@ def _slim_local_step(axis: str, w: int, rows_out: int, hops: int,
             parts += list(reversed(lo_chain)) + hi_chain
     with jax.named_scope("body_spmm"):
         z = jnp.concatenate(parts, axis=1)
-        out = _stack_spmm_t(body, z)                 # (k, rows_out)
+        out = _stack_spmm_t(body, z, gather_budget)  # (k, rows_out)
     with jax.named_scope("head_reduce"):
-        head_part = _stack_spmm_t(head, xt)
+        head_part = _stack_spmm_t(head, xt, gather_budget)
         c0 = lax.psum(head_part, axis)
         c0w = jnp.take(c0, head_unsort, axis=1)[:, :w]
         out = jnp.where(
@@ -826,7 +868,8 @@ def _slim_local_step(axis: str, w: int, rows_out: int, hops: int,
 
 def make_sharded_step(mesh: Mesh, axis: str, width: int, rows_out: int,
                       hops: int = 0, rem: int = 0,
-                      feat_axis: Optional[str] = None):
+                      feat_axis: Optional[str] = None,
+                      gather_budget: Optional[int] = None):
     """Raw (traceable) shard_map'd slim step for one level:
     ``step(body, head, head_unsort, orig_pos, xt) -> ct`` on
     feature-major (k, total_out) arrays.
@@ -843,7 +886,8 @@ def make_sharded_step(mesh: Mesh, axis: str, width: int, rows_out: int,
 
     def local_step(body, head, head_unsort, orig_pos, xt):
         return _slim_local_step(axis, w, rows_out, hops, rem, n_dev,
-                                body, head, head_unsort, orig_pos, xt)
+                                gather_budget, body, head, head_unsort,
+                                orig_pos, xt)
 
     spec = lambda tree: jax.tree_util.tree_map(lambda _: P(axis), tree)
 
@@ -854,7 +898,7 @@ def make_sharded_step(mesh: Mesh, axis: str, width: int, rows_out: int,
             local_step, mesh=mesh,
             in_specs=(spec(body), spec(head), P(), P(axis), x_spec),
             out_specs=x_spec,
-            **shard_map_check_kwargs(),
+            check_vma=False,
         )(body, head, head_unsort, orig_pos, xt)
 
     return step
@@ -1010,8 +1054,10 @@ class SellSlim:
             np.arange(self.shard_len * self.n_dev), ops.body_order,
             self.shard_len, self.shard_len * self.n_dev)
         self.overlap_slabs = int(overlap_slabs)
+        self.gather_budget = mesh_gather_budget(mesh)
         raw_step = make_sharded_step(mesh, axis, width, ops.rows_out,
-                                     hops=ops.hops, rem=ops.rem)
+                                     hops=ops.hops, rem=ops.rem,
+                                     gather_budget=self.gather_budget)
         # Wrapper order: repl outermost, overlap inside — each replica
         # group overlap-schedules its own k/c slab (S must divide k/c).
         step_sched = _overlap_step(raw_step, self.overlap_slabs)
@@ -1312,9 +1358,11 @@ class SellMultiLevel:
         self._ideal_route_units = commstats.ideal_routing_bytes(
             padded, n_dev, 1, itemsize=1)
 
+        self.gather_budget = mesh_gather_budget(mesh)
         steps = [make_sharded_step(mesh, axis, width, ops.rows_out,
                                    hops=ops.hops, rem=ops.rem,
-                                   feat_axis=feat_axis)
+                                   feat_axis=feat_axis,
+                                   gather_budget=self.gather_budget)
                  for ops in self.ops]
         feat_shard = NamedSharding(mesh, P(feat_axis, axis))
 

@@ -53,8 +53,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from arrow_matrix_tpu.io.graphio import num_rows
 from arrow_matrix_tpu.ops.ell import align_up
 from arrow_matrix_tpu.parallel.mesh import (fetch_replicated, make_mesh,
-                                             put_global,
-                                             shard_map_check_kwargs)
+                                             put_global)
 from arrow_matrix_tpu.parallel.multi_level import resolve_feature_dtype
 from arrow_matrix_tpu.parallel.sell_slim import (
     _banded_reach,
@@ -65,6 +64,7 @@ from arrow_matrix_tpu.parallel.sell_slim import (
     _gather_carried,
     _live,
     _pack_shard_tiers,
+    mesh_gather_budget,
     _positions_inv,
     _remap_body_cols,
     _remap_head_cols,
@@ -128,6 +128,7 @@ class SellSpaceShared:
         self.lvl_axis = lvl_axis
         self.axis = axis
         self.k_levels = k_levels
+        self.gather_budget = mesh_gather_budget(mesh)
         n_dev = mesh.shape[axis]
         w = width
 
@@ -296,7 +297,7 @@ class SellSpaceShared:
         # (its lvl slice); the shared body wants the resolved (w,).
         def local_step(body, head, head_unsort, orig_pos, xt):
             return _slim_local_step(axis, w, rows_out, hops, rem,
-                                    n_dev,
+                                    n_dev, self.gather_budget,
                                     body, head, head_unsort[0],
                                     orig_pos, xt)
 
@@ -310,7 +311,7 @@ class SellSpaceShared:
                 in_specs=(spec(body), spec(head), P(lvl_axis),
                           P((lvl_axis, axis)), x_spec),
                 out_specs=x_spec,
-                **shard_map_check_kwargs(),
+                check_vma=False,
             )(body, head, head_unsort, orig_pos, xt)
 
         def space_step(xt, body, head, head_unsort, orig_pos,

@@ -36,22 +36,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 
 from arrow_matrix_tpu.parallel.mesh import (
     build_global_parts,
     fetch_replicated,
     largest_replication,  # noqa: F401  (re-export: hoisted to mesh.py)
     put_global,
-    shard_map_check_kwargs,
 )
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from scipy import sparse
-
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from arrow_matrix_tpu.ops.ell import align_up, ell_pack, ell_spmm
 
@@ -257,7 +251,7 @@ class SpMM15D:
             in_specs=(P(rows_axis, repl_axis), P(rows_axis, repl_axis),
                       P(rows_axis)),
             out_specs=P(rows_axis, repl_axis),
-            **shard_map_check_kwargs(),
+            check_vma=False,
         ))
 
     # -- feature placement -------------------------------------------------

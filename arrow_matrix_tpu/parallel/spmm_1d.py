@@ -42,7 +42,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from arrow_matrix_tpu.parallel.mesh import (
@@ -50,14 +50,9 @@ from arrow_matrix_tpu.parallel.mesh import (
     build_global_parts,
     fetch_replicated,
     put_global,
-    shard_map_check_kwargs,
+    
 )
 from scipy import sparse
-
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from arrow_matrix_tpu.ops.ell import align_up, ell_pack
 
@@ -416,7 +411,7 @@ class MatrixSlice1D:
             local_step, mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis)),
             out_specs=P(axis),
-            **shard_map_check_kwargs(),
+            check_vma=False,
         ))
 
     # -- feature placement -------------------------------------------------

@@ -29,8 +29,8 @@ aliases=("_cond",))``
 ``flock_witness(node)``
     Context manager registering a held ``fcntl.flock`` region as the
     graph vertex ``flock:<node>`` (no-op context when the witness is
-    off).  The package's two flock disciplines — the artifacts sidecar
-    lock and the preemption registry — both route through it.
+    off).  The package's flock discipline — the artifacts sidecar lock —
+    routes through it.
 
 The witness
 -----------
@@ -115,8 +115,8 @@ DECLARED_ORDER: Tuple[Tuple[str, str], ...] = (
 )
 
 #: Known flock vertices (``flock:<node>``) — the sidecar lock helper in
-#: utils/artifacts.py and the preemption registry in utils/platform.py.
-FLOCK_NODES: Tuple[str, ...] = ("flock:sidecar", "flock:preempt_registry")
+#: utils/artifacts.py.
+FLOCK_NODES: Tuple[str, ...] = ("flock:sidecar",)
 
 
 class LockOrderViolation(RuntimeError):

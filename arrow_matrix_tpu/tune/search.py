@@ -112,16 +112,6 @@ def _build_executor(levels, width: int, cand: Candidate):
                            **kwargs)
 
 
-def _golden_output(levels, width: int, x_host: np.ndarray) -> np.ndarray:
-    """The golden: the DEFAULT fold executor — the ``ops/sell.py``
-    ``sell_spmm_t`` path — stepped once, gathered back to original row
-    order, f32."""
-    multi = _build_executor(levels, width, Candidate("default"))
-    x = multi.set_features(x_host)
-    return np.asarray(multi.gather_result(multi.step(x)),
-                      dtype=np.float32)
-
-
 def _flight_install(name: str) -> None:
     """Best-effort black-box recorder in a tune child (bench.py's
     ``_install_flight`` contract: a SIGKILLed child still leaves its
@@ -146,11 +136,14 @@ def candidate_child_main(cfg: dict) -> dict:
     Prints nothing itself — the caller emits the returned dict as the
     final JSON line (``utils/artifacts.parse_last_json_line`` contract).
     """
-    if (os.environ.get("AMT_BENCH_FORCECPU") == "1"
-            or os.environ.get("AMT_BENCH_CPU") == "1"):
-        from arrow_matrix_tpu.utils.platform import force_cpu_devices
+    from arrow_matrix_tpu.utils.platform import (
+        enable_compile_cache,
+        force_cpu_devices,
+    )
 
+    if cfg.get("platform") == "cpu":
         force_cpu_devices()
+    enable_compile_cache()
     name = cfg["candidate"]["name"]
     _flight_install(f"tune_{name}")
     from arrow_matrix_tpu.obs import chained_iteration_ms
@@ -168,7 +161,13 @@ def candidate_child_main(cfg: dict) -> dict:
     bit_identical = None
     rel_frobenius = None
     golden_path = cfg.get("golden_path")
-    if golden_path:
+    if cfg.get("write_golden"):
+        # The golden is the default fold executor stepped once — on the
+        # device, so it runs here in a child like every candidate.
+        np.save(golden_path, np.asarray(
+            multi.gather_result(multi.step(x)), dtype=np.float32))
+        bit_identical, rel_frobenius = True, 0.0
+    elif golden_path:
         golden = np.load(golden_path)
         mine = np.asarray(multi.gather_result(multi.step(x)),
                           dtype=np.float32)
@@ -187,7 +186,7 @@ def candidate_child_main(cfg: dict) -> dict:
 
 
 def _spawn_tune_candidate(cand: Candidate, cfg: dict,
-                          timeout_s: float, platform: str) -> dict:
+                          timeout_s: float) -> dict:
     """One candidate subprocess -> its parsed JSON (or an error dict);
     every failure shape is contained to the returned dict, the
     ``bench.py _spawn_candidate`` contract."""
@@ -196,12 +195,10 @@ def _spawn_tune_candidate(cand: Candidate, cfg: dict,
     child_cfg = dict(cfg)
     child_cfg["candidate"] = {"name": cand.name, "build": cand.build,
                               "kernel_opts": cand.kernel_opts}
-    env = dict(os.environ, AMT_TUNE_CFG=json.dumps(child_cfg))
-    if platform == "cpu":
-        env["AMT_BENCH_FORCECPU"] = "1"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   os.path.abspath(os.path.join("bench_cache",
-                                                "xla_cache")))
+    from arrow_matrix_tpu.utils.platform import compile_cache_env
+
+    env = compile_cache_env(dict(os.environ,
+                                 AMT_TUNE_CFG=json.dumps(child_cfg)))
     try:
         proc = subprocess.run(
             [sys.executable, "-m", "arrow_matrix_tpu.tune",
@@ -340,13 +337,12 @@ def search(source: dict, k: int, *, iters: int = 3,
                 "plan": cached.to_dict(),
             }
 
-    platform = "cpu"
-    try:
-        import jax
+    # One process per chip: the platform comes from a discovery child,
+    # never from a backend in this (parent) process.
+    from arrow_matrix_tpu.utils.platform import child_platform
 
-        platform = jax.devices()[0].platform
-    except (ImportError, RuntimeError):  # searchable without a device
-        pass
+    found = child_platform()
+    platform = found["platform"]
     evaluator = "cpu-interpret" if platform == "cpu" else platform
 
     if isinstance(lens_model, (str, os.PathLike)):
@@ -366,8 +362,8 @@ def search(source: dict, k: int, *, iters: int = 3,
                              for c in generated))
             extra = list(extra or []) + generated
     cands, pruned = enumerate_candidates(
-        fp, k, platform=platform, allow_int8=allow_int8,
-        restrict=restrict, traffic_class=traffic_class, extra=extra,
+        fp, k, platform=platform, budget_bytes=found["hbm_budget"],
+        allow_int8=allow_int8, restrict=restrict, traffic_class=traffic_class, extra=extra,
         lens_model=lens_model)
     for name, why in pruned.items():
         _say(f"pruned {name}: {why}")
@@ -388,18 +384,23 @@ def search(source: dict, k: int, *, iters: int = 3,
     run_dir = run_dir or os.path.join("bench_cache", "tune_runs", h)
     os.makedirs(run_dir, exist_ok=True)
     golden_path = os.path.join(run_dir, f"golden_k{int(k)}.npy")
-    from arrow_matrix_tpu.utils.graphs import random_dense
-
-    x_host = random_dense(fp["n"], int(k), seed=GOLDEN_SEED)
-    np.save(golden_path, _golden_output(levels, width, x_host))
-
     cfg = {"source": source, "k": int(k), "iters": int(iters),
-           "golden_path": os.path.abspath(golden_path)}
+           "golden_path": os.path.abspath(golden_path),
+           "platform": platform}
+    # The default candidate's child writes the golden first; every
+    # other candidate is compared against it.
+    if not any(c.name == "default" for c in cands):
+        gold = _spawn_tune_candidate(Candidate("default"),
+                                     dict(cfg, write_golden=True),
+                                     timeout_s)
+        if gold.get("error"):
+            raise RuntimeError(f"golden run failed: {gold['error']}")
     results: Dict[str, dict] = {}
-    for cand in cands:
+    for cand in sorted(cands, key=lambda c: c.name != "default"):
         _say(f"racing {cand.name}")
         results[cand.name] = _spawn_tune_candidate(
-            cand, cfg, timeout_s, platform)
+            cand, dict(cfg, write_golden=cand.name == "default"),
+            timeout_s)
         r = results[cand.name]
         _say(f"  {cand.name}: ms={r.get('ms')} "
              f"bit_identical={r.get('bit_identical')} "

@@ -255,7 +255,7 @@ def enumerate_candidates(fp: dict, k: int, *,
                 supported_feature_width)
             if not interpret and not supported_feature_width(k):
                 pruned[c.name] = ("streaming pallas_sell needs "
-                                  f"k % 16 == 0 on chip (k={k})")
+                                  f"k % 16 == 0 and k | 128 on chip (k={k})")
                 continue
             if interpret and "ring" in c.kernel_opts:
                 pruned[c.name] = ("DMA ring depth is a stream-only "

@@ -68,7 +68,7 @@ MID_WIDTH = 64
 #: bytes, so keep the VMEM tile narrow, the DMA ring deep (latency
 #: hiding over bandwidth), and the slab short; head tiers are dense-ish
 #: — wide rows amortize the launch, so widen the tile, keep the ring
-#: shallow, and let the slab grow to the full scalar-prefetch budget
+#: shallow, and let the slab grow to the full slab budget
 #: (slab_blocks=None).
 FAMILY_POLICY: Dict[str, Tuple[int, int, int, Optional[int]]] = {
     "tail": (64, 8, 4, 4),
@@ -129,7 +129,7 @@ def synthesize_schedule(fp: dict, *,
         fam = ladder_family(w)
         row_block, wave, ring, slab_blocks = FAMILY_POLICY[fam]
         if slab_blocks is None:
-            budget = None   # full scalar-prefetch budget: long slabs
+            budget = None   # full slab budget: long slabs
         else:
             # Bound the slab to ``slab_blocks`` row blocks of cols
             # (int32: m_t * 4 B per row) — slab_rows() floors at one
@@ -282,24 +282,25 @@ def _normalized_points(prog: dict) -> List[dict]:
     budget) points of one program — EXACTLY the numbers
     ``sell_tier_spmm_packed`` would execute, so the certified metas and
     the executed calls cannot drift (the meta-first discipline)."""
-    granule = int(prog["granule"])
+    from arrow_matrix_tpu.ops import pallas_sell as ps
+
     default_budget = int(prog["smem_cols_budget"])
     points = []
     for e in prog["schedule"]:
         m_t, rows = int(e["m_t"]), int(e["rows"])
         if m_t < 1 or rows < 1:
             continue
-        rb = int(e.get("row_block", 256))
-        aligned_rows = -(-max(rows, 1) // granule) * granule
-        rb = min(rb, aligned_rows)
-        rb = max(granule, rb - rb % granule)
+        carriage = e.get("carriage", "f32")
+        rb = ps._tier_row_block(
+            rows, int(e.get("row_block", 256)),
+            ps.out_rows_per_line(int(prog["k"]), carriage))
         w = min(int(e.get("wave", 16)), rb)
         while w > 1 and rb % w:
             w -= 1
         points.append({
             "m_t": m_t, "rows": rows, "row_block": rb, "wave": w,
             "ring": int(e.get("ring", 2)),
-            "carriage": e.get("carriage", "f32"),
+            "carriage": carriage,
             "budget": int(e.get("smem_cols_budget", default_budget)),
         })
     return points
@@ -310,20 +311,18 @@ def _program_metas(prog: dict) -> List[dict]:
     generated program (lazy jax import — certification time only)."""
     from arrow_matrix_tpu.ops import pallas_sell as ps
 
-    granule = int(prog["granule"])
     k = int(prog["k"])
     n = int(prog["n"])
-    n_lines = max(1, -(-n // granule))
     binary = bool(prog["binary"])
     metas = []
     for pt in _normalized_points(prog):
+        n_lines = max(1, -(-n // ps.line_geometry(k, pt["carriage"])[1]))
         rb = pt["row_block"]
         rows_pad = -(-pt["rows"] // rb) * rb
         slab = min(ps.slab_rows(pt["m_t"], rb, pt["budget"]), rows_pad)
         metas.append(ps.slab_call_meta(
             pt["m_t"], slab, k, rb, binary, True, pt["wave"],
-            pt["ring"], n_lines=n_lines, carriage=pt["carriage"],
-            smem_cols_budget=pt["budget"]))
+            pt["ring"], n_lines=n_lines, carriage=pt["carriage"]))
     return metas
 
 
@@ -348,17 +347,18 @@ def _program_witness(prog: dict):
     x_t = jnp.asarray(
         np.linspace(-1.0, 1.0, k * n_table, dtype=np.float32)
         .reshape(k, n_table))
-    x_packed = ps.pack_features_t(x_t)
     try:
         for rb, wave, ring, carriage in configs:
             rows, m_t = min(rb, 32), 3
             cols = jnp.full((m_t, rows), n_table - 1, dtype=jnp.int32)
             deg = jnp.full((rows,), m_t, dtype=jnp.int32)
+            x_packed = ps.pack_features_t(x_t, carriage)
             vec = ps.sell_tier_spmm_packed(
-                cols, x_packed, deg=deg, stream=False, interpret=True,
-                row_block=rb, wave=wave, feature_dtype=carriage)
+                cols, x_packed, k, deg=deg, stream=False,
+                interpret=True, row_block=rb, wave=wave,
+                feature_dtype=carriage)
             st = ps.sell_tier_spmm_packed(
-                cols, x_packed, deg=deg, stream=True, interpret=True,
+                cols, x_packed, k, deg=deg, stream=True, interpret=True,
                 row_block=rb, wave=wave, ring=ring,
                 feature_dtype=carriage)
             if not np.array_equal(np.asarray(vec), np.asarray(st)):

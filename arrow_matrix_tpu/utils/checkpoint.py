@@ -20,6 +20,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import shutil
 import sys
 from typing import Optional
 
@@ -80,6 +81,11 @@ def _read_meta(path: str) -> Optional[dict]:
         return None
 
 
+#: Suffixes of a save in flight (save_state's staging/previous
+#: directories and the npz temp file): never a checkpoint stem.
+_TRANSIENT = (".saving", ".prev", ".tmp.npz")
+
+
 def list_checkpoints(ckpt_dir: str, prefix: str = "ck_") -> list:
     """Stems of every checkpoint under ``ckpt_dir`` with ``prefix``,
     across both backends (orbax directories and ``.npz`` files),
@@ -92,7 +98,7 @@ def list_checkpoints(ckpt_dir: str, prefix: str = "ck_") -> list:
         return []
     for e in entries:
         p = os.path.join(ckpt_dir, e)
-        if not e.startswith(prefix):
+        if not e.startswith(prefix) or e.endswith(_TRANSIENT):
             continue
         if e.endswith(".npz"):
             stems.add(p[: -len(".npz")])
@@ -221,10 +227,24 @@ def save_state(path: str, x: jax.Array, step: int,
     path = os.path.abspath(path)
     ocp = _orbax()
     if ocp is not None:
+        # orbax's force=True deletes the old checkpoint before it writes
+        # the new one, so a kill mid-save would lose both.  Write beside
+        # it, then swap: the previous state survives as ``.prev`` until
+        # the new one is in place (load_state falls back to it).
+        staging, prev = path + ".saving", path + ".prev"
         ckpt = ocp.PyTreeCheckpointer()
-        ckpt.save(path, {"x": x, "step": np.int64(step)}, force=True)
+        ckpt.save(staging, {"x": x, "step": np.int64(step)}, force=True)
         if jax.process_index() == 0:
+            if os.path.isdir(path):
+                shutil.rmtree(prev, ignore_errors=True)
+                os.rename(path, prev)
+            os.rename(staging, path)
             _write_meta(path, step, layout)
+            shutil.rmtree(prev, ignore_errors=True)
+        if jax.process_count() > 1:
+            from jax.experimental import multihost_utils
+
+            multihost_utils.sync_global_devices(f"save_state:{path}")
         return
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     from arrow_matrix_tpu.parallel.mesh import fetch_replicated
@@ -296,6 +316,8 @@ def load_state(path: str, like: Optional[jax.Array] = None,
     """
     path = os.path.abspath(path)
     ocp = _orbax()
+    if not os.path.exists(path) and os.path.isdir(path + ".prev"):
+        path += ".prev"   # a save was cut between its two renames
     if os.path.isdir(path) and ocp is None:
         raise RuntimeError(
             f"checkpoint at {path} was written with orbax, which is not "

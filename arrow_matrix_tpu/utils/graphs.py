@@ -128,7 +128,14 @@ def random_dense(rows: int, cols: int, seed: int | None = None,
                  dtype=np.float32) -> np.ndarray:
     """Uniform [-1, 1) dense matrix (reference arrow/common/utils.py:90-99)."""
     rng = np.random.default_rng(seed)
-    return rng.uniform(-1.0, 1.0, size=(rows, cols)).astype(dtype)
+    # Row slices draw the same stream as one call (C order) without a
+    # whole-matrix f64 intermediate.
+    out = np.empty((rows, cols), dtype=dtype)
+    step = max((1 << 22) // max(cols, 1), 1)
+    for s in range(0, rows, step):
+        out[s:s + step] = rng.uniform(-1.0, 1.0,
+                                      size=(min(step, rows - s), cols))
+    return out
 
 
 def grid_graph(side: int, dtype=np.float32) -> sparse.csr_matrix:

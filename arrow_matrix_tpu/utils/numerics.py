@@ -68,10 +68,21 @@ def relative_tolerance(row_nnz: float, iters: int = 1) -> float:
         * max(int(iters), 1)
 
 
+_SLICE = 1 << 20   # elements per f64 slice in relative_error
+
+
 def relative_error(got: np.ndarray, want: np.ndarray) -> float:
     """Relative Frobenius error ||got - want|| / ||want|| (the
     reference's validation metric, spmm_15d_main.py:195-197)."""
-    got = np.asarray(got, dtype=np.float64)
-    want = np.asarray(want, dtype=np.float64)
-    return float(np.linalg.norm(got - want) /
-                 max(np.linalg.norm(want), 1e-30))
+    # f64 sums over slices: whole-array f64 copies of a 2^22 x 128
+    # result would take ~13 GB of host memory.
+    got, want = (a.reshape(-1) for a in np.broadcast_arrays(
+        np.asarray(got), np.asarray(want)))
+    num = den = 0.0
+    for s in range(0, got.size, _SLICE):
+        g = got[s:s + _SLICE].astype(np.float64)
+        w = want[s:s + _SLICE].astype(np.float64)
+        g -= w
+        num += float(np.dot(g, g))
+        den += float(np.dot(w, w))
+    return float(np.sqrt(num) / max(np.sqrt(den), 1e-30))

@@ -1,6 +1,8 @@
 """Iteration-state checkpoint/resume (utils/checkpoint.py): runtime
 state persists beyond the reference's artifact-only resume point."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -201,3 +203,27 @@ def test_load_state_emits_resumed_flight_event(tmp_path):
     ev = [e for e in rec.events if e.get("name") == "resumed"]
     assert ev and ev[0]["kind"] == "heal"
     assert ev[0]["data"]["step"] == 7
+
+
+def test_interrupted_save_keeps_the_previous_checkpoint(tmp_path):
+    """A save cut between its two renames leaves the previous state as
+    ``.prev``: load falls back to it, and listings skip the transient
+    names (a kill mid-save must not lose the last good checkpoint)."""
+    import jax.numpy as jnp
+
+    from arrow_matrix_tpu.utils.checkpoint import list_checkpoints
+
+    path = str(tmp_path / "ck_r1")
+    x = jnp.arange(8, dtype=jnp.float32).reshape(2, 4)
+    save_state(path, x, 3, layout="t")
+    save_state(path, x * 2, 4, layout="t")
+    got, step = load_state(path, layout="t")
+    assert step == 4
+    np.testing.assert_array_equal(np.asarray(got), 2 * np.asarray(x))
+    if not os.path.isdir(path):
+        pytest.skip("npz backend: the save is a single atomic replace")
+    os.rename(path, path + ".prev")          # cut after the first rename
+    os.makedirs(path + ".saving")            # the new save, unfinished
+    got, step = load_state(path)
+    assert step == 4
+    assert list_checkpoints(str(tmp_path)) == []

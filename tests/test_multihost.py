@@ -42,7 +42,7 @@ except Exception as e:
     sys.exit(0)
 import jax, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from arrow_matrix_tpu.parallel.mesh import make_mesh, put_global
 mesh = make_mesh((4,), ("blocks",))
 f = jax.jit(shard_map(lambda v: jax.lax.psum(v, "blocks"), mesh=mesh,

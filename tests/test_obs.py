@@ -165,14 +165,14 @@ def test_account_collectives_on_shard_map_psum():
     from arrow_matrix_tpu.parallel.arrow_layout import shard_map
     from arrow_matrix_tpu.parallel.mesh import (
         make_mesh,
-        shard_map_check_kwargs,
+        
     )
 
     mesh = make_mesh((2,), ("blocks",), devices=jax.devices()[:2])
     f = jax.jit(shard_map(
         lambda v: jax.lax.psum(v, "blocks"), mesh=mesh,
         in_specs=P("blocks"), out_specs=P(),
-        **shard_map_check_kwargs()))
+        check_vma=False))
     x = jnp.ones((4, 8), jnp.float32)
 
     reg = obs.MetricsRegistry()

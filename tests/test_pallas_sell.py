@@ -86,9 +86,9 @@ def test_stream_dma_path_matches_vectorized():
     m, x_t = _synthetic_binary(1024, 64, 5, 16, seed=11)
     x_packed = pack_features_t(x_t)
     cols, deg = m.cols[0], m.deg[0]
-    ref = sell_tier_spmm_packed(cols, x_packed, deg=deg,
+    ref = sell_tier_spmm_packed(cols, x_packed, 16, deg=deg,
                                 stream=False, interpret=True)
-    got = sell_tier_spmm_packed(cols, x_packed, deg=deg,
+    got = sell_tier_spmm_packed(cols, x_packed, 16, deg=deg,
                                 stream=True, wave=4, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
@@ -110,12 +110,13 @@ def test_slab_rows_degenerate_cases():
     # forward progress: exactly one row block per slab.
     assert slab_rows(10**9, 64, smem_cols_budget=1 << 18) == 64
     # Normal case: the slab is a whole multiple of the row block and
-    # fits the budget (per_row = m_t * 4 bytes of int32 cols).
+    # fits the budget (per_row = 4 bytes of int32 cols per slot, slots
+    # padded to a multiple of 8 sublanes).
     s = slab_rows(6, 64, smem_cols_budget=64 * 24 * 4)
-    assert s % 64 == 0 and s * 6 * 4 <= 64 * 24 * 4
+    assert s % 64 == 0 and s * 8 * 4 <= 64 * 24 * 4
     # Explicit budget wins over the module-level env default, and the
-    # arithmetic is exact: budget 512 B / (4 slots * 4 B) = 32 rows.
-    assert slab_rows(4, 8, smem_cols_budget=512) == 32
+    # arithmetic is exact: budget 512 B / (8 slot rows * 4 B) = 16 rows.
+    assert slab_rows(4, 8, smem_cols_budget=512) == 16
     # m_t = 0 (the zero tier) must not divide by zero.
     assert slab_rows(0, 64, smem_cols_budget=1024) >= 64
 
@@ -138,9 +139,9 @@ def test_ring_depth_variants_match_double_buffer(ring):
     m, x_t = _synthetic_binary(1024, 64, 5, 16, seed=11)
     x_packed = pack_features_t(x_t)
     cols, deg = m.cols[0], m.deg[0]
-    ref = sell_tier_spmm_packed(cols, x_packed, deg=deg,
+    ref = sell_tier_spmm_packed(cols, x_packed, 16, deg=deg,
                                 stream=True, wave=4, interpret=True)
-    got = sell_tier_spmm_packed(cols, x_packed, deg=deg,
+    got = sell_tier_spmm_packed(cols, x_packed, 16, deg=deg,
                                 stream=True, wave=4, ring=ring,
                                 interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
@@ -150,31 +151,41 @@ def test_ring_validation():
     m, x_t = _synthetic_binary(256, 64, 3, 16, seed=1)
     x_packed = pack_features_t(x_t)
     with pytest.raises(ValueError, match="ring"):
-        sell_tier_spmm_packed(m.cols[0], x_packed, deg=m.deg[0],
+        sell_tier_spmm_packed(m.cols[0], x_packed, x_t.shape[0], deg=m.deg[0],
                               stream=True, ring=0, interpret=True)
 
 
 def test_pack_features_granule_lines():
-    x_t = jnp.arange(2 * 10, dtype=jnp.float32).reshape(2, 10)
+    x_t = jnp.arange(16 * 10, dtype=jnp.float32).reshape(16, 10)
     packed = pack_features_t(x_t)
     n_pad = ((10 + GRANULE - 1) // GRANULE) * GRANULE
-    assert packed.shape == (n_pad // GRANULE, GRANULE * 2)
+    assert packed.shape == (n_pad // GRANULE, GRANULE * 16)
+    assert packed.dtype == jnp.int32
     # Line 0 holds rows 0..7 of the row-major view, contiguous.
     np.testing.assert_array_equal(
-        np.asarray(packed)[0], np.asarray(x_t.T[:GRANULE]).reshape(-1))
+        np.asarray(packed)[0].view(np.float32),
+        np.asarray(x_t.T[:GRANULE]).reshape(-1))
+    # bf16: 16 rows per 128-word line; word q of a row carries features
+    # q (low half) and q + k/2 (high half).
+    bf = np.asarray(pack_features_t(x_t, "bf16"))
+    assert bf.shape == (1, 128)
+    halves = bf[0].view(np.uint16).reshape(16, 8, 2)
+    want = np.asarray(x_t.T.astype(jnp.bfloat16)).view(np.uint16)
+    np.testing.assert_array_equal(halves[:10, :, 0], want[:, :8])
+    np.testing.assert_array_equal(halves[:10, :, 1], want[:, 8:])
 
 
 def test_validation():
     m, x_t = _synthetic_binary(256, 64, 3, 10, seed=1)
     x_packed = pack_features_t(x_t)
     with pytest.raises(ValueError, match="k % 16"):
-        sell_tier_spmm_packed(m.cols[0], x_packed, deg=m.deg[0],
+        sell_tier_spmm_packed(m.cols[0], x_packed, x_t.shape[0], deg=m.deg[0],
                               stream=True, interpret=True)
     with pytest.raises(ValueError, match="interpret-only"):
-        sell_tier_spmm_packed(m.cols[0], x_packed, deg=m.deg[0],
+        sell_tier_spmm_packed(m.cols[0], x_packed, x_t.shape[0], deg=m.deg[0],
                               stream=False, interpret=False)
     with pytest.raises(ValueError, match="requires deg"):
-        sell_tier_spmm_packed(m.cols[0], x_packed, interpret=True)
+        sell_tier_spmm_packed(m.cols[0], x_packed, 10, interpret=True)
     assert supported_feature_width(16)
     assert supported_feature_width(128)
     assert not supported_feature_width(8)
@@ -241,10 +252,10 @@ def test_certified_parity_matrix(ring, row_block, k, feature_dtype):
         feature_dtype=feature_dtype) is None
 
     m, x_t = _parity_problem(k, seed=ring * 1000 + row_block + k)
-    x_packed = pack_features_t(x_t)
+    x_packed = pack_features_t(x_t, feature_dtype)
     cols, deg = m.cols[0], m.deg[0]
     got = np.asarray(sell_tier_spmm_packed(
-        cols, x_packed, deg=deg, stream=True, interpret=True,
+        cols, x_packed, k, deg=deg, stream=True, interpret=True,
         row_block=row_block, wave=4, ring=ring,
         feature_dtype=feature_dtype))
     if feature_dtype == "f32":
@@ -269,11 +280,11 @@ def test_bf16_stream_bitwise_matches_vectorized(k):
     # Same accumulation order on both interpret bodies -> the bf16
     # carriage answers bit-identically regardless of the DMA path.
     m, x_t = _parity_problem(k, seed=31 + k)
-    x_packed = pack_features_t(x_t)
+    x_packed = pack_features_t(x_t, "bf16")
     cols, deg = m.cols[0], m.deg[0]
-    vec = sell_tier_spmm_packed(cols, x_packed, deg=deg, stream=False,
+    vec = sell_tier_spmm_packed(cols, x_packed, k, deg=deg, stream=False,
                                 interpret=True, feature_dtype="bf16")
-    st = sell_tier_spmm_packed(cols, x_packed, deg=deg, stream=True,
+    st = sell_tier_spmm_packed(cols, x_packed, k, deg=deg, stream=True,
                                interpret=True, wave=4, ring=2,
                                feature_dtype="bf16")
     np.testing.assert_array_equal(np.asarray(st), np.asarray(vec))

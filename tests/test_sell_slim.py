@@ -496,3 +496,38 @@ def test_sliced_halo_exchange_fewer_bytes():
         whole, o.body, o.head, o.head_unsort, o.orig_pos, xt)
     assert (sliced_stats["collective-permute"]["bytes"]
             < whole_stats["collective-permute"]["bytes"])
+
+
+@pytest.mark.parametrize("budget", [None, 1 << 14])
+def test_tier_gather_budget_bounds_chunks(monkeypatch, budget):
+    """The mesh tiers take their gather bound from the device (the
+    fold's rule); a tight one turns tiers into slot chunks — single-slot
+    gathers below one tile — and leaves the result unchanged."""
+    from arrow_matrix_tpu.parallel import sell_slim
+    from arrow_matrix_tpu.parallel.sell_slim import (
+        SellMultiLevel,
+        format_tier_chunks,
+        tier_chunks,
+    )
+
+    if budget is not None:
+        monkeypatch.setattr(sell_slim, "mesh_gather_budget",
+                            lambda mesh: budget)
+    a = barabasi_albert(1 << 10, 8, seed=7)
+    levels = arrow_decomposition(a, arrow_width=128, max_levels=3,
+                                 block_diagonal=True, seed=7)
+    multi = SellMultiLevel(levels, 128, make_mesh((4,), ("blocks",)))
+    k = 16
+    chunks = [c for ops in multi.ops for stack in (ops.body, ops.head)
+              for m, rows, c in tier_chunks(stack, k, 4,
+                                            multi.gather_budget)
+              if m and rows]
+    if budget is None:
+        assert multi.gather_budget > 0 and set(chunks) == {None}
+    else:
+        assert 1 in chunks
+    assert "tier gather chunks" in format_tier_chunks(multi, k, 4)
+    x = random_dense(a.shape[0], k, seed=3)
+    got = multi.gather_result(multi.step(multi.set_features(x)))
+    np.testing.assert_allclose(got, np.asarray(a @ x), rtol=1e-4,
+                               atol=1e-4)

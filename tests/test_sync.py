@@ -67,7 +67,7 @@ def test_witness_off_by_default_is_zero_overhead():
     lock = threading.Lock()
     assert sync.witnessed("arrow_server", lock) is lock
     cm = sync.flock_witness("sidecar")
-    assert cm is sync.flock_witness("preempt_registry")  # shared null
+    assert cm is sync.flock_witness("other")  # shared null
     with cm:
         pass
 
@@ -165,8 +165,7 @@ def test_declared_order_matches_package_constants():
     snap = reg.snapshot()
     assert sorted(tuple(e) for e in snap["declared_edges"]) == sorted(
         sync.DECLARED_ORDER)
-    assert set(sync.FLOCK_NODES) == {"flock:sidecar",
-                                     "flock:preempt_registry"}
+    assert set(sync.FLOCK_NODES) == {"flock:sidecar"}
 
 
 # ---------------------------------------------------------------------------

@@ -399,3 +399,20 @@ def test_save_plans_concurrent_writers_drop_no_entry(tmp_path):
     assert sorted(int(s) for s in doc["plans"]) == ks
     for k in ks:                        # every entry loads cleanly too
         assert load_plan(h, k, directory=d).k == k
+
+
+def test_search_parent_never_initializes_a_backend(tmp_path):
+    """One process per chip: the search parent learns the platform from
+    a child and races candidates in children — it must never create a
+    JAX backend itself (on a chip it would hold the device)."""
+    code = ("from arrow_matrix_tpu.tune.search import smoke_tune; "
+            "from arrow_matrix_tpu.utils.platform import "
+            "backend_initialized; "
+            f"r = smoke_tune({str(tmp_path)!r}); "
+            "print(r['ok'], r['children_spawned'], backend_initialized())")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code],
+                          capture_output=True, text=True, timeout=600,
+                          cwd=repo)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split()[-3:] == ["True", "3", "False"]

@@ -30,35 +30,28 @@ sys.path.insert(0, REPO)
 
 def main() -> None:
     # AMT_LADDER_CPU=1 runs the race logic on the host CPU (test
-    # fixture; AMT_LADDER_N shrinks the scale) — the watcher always
-    # runs it chip-or-bust.
+    # fixture; AMT_LADDER_N shrinks the scale); otherwise it runs
+    # chip-or-bust.
     cpu_ok = os.environ.get("AMT_LADDER_CPU") == "1"
     if cpu_ok:
         from arrow_matrix_tpu.utils.platform import force_cpu_devices
 
         force_cpu_devices()
-    from arrow_matrix_tpu.utils.platform import probe_default_backend
+    from arrow_matrix_tpu.utils.platform import enable_compile_cache
 
-    if cpu_ok:
-        platform, kind, err = "cpu", "host", None
-    else:
-        platform, kind, err = probe_default_backend(timeout_s=120,
-                                                    retries=1)
+    enable_compile_cache()
+    import jax
+
+    dev = jax.devices()[0]
+    platform, kind = dev.platform, dev.device_kind
     out: dict = {"metric": "ladder_race", "platform": platform,
                  "device_kind": kind}
-    if not cpu_ok and (err or platform == "cpu"):
-        out["error"] = f"no accelerator: {err}"
+    if not cpu_ok and platform != "tpu":
+        out["error"] = f"no TPU (platform {platform})"
         print(json.dumps(out), flush=True)
         raise SystemExit(1)
 
-    import jax
-
     jax.config.update("jax_default_matmul_precision", "highest")
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(REPO, "bench_cache", "xla_cache"))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
 
     import numpy as np
 

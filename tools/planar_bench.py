@@ -69,17 +69,17 @@ def main() -> None:
         from arrow_matrix_tpu.utils.platform import force_cpu_devices
 
         force_cpu_devices()
-    from arrow_matrix_tpu.utils.platform import probe_default_backend
+    from arrow_matrix_tpu.utils.platform import enable_compile_cache
 
-    if cpu:
-        platform, kind, err = "cpu", "host", None
-    else:
-        platform, kind, err = probe_default_backend(timeout_s=120,
-                                                    retries=1)
+    enable_compile_cache()
+    import jax
+
+    dev = jax.devices()[0]
+    platform, kind = dev.platform, dev.device_kind
     out: dict = {"metric": "planar_grid_iter_ms",
                  "platform": platform, "device_kind": kind}
-    if not cpu and (err or platform == "cpu"):
-        out["error"] = f"no accelerator: {err}"
+    if not cpu and platform != "tpu":
+        out["error"] = f"no TPU (platform {platform})"
         print(json.dumps(out), flush=True)
         raise SystemExit(1)
 
@@ -102,14 +102,7 @@ def main() -> None:
     n = side * side
     out.update({"side": side, "n": n, "width": width, "k": 16})
 
-    import jax
-
     jax.config.update("jax_default_matmul_precision", "highest")
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(REPO, "bench_cache", "xla_cache"))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
 
     import numpy as np
 

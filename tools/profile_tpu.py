@@ -127,7 +127,8 @@ def main():
                   f" ms", flush=True)
             steps = [make_sharded_step(sm.mesh, sm.axis, width,
                                        o.rows_out, hops=o.hops,
-                                       rem=o.rem)
+                                       rem=o.rem,
+                                       gather_budget=sm.gather_budget)
                      for o in sm.ops]
             for i, (o, st) in enumerate(zip(sm.ops, steps)):
                 f = jax.jit(st)

@@ -363,7 +363,7 @@ def main(argv=None) -> int:
     from arrow_matrix_tpu import sync
 
     # Arm the lock-order witness so the migration scenarios (flock'd
-    # preemption registry + live-grow server) run order-checked; the
+    # sidecars + live-grow server) run order-checked; the
     # kill_mid_migration driver subprocess inherits AMT_LOCK_WITNESS
     # from the environment.
     registry = sync.enable_witness()
