@@ -154,7 +154,7 @@ def main(argv=None) -> int:
 
         from arrow_matrix_tpu.obs import Tracer, pulse as pulse_mod
 
-        tracer = Tracer("graft-serve", registry=registry)
+        tracer = Tracer("graft-serve")
         ring = (os.path.join(args.obs_dir, "pulse_ring.json")
                 if args.obs_dir else None)
         monitor = pulse_mod.PulseMonitor(

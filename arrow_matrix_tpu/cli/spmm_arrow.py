@@ -471,6 +471,14 @@ def main(argv=None) -> int:
             f"_{args.mode.capitalize()}Shared")
     wb.init(algo, os.path.basename(path), config=vars(args))
 
+    # The process tracer and registry, reset before the build: library
+    # code records the build's spans and gauges into them, so the
+    # --obs_dir artifacts hold the build beside the iterations.
+    from arrow_matrix_tpu import obs
+
+    obs_reg = obs.init_registry(run_dir=args.obs_dir)
+    obs_tracer = obs.init_tracer("spmm_arrow")
+
     with wb.segment("build_time"):
         if args.mode == "space":
             from arrow_matrix_tpu.parallel.space_shared import (
@@ -557,11 +565,6 @@ def main(argv=None) -> int:
         graphs.random_dense(n, args.features, seed=args.seed))
     with wb.segment("first_call_time"):
         jax.block_until_ready(multi.step(warm))
-
-    from arrow_matrix_tpu import obs
-
-    obs_reg = obs.MetricsRegistry(run_dir=args.obs_dir)
-    obs_tracer = obs.Tracer("spmm_arrow", registry=obs_reg)
 
     if args.comm_report:
         from arrow_matrix_tpu.utils import commstats

@@ -6,10 +6,11 @@ the paper's headline claim (communication volume) per run:
   * :mod:`~arrow_matrix_tpu.obs.metrics` — process-level counters /
     gauges / histograms with a JSONL sink (the quantitative record);
   * :mod:`~arrow_matrix_tpu.obs.tracer` — host-side phase spans that
-    double as ``jax.named_scope`` + profiler annotations, emitted as
-    Chrome-trace / Perfetto JSON, plus the shared block-until-ready
-    timing harness (``bench.py``'s former private ``_timed`` /
-    ``_measure``);
+    double as ``jax.named_scope`` + profiler annotations and record
+    their parent span, emitted as Chrome-trace / Perfetto JSON, with a
+    process-wide default (``get_tracer``), plus the shared
+    block-until-ready timing harness (``bench.py``'s former private
+    ``_timed`` / ``_measure``);
   * :mod:`~arrow_matrix_tpu.obs.comm` — trace-time collective-byte
     accounting (utils/commstats) compared against each orchestration's
     ``ideal_comm_bytes`` paper cost model;
@@ -108,6 +109,8 @@ from arrow_matrix_tpu.obs.tracer import (
     Tracer,
     call_time_ms,
     chained_iteration_ms,
+    get_tracer,
+    init_tracer,
     iteration_time_ms,
     timed,
 )
@@ -147,9 +150,11 @@ __all__ = [
     "format_memory_report",
     "format_placement",
     "get_registry",
+    "get_tracer",
     "hbm_budget_bytes",
     "ideal_bytes_for",
     "init_registry",
+    "init_tracer",
     "iteration_time_ms",
     "memory_report",
     "merge_process_traces",

@@ -27,13 +27,12 @@ from arrow_matrix_tpu.obs import flight
 from arrow_matrix_tpu.sync import guarded_by, witnessed
 
 
-#: Metric names whose samples are NOT mirrored into the flight ring.
-#: ``span_ms`` is mirrored by the Tracer itself (with request context);
+#: Metric names whose samples are NOT mirrored into the flight ring:
 #: the per-frame wire metrics fire on every fleet frame and would evict
 #: the span events graft-xray recovers a SIGKILLed worker's partial
 #: trace from.
 FLIGHT_MIRROR_SKIP = frozenset(
-    {"span_ms", "wire_frame_bytes", "wire_serialize_ms", "wire_ms"})
+    {"wire_frame_bytes", "wire_serialize_ms", "wire_ms"})
 
 
 def _label_key(labels: Dict[str, Any]) -> Tuple:
@@ -192,11 +191,10 @@ class MetricsRegistry:
                                 "labels": dict(labels)})
         # Mirror into the flight recorder ring (no-op unless installed):
         # metric samples are the blackbox's record of what the run was
-        # doing when a wedge killed it.  span_ms is skipped — the
-        # Tracer mirrors spans itself with better context — and the
-        # per-frame wire metrics are skipped too: a chatty wire would
-        # churn the bounded ring and evict the span events graft-xray
-        # recovers a killed worker's trace from.
+        # doing when a wedge killed it.  The per-frame wire metrics
+        # are skipped: a chatty wire would churn the bounded ring and
+        # evict the span events graft-xray recovers a killed worker's
+        # trace from.
         if name not in FLIGHT_MIRROR_SKIP:
             data = dict(labels)
             data["value"] = value

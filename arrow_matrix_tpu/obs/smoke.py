@@ -176,7 +176,7 @@ def run_smoke(run_dir: str, n: int = 256, width: int = 32, k: int = 4,
     summary: Dict[str, dict] = {}
 
     for name, build in _adapters(n, width, k, n_dev, algorithms):
-        tracer = Tracer(name=name, registry=reg)
+        tracer = Tracer(name=name)
 
         with tracer.span(f"{name}/build"):
             obj, x, step, jit_fn, jit_args = build()

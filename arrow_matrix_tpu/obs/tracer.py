@@ -4,7 +4,11 @@ Host-side spans (``Tracer.span``) measure wall time per phase and emit
 Chrome-trace / Perfetto JSON; each span also enters ``jax.named_scope``
 and ``jax.profiler.TraceAnnotation`` so that when any jit tracing or a
 profiler capture happens inside the span, the device-side record
-carries the same phase names as the host-side one.
+carries the same phase names as the host-side one.  A span records the
+span that was open around it (its parent), whichever tracer holds
+that one.  :func:`get_tracer` is the process-wide tracer that library
+code (the executor build) records into, beside
+:func:`~arrow_matrix_tpu.obs.metrics.get_registry`.
 
 The timing helpers are the one honest way to time async-dispatch jax
 work (graft-lint R7 flags the dishonest way):
@@ -22,6 +26,8 @@ work (graft-lint R7 flags the dishonest way):
 from __future__ import annotations
 
 import contextlib
+import contextvars
+import itertools
 import json
 import os
 import time
@@ -36,13 +42,25 @@ from arrow_matrix_tpu.utils.logging import block_until_ready
 
 @dataclass
 class Span:
-    """One completed phase: Chrome-trace complete event ("ph": "X")."""
+    """One completed phase: Chrome-trace complete event ("ph": "X").
+
+    ``span_id`` is unique in the process; ``parent`` / ``parent_id``
+    name the span that was open around this one (None at the top)."""
 
     name: str
     ts_us: float
     dur_us: float
     tid: int = 0
     args: Dict[str, Any] = field(default_factory=dict)
+    span_id: int = 0
+    parent: Optional[str] = None
+    parent_id: Optional[int] = None
+
+
+_SPAN_IDS = itertools.count(1)
+# (name, span_id) of the spans open in this context, innermost last.
+_OPEN: contextvars.ContextVar = contextvars.ContextVar(
+    "obs_open_spans", default=())
 
 
 @contextlib.contextmanager
@@ -68,9 +86,8 @@ class Tracer:
     phase still shows up — with an ``error`` arg — in the trace.
     """
 
-    def __init__(self, name: str = "run", registry=None):
+    def __init__(self, name: str = "run"):
         self.name = name
-        self.registry = registry
         self.spans: List[Span] = []
         self._epoch = time.perf_counter()
         # Wall-clock anchor for the monotonic span epoch: a span's
@@ -80,7 +97,9 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        """Time a phase; nested spans render nested in Perfetto.
+        """Time a phase; nested spans render nested in Perfetto, and
+        each records the innermost span open around it (in any tracer
+        of this context) as its parent.
 
         Inside a :func:`~arrow_matrix_tpu.obs.flight.request_context`
         scope the span args carry ``request_id`` (and ``tenant``), so
@@ -92,6 +111,10 @@ class Tracer:
         if ctx is not None:
             for k, v in ctx.items():
                 args.setdefault(k, v)
+        open_spans = _OPEN.get()
+        parent, parent_id = open_spans[-1] if open_spans else (None, None)
+        span_id = next(_SPAN_IDS)
+        token = _OPEN.set(open_spans + ((name, span_id),))
         tic = time.perf_counter()
         try:
             with _device_annotation(name):
@@ -101,15 +124,16 @@ class Tracer:
             raise
         finally:
             toc = time.perf_counter()
+            _OPEN.reset(token)
             self.spans.append(Span(
                 name=name,
                 ts_us=(tic - self._epoch) * 1e6,
                 dur_us=(toc - tic) * 1e6,
                 args=args,
+                span_id=span_id,
+                parent=parent,
+                parent_id=parent_id,
             ))
-            if self.registry is not None:
-                self.registry.record("span_ms", (toc - tic) * 1e3,
-                                     run=self.name, span=name)
             # Mirror into the flight recorder ring (no-op unless
             # installed): the last completed spans name the phase a
             # wedge killed.
@@ -127,6 +151,9 @@ class Tracer:
     def to_chrome_trace(self) -> dict:
         events = []
         for s in self.spans:
+            args = dict(s.args, span_id=s.span_id)
+            if s.parent is not None:
+                args.update(parent=s.parent, parent_id=s.parent_id)
             events.append({
                 "name": s.name,
                 "ph": "X",
@@ -134,7 +161,7 @@ class Tracer:
                 "dur": s.dur_us,
                 "pid": 1,
                 "tid": s.tid,
-                "args": s.args,
+                "args": args,
             })
         # Chronological order helps Perfetto's importer nest events.
         events.sort(key=lambda e: e["ts"])
@@ -152,6 +179,21 @@ class Tracer:
         with open(path, "w", encoding="utf-8") as fh:
             json.dump(self.to_chrome_trace(), fh, indent=1)
         return path
+
+
+_DEFAULT = Tracer("process")
+
+
+def get_tracer() -> Tracer:
+    """The process-wide tracer library code records into."""
+    return _DEFAULT
+
+
+def init_tracer(name: str = "run") -> Tracer:
+    """Reset the process-wide tracer for a new run."""
+    global _DEFAULT
+    _DEFAULT = Tracer(name)
+    return _DEFAULT
 
 
 def timed(fn) -> float:

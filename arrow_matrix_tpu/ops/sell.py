@@ -41,6 +41,8 @@ from flax import struct
 from scipy import sparse
 
 from arrow_matrix_tpu.io.graphio import CsrLike, num_rows
+from arrow_matrix_tpu.obs.metrics import get_registry
+from arrow_matrix_tpu.obs.tracer import get_tracer
 from arrow_matrix_tpu.ops.ell import SLOT_ALIGN, align_up, ell_spmm_t
 
 
@@ -114,7 +116,29 @@ def sell_from_csr(matrix: CsrLike, pad_rows_to: Optional[int] = None,
     columns) into the sorted coordinates, so a caller carrying features
     ``y[i] = x[order[i]]`` computes ``(A @ x)`` as ``sell @ y`` with no
     runtime permutation at all.
+
+    The host packing is the ``sell.pack`` span of the process tracer
+    and records the ``sell.nnz`` / ``sell.slots`` gauges; the device
+    copy is ``sell.upload`` (:func:`upload_sell`).
     """
+    with get_tracer().span("sell.pack"):
+        host, order = _pack(matrix, pad_rows_to, dtype, binary, growth,
+                            slot_align)
+    return upload_sell(host), order
+
+
+def upload_sell(sell: SellMatrix) -> SellMatrix:
+    """Copy a host-packed SellMatrix to the default device, blocked
+    until every tier is resident (the ``sell.upload`` span)."""
+    with get_tracer().span("sell.upload"):
+        return jax.block_until_ready(jax.tree_util.tree_map(jnp.asarray,
+                                                            sell))
+
+
+def _pack(matrix: CsrLike, pad_rows_to, dtype, binary, growth,
+          slot_align) -> tuple[SellMatrix, np.ndarray]:
+    """``sell_from_csr``'s host work: ``(sell, order)`` with the tiers
+    as numpy arrays."""
     from arrow_matrix_tpu.ops.hyb import resolve_binary
 
     n = num_rows(matrix)
@@ -168,12 +192,15 @@ def sell_from_csr(matrix: CsrLike, pad_rows_to: Optional[int] = None,
             cols[slot, tloc] = all_cols[src]
             if not is_binary:
                 vals[slot, tloc] = all_data[src]
-        cols_t.append(jnp.asarray(cols))
+        cols_t.append(cols)
         if is_binary:
-            deg_t.append(jnp.asarray(degs.astype(np.int32)))
+            deg_t.append(degs.astype(np.int32))
         else:
-            data_t.append(jnp.asarray(vals))
+            data_t.append(vals)
 
+    reg = get_registry()
+    reg.gauge("sell.nnz").set(nnz)
+    reg.gauge("sell.slots").set(sum(c.size for c in cols_t))
     sell = SellMatrix(
         cols=tuple(cols_t),
         data=None if is_binary else tuple(data_t),

@@ -614,8 +614,28 @@ class MultiLevelArrow:
         (O(nnz) host RAM); the streamed >RAM ingestion path keeps the
         per-level formats on a mesh instead.
         """
-        from arrow_matrix_tpu.ops.sell import sell_from_csr, sell_spmm_t
+        from arrow_matrix_tpu.obs.tracer import get_tracer
+        from arrow_matrix_tpu.ops.sell import sell_from_csr
 
+        with get_tracer().span("fold.compose"):
+            folded = self._compose_folded(levels)
+
+        # SELL packing in degree-sorted coordinates; the sort permutation
+        # is composed into the carried ordering (set_features/
+        # gather_result), so it is free at runtime.
+        if slot_align is None:   # follow the library-wide tile alignment
+            from arrow_matrix_tpu.ops.ell import SLOT_ALIGN
+            slot_align = SLOT_ALIGN
+        sell, order = sell_from_csr(folded, pad_rows_to=self.total_rows,
+                                    dtype=dtype, binary=self.binary,
+                                    growth=growth, slot_align=slot_align)
+        self.perm0 = self.perm0[order]
+        self.inv_perm0 = np.argsort(self.perm0)
+        self._finalize_folded(sell, chunk, gather_budget)
+
+    def _compose_folded(self, levels) -> sparse.csr_matrix:
+        """Every level's triplets conjugated into level-0 order as one
+        CSR (sets ``perm0`` / ``inv_perm0`` to level 0's)."""
         total = self.total_rows
         perms = [pad_permutation(np.asarray(lvl.permutation), total)
                  for lvl in levels]
@@ -655,19 +675,7 @@ class MultiLevelArrow:
         if implicit_ones and not np.all(folded.data == 1.0):
             raise AssertionError(
                 "edge-disjoint levels folded to duplicate positions")
-
-        # SELL packing in degree-sorted coordinates; the sort permutation
-        # is composed into the carried ordering (set_features/
-        # gather_result), so it is free at runtime.
-        if slot_align is None:   # follow the library-wide tile alignment
-            from arrow_matrix_tpu.ops.ell import SLOT_ALIGN
-            slot_align = SLOT_ALIGN
-        sell, order = sell_from_csr(folded, pad_rows_to=total, dtype=dtype,
-                                    binary=self.binary, growth=growth,
-                                    slot_align=slot_align)
-        self.perm0 = self.perm0[order]
-        self.inv_perm0 = np.argsort(self.perm0)
-        self._finalize_folded(sell, chunk, gather_budget)
+        return folded
 
     def _finalize_folded(self, sell, chunk, gather_budget: int) -> None:
         """Install a packed SELL operator as the fold execution state
@@ -843,7 +851,7 @@ class MultiLevelArrow:
         the tier arrays as host memmaps (budget accounting / tests)."""
         import json
 
-        from arrow_matrix_tpu.ops.sell import SellMatrix
+        from arrow_matrix_tpu.ops.sell import SellMatrix, upload_sell
 
         with open(os.path.join(in_dir, "meta.json")) as f:
             meta = json.load(f)
@@ -862,18 +870,14 @@ class MultiLevelArrow:
         self.feature_dtype = resolve_feature_dtype(feature_dtype)
         self.perm0 = np.load(os.path.join(in_dir, "perm0.npy"))
         self.inv_perm0 = np.argsort(self.perm0)
-        put = jnp.asarray if device_put else \
-            (lambda a: np.asarray(a))
         cols_t, deg_t, data_t = [], [], []
         for t in range(meta["n_tiers"]):
-            arr = np.load(os.path.join(in_dir, f"cols_{t}.npy"),
-                          mmap_mode="r")
-            cols_t.append(put(arr))
+            cols_t.append(np.asarray(np.load(
+                os.path.join(in_dir, f"cols_{t}.npy"), mmap_mode="r")))
             if meta["binary"]:
-                deg_t.append(put(np.load(
-                    os.path.join(in_dir, f"deg_{t}.npy"))))
+                deg_t.append(np.load(os.path.join(in_dir, f"deg_{t}.npy")))
             else:
-                data_t.append(put(np.load(
+                data_t.append(np.asarray(np.load(
                     os.path.join(in_dir, f"data_{t}.npy"),
                     mmap_mode="r")))
         sell = SellMatrix(
@@ -882,6 +886,8 @@ class MultiLevelArrow:
             deg=tuple(deg_t) if meta["binary"] else None,
             n_rows=meta["total_rows"],
             row_starts=tuple(meta["row_starts"]))
+        if device_put:
+            sell = upload_sell(sell)
         self._finalize_folded(sell, chunk, gather_budget)
         return self
 

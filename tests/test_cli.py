@@ -240,6 +240,31 @@ def test_spmm_arrow_fold_single_chip(tmp_path, monkeypatch):
     assert rc == 0
 
 
+def test_spmm_arrow_obs_dir_holds_build_spans(tmp_path, monkeypatch):
+    """--obs_dir's Chrome trace holds the fold build's spans beside the
+    per-iteration ones, and its metrics the operator's slot gauges."""
+    import json
+
+    monkeypatch.chdir(tmp_path)
+    obs_dir = tmp_path / "obs"
+    rc = spmm_arrow.main([
+        "--vertices", "300", "--width", "32", "--features", "4",
+        "--iterations", "2", "--device", "cpu", "--devices", "1",
+        "--fmt", "fold", "--obs_dir", str(obs_dir),
+        "--logdir", str(tmp_path / "logs"),
+    ])
+    assert rc == 0
+    with open(obs_dir / "spmm_arrow.trace.json") as f:
+        names = [e["name"] for e in json.load(f)["traceEvents"]]
+    for span in ("fold.compose", "sell.pack", "sell.upload"):
+        assert names.count(span) == 1
+    assert names.count("step") == 2
+    with open(obs_dir / "metrics.jsonl") as f:
+        gauges = {e["name"] for e in map(json.loads, f)
+                  if e["kind"] == "gauge"}
+    assert {"sell.nnz", "sell.slots"} <= gauges
+
+
 def test_spmm_arrow_fold_rejects_mesh(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     with pytest.raises(SystemExit, match="single-chip"):

@@ -88,8 +88,7 @@ def test_segment_log_raising_body_still_logs():
 
 
 def test_tracer_spans_and_chrome_trace(tmp_path):
-    reg = obs.MetricsRegistry()
-    tr = obs.Tracer("myrun", registry=reg)
+    tr = obs.Tracer("myrun")
     with tr.span("outer"):
         with tr.span("inner", detail=7) as args:
             args["extra"] = "x"
@@ -100,12 +99,15 @@ def test_tracer_spans_and_chrome_trace(tmp_path):
     meta, *events = trace["traceEvents"]
     assert meta["ph"] == "M" and meta["args"]["name"] == "myrun"
     assert [e["name"] for e in events] == ["outer", "inner"]  # ts order
-    inner = events[1]
+    outer, inner = events
     assert inner["ph"] == "X" and inner["dur"] >= 0
-    assert inner["args"] == {"detail": 7, "extra": "x"}
-    # Every span also lands in the registry as span_ms.
-    assert reg.histogram("span_ms", run="myrun",
-                         span="inner").summary()["count"] == 1
+    assert inner["args"] == {"detail": 7, "extra": "x",
+                             "span_id": inner["args"]["span_id"],
+                             "parent": "outer",
+                             "parent_id": outer["args"]["span_id"]}
+    # Each span is recorded once, by the tracer itself.
+    assert [(s.name, s.dur_us >= 0) for s in tr.spans] == [
+        ("inner", True), ("outer", True)]
 
     path = tr.save(str(tmp_path / "t.trace.json"))
     assert json.load(open(path, encoding="utf-8"))["traceEvents"]
@@ -118,6 +120,56 @@ def test_tracer_records_failed_span():
             raise ValueError("bad phase")
     assert len(tr.spans) == 1
     assert tr.spans[0].args["error"].startswith("ValueError")
+
+
+def test_nested_spans_carry_parent_into_chrome_trace(monkeypatch):
+    """A span records the innermost open span as its parent, across
+    tracers (library code records into the process tracer inside a
+    caller's span); siblings share the parent; the top has none."""
+    from arrow_matrix_tpu.obs import tracer as tracer_mod
+
+    monkeypatch.setattr(tracer_mod, "_DEFAULT", tracer_mod.Tracer("p"))
+    app = obs.Tracer("app")
+    with app.span("build"):
+        with obs.get_tracer().span("fold.compose"):
+            with app.span("inner"):
+                pass
+        with obs.get_tracer().span("sell.pack"):
+            pass
+    with app.span("after"):
+        pass
+
+    events = {e["name"]: e for tr in (app, obs.get_tracer())
+              for e in tr.to_chrome_trace()["traceEvents"][1:]}
+    ids = {name: e["args"]["span_id"] for name, e in events.items()}
+    assert len(set(ids.values())) == len(ids)
+    parents = {name: (e["args"].get("parent"), e["args"].get("parent_id"))
+               for name, e in events.items()}
+    assert parents == {
+        "build": (None, None),
+        "fold.compose": ("build", ids["build"]),
+        "inner": ("fold.compose", ids["fold.compose"]),
+        "sell.pack": ("build", ids["build"]),
+        "after": (None, None),
+    }
+    # A failed span closes: the next one is not its child.
+    with pytest.raises(KeyError):
+        with app.span("fails"):
+            raise KeyError("x")
+    with app.span("next"):
+        pass
+    assert app.spans[-1].parent is None
+
+
+def test_process_tracer_reset(monkeypatch):
+    from arrow_matrix_tpu.obs import tracer as tracer_mod
+
+    monkeypatch.setattr(tracer_mod, "_DEFAULT", tracer_mod.Tracer("p"))
+    with obs.get_tracer().span("old"):
+        pass
+    fresh = obs.init_tracer("run2")
+    assert obs.get_tracer() is fresh and fresh.name == "run2"
+    assert fresh.spans == []
 
 
 # ---------------------------------------------------------------------------
