@@ -30,12 +30,40 @@ import jax.numpy as jnp
 import numpy as np
 from scipy import sparse
 
-# Pad the ELL slot axis to a multiple of this (sublane-friendly).
+# Slot counts, gather chunks and the packed gather's tier rows are
+# multiples of this (one sublane tile: the second-minor dimension of a
+# TPU array pads to it).
 SLOT_ALIGN = 8
+# Width of a TPU vector register and of the minor tile dimension: a
+# gathered row narrower than this costs about as much as a whole one.
+LANES = 128
 
 
 def align_up(x: int, align: int) -> int:
     return -(-x // align) * align
+
+
+def lane_pack_factor(k: int) -> int:
+    """Nodes per 128-lane packed row of :func:`lane_pack`: ``128 // k``
+    when k features are narrower than the lane tile and divide it, else
+    1 (the packed form does not engage)."""
+    return LANES // k if 0 < k < LANES and LANES % k == 0 else 1
+
+
+def lane_pack(x_t: jax.Array) -> Optional[jax.Array]:
+    """Feature-major ``(k, N)`` -> row-major ``(ceil(N / p), 128)`` with
+    ``p = lane_pack_factor(k)``: row ``j // p``, lanes
+    ``k*(j % p) .. k*(j % p) + k - 1`` hold node j's k features (N is
+    zero-padded up to a multiple of p).  None when p is 1: the k-wide
+    rows of ``x_t`` are gathered as they are."""
+    k, n = x_t.shape
+    p = lane_pack_factor(k)
+    if p == 1:
+        return None
+    pad = align_up(n, p) - n
+    if pad:
+        x_t = jnp.pad(x_t, ((0, 0), (0, pad)))
+    return x_t.T.reshape(-1, LANES)
 
 
 def block_index_dtype(width: int):
@@ -116,17 +144,23 @@ def ell_pack_stack(mats: list[sparse.spmatrix], dtype=np.float32,
 
 def feature_major_chunk(rows: int, k: int, m: int, budget_bytes: int,
                         itemsize: int = 4) -> Optional[int]:
-    """Slot chunk bounding the feature-major ``(k, chunk, rows)`` gather
-    intermediate of :func:`ell_spmm_t` to ``budget_bytes``; ``None``
-    when the whole slot axis fits.  The chunk axis is second-minor, so
-    TPU pads it to a whole sublane tile (SLOT_ALIGN): chunks below it
-    cost a full tile and are replaced by single-slot 2-D gathers
-    (chunk 1), the only bound left when one tile of slots is already
-    over budget (a 2^22-row tier at k=128: 9.7 GB per tile).
+    """Slot chunk bounding the gather intermediate of :func:`ell_spmm_t`
+    (``chunk * rows`` gathered rows) to ``budget_bytes``; ``None`` when
+    the whole slot axis fits.
+
+    Each gathered row is budgeted at its physical width: 128 lanes
+    whenever the lane-packed form runs (``lane_pack_factor(k) > 1``),
+    else k, so a packed tier chunks exactly as the same tier does at
+    k=128.  Chunks are whole multiples of SLOT_ALIGN; below one such
+    tile the chunk is a single slot (chunk 1, a 2-D gather), the only
+    bound left when one tile of slots is already over budget (the
+    largest tier of a 2^22-row BA fold, 2.37M rows at 128 lanes: 9.7 GB
+    per tile).
     """
     if m == 0 or rows <= 0 or k <= 0:
         return None
-    per_slot = k * rows * itemsize
+    width = LANES if lane_pack_factor(k) > 1 else k
+    per_slot = width * rows * itemsize
     if align_up(m, SLOT_ALIGN) * per_slot <= budget_bytes:
         return None
     c = int(budget_bytes // per_slot)
@@ -239,10 +273,33 @@ def ell_spmm(cols: jax.Array, data: Optional[jax.Array], x: jax.Array,
     return acc.astype(x.dtype)
 
 
+def _packed_gather(packed: jax.Array, k: int,
+                   cols_c: jax.Array) -> jax.Array:
+    """``x_t[:, cols_c]`` as a ``(k, *cols_c.shape)`` array, gathered
+    as whole 128-lane rows of :func:`lane_pack`'s operand: each slot
+    keeps the k lanes of its own node and zeroes the rest, and the 0/1
+    matrix ``S[k*g + f, f] = 1`` folds the p lane groups onto the k
+    features on the MXU.  Each output is one value plus zeros, and
+    ``HIGHEST`` keeps that value whole (a default-precision pass would
+    round f32 to bf16), so the result is the k-wide gather's, bit for
+    bit, while the features are finite (an inf times a zero of ``S`` is
+    NaN).  The weights are applied after, as in the k-wide form."""
+    p = LANES // k
+    g = jnp.take(packed, cols_c // p, axis=0)             # (..., 128)
+    own = (jnp.arange(LANES, dtype=cols_c.dtype) // k
+           == (cols_c % p)[..., None])
+    select = np.tile(np.eye(k, dtype=np.float32), (p, 1))
+    return jnp.einsum("...l,lf->f...", jnp.where(own, g, 0), select,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32
+                      ).astype(packed.dtype)
+
+
 def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
                data: Optional[jax.Array] = None,
                deg: Optional[jax.Array] = None,
-               chunk: Optional[int] = None) -> jax.Array:
+               chunk: Optional[int] = None,
+               packed: Optional[jax.Array] = None) -> jax.Array:
     """Slot-major, feature-major ELL SpMM (the padding-free layout):
     ``out_t[:, r] = sum_j w[j, r] * x_t[:, cols[j, r]]``.
 
@@ -252,8 +309,21 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
     feature array ``(N, 16)`` 8x — a compile-time OOM at protocol scale
     (28 GB program for 2.4 GB of logical data) and the same factor in
     streamed bytes.  Storing slots major ``(m, rows)`` and features
-    major ``(k, N)`` puts the large dimension minor everywhere; no
-    hidden padding remains.
+    major ``(k, N)`` puts the large dimension minor in every stored
+    array.
+
+    The gather itself fetches one row per slot from a row-major view
+    of the features, and on TPU a row narrower than 128 lanes costs
+    about as much as a whole one (v5e, 2^22-row BA fold: each k=16
+    index took 2.0-2.5x the time of a k=128 one).  So when k divides
+    128 (``lane_pack_factor(k) = p > 1``) the operand is packed p nodes
+    to a 128-lane row (:func:`lane_pack`), each slot gathers the whole
+    row ``packed[col // p]``, and the node's own k lanes are selected
+    exactly (:func:`_packed_gather`).  The packed form pads the
+    rows to a multiple of SLOT_ALIGN, so that the ``(chunk * rows,
+    128)`` gather reshapes to ``(chunk, rows, 128)`` without a
+    relayout.  k >= 128, or k not dividing 128, gathers k-wide rows of
+    ``x_t``.
 
     Weighted mode passes ``data`` (m, rows) with zeros in padding
     slots.  Binary mode (implicit-ones matrices — graph adjacency)
@@ -268,7 +338,10 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
     :param data: (m, rows) values, or None for binary.
     :param deg:  (rows,) int32 valid-slot counts (binary mode only).
     :param chunk: slot-axis chunk bounding the gather intermediate
-        (k * chunk * rows elements); None processes all slots at once.
+        (chunk * rows gathered rows, see :func:`feature_major_chunk`);
+        None processes all slots at once.
+    :param packed: ``lane_pack(x_t)``, for callers that run several
+        tiers over one operand; built here when not given.
     :returns: (k, rows) result, feature-major.
     """
     m, rows = cols.shape
@@ -277,6 +350,17 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
         raise ValueError("binary ELL (data=None) requires deg")
     if m == 0:
         return jnp.zeros((k, rows), dtype=x_t.dtype)
+    rows_out = rows
+    if packed is None:
+        packed = lane_pack(x_t)
+    if packed is not None:
+        rows = align_up(rows, SLOT_ALIGN)
+        if rows > rows_out:
+            cols = jnp.pad(cols, ((0, 0), (0, rows - rows_out)))
+            if data is not None:
+                data = jnp.pad(data, ((0, 0), (0, rows - rows_out)))
+            else:
+                deg = jnp.pad(deg, (0, rows - rows_out))
     c = m if chunk is None else min(chunk, m)
     n_chunks = align_up(m, c) // c
     pad = n_chunks * c - m
@@ -285,15 +369,22 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
         if data is not None:
             data = jnp.pad(data, ((0, pad), (0, 0)))
 
+    def gather(cols_c):
+        """``x_t[:, cols_c]``: (k, *cols_c.shape) in x_t's dtype."""
+        if packed is not None:
+            return _packed_gather(packed, k, cols_c)
+        if cols_c.ndim == 1:
+            return jnp.take(x_t, cols_c, axis=1)
+        return jnp.take(x_t, cols_c.reshape(-1), axis=1).reshape(
+            (k,) + cols_c.shape)
+
     def contribution(cols_c, w_c):
         if c == 1:
             # One slot per step: a 2-D (k, rows) gather.  A (k, 1, rows)
             # intermediate would pad its slot axis to a whole sublane
             # tile on TPU — 8x the bytes this chunking exists to bound.
-            g = jnp.take(x_t, cols_c[0], axis=1)
-            return (g * w_c[0][None]).astype(jnp.float32)
-        g = jnp.take(x_t, cols_c.reshape(-1), axis=1)
-        g = g.reshape(k, c, rows)
+            return (gather(cols_c[0]) * w_c[0][None]).astype(jnp.float32)
+        g = gather(cols_c)
         # f32 accumulation whatever the carried feature dtype: bf16
         # features (half the gathered bytes — the k=128 bandwidth
         # lever) must not also mean bf16 sums, and f32 matrix VALUES
@@ -310,26 +401,28 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
         else:
             w = (jnp.arange(m + pad, dtype=deg.dtype)[:, None]
                  < deg[None, :])
-        return contribution(cols, w).astype(x_t.dtype)
-
-    cols_c = cols.reshape(n_chunks, c, rows)
-    if data is not None:
-        def body(acc, xs):
-            cc, dc = xs
-            return acc + contribution(cc, dc), None
-        xs = (cols_c, data.reshape(n_chunks, c, rows))
+        acc = contribution(cols, w)
     else:
-        offsets = jnp.arange(n_chunks, dtype=deg.dtype) * c
+        cols_c = cols.reshape(n_chunks, c, rows)
+        if data is not None:
+            def body(acc, xs):
+                cc, dc = xs
+                return acc + contribution(cc, dc), None
+            xs = (cols_c, data.reshape(n_chunks, c, rows))
+        else:
+            offsets = jnp.arange(n_chunks, dtype=deg.dtype) * c
 
-        def body(acc, xs):
-            cc, off = xs
-            w = (off + jnp.arange(c, dtype=deg.dtype)[:, None]
-                 < deg[None, :])
-            return acc + contribution(cc, w), None
-        xs = (cols_c, offsets)
+            def body(acc, xs):
+                cc, off = xs
+                w = (off + jnp.arange(c, dtype=deg.dtype)[:, None]
+                     < deg[None, :])
+                return acc + contribution(cc, w), None
+            xs = (cols_c, offsets)
 
-    acc0 = jnp.zeros((k, rows), dtype=jnp.float32)
-    acc, _ = jax.lax.scan(body, acc0, xs)
+        acc0 = jnp.zeros((k, rows), dtype=jnp.float32)
+        acc, _ = jax.lax.scan(body, acc0, xs)
+    if rows > rows_out:
+        acc = acc[:, :rows_out]
     return acc.astype(x_t.dtype)
 
 

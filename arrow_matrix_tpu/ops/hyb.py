@@ -19,10 +19,10 @@ ELL stays small.
 
 Two TPU-measured layout rules shape the arrays (see ops/ell.py
 ``ell_spmm_t``): everything is stored slot-major ``(m, rows)`` and
-computed feature-major ``(k, N)`` so no dimension smaller than the
-128-lane tile is ever minor (a row-major (rows, 8..24) ELL array is
-physically padded 5-16x by XLA's (8, 128) tiling — the round-2
-compile-OOM at protocol scale); and binary matrices (graph adjacency —
+carried feature-major ``(k, N)`` so every stored array keeps its large
+dimension minor (a row-major (rows, 8..24) ELL array is physically
+padded 5-16x by XLA's (8, 128) tiling — the round-2 compile-OOM at
+protocol scale); and binary matrices (graph adjacency —
 implicit-ones data, the reference's missing-``_data``-file convention,
 graphio.py:298) drop their value arrays entirely in favor of a per-row
 degree mask, halving the streamed bytes.
@@ -47,7 +47,12 @@ from flax import struct
 from scipy import sparse
 
 from arrow_matrix_tpu.io.graphio import CsrLike, num_rows
-from arrow_matrix_tpu.ops.ell import SLOT_ALIGN, align_up, ell_spmm_t
+from arrow_matrix_tpu.ops.ell import (
+    SLOT_ALIGN,
+    align_up,
+    ell_spmm_t,
+    lane_pack,
+)
 
 
 @struct.dataclass
@@ -216,12 +221,15 @@ def hyb_spmm_t(level: HybLevel, x_t: jax.Array,
     """``(level @ x_t.T).T`` on feature-major (k, rows) operands — the
     native form: light slot-major ELL gather + compact heavy ELL,
     merged by one h-column scatter-add (heavy rows' light slots are
-    empty, so add is exact)."""
+    empty, so add is exact).  Both gathers share one lane-packed
+    operand when k divides the 128-lane tile."""
+    packed = lane_pack(x_t)
     out = ell_spmm_t(level.light_cols, x_t, data=level.light_data,
-                     deg=level.light_deg, chunk=chunk)
+                     deg=level.light_deg, chunk=chunk, packed=packed)
     if level.heavy_idx.shape[0]:
         heavy = ell_spmm_t(level.heavy_cols, x_t, data=level.heavy_data,
-                           deg=level.heavy_deg, chunk=heavy_chunk)
+                           deg=level.heavy_deg, chunk=heavy_chunk,
+                           packed=packed)
         out = out.at[:, level.heavy_idx].add(heavy.astype(out.dtype),
                                              unique_indices=True,
                                              indices_are_sorted=True)

@@ -16,10 +16,12 @@ for TPU lanes:
     ``growth`` times the tier's smallest) — padded slots <= growth x
     nonzeros by construction;
   * each tier is one slot-major (m_t, n_t) ELL computed feature-major
-    (ops/ell.py ``ell_spmm_t``: no dimension smaller than the 128-lane
-    tile is ever minor), and tier outputs **concatenate** — the tiers
-    are contiguous runs of the sorted order, so there is no scatter
-    anywhere (TPU scatters serialize; concatenation is free).
+    (ops/ell.py ``ell_spmm_t``: every stored array keeps its large
+    dimension minor, and features narrower than the 128-lane tile are
+    gathered as lane-packed whole rows), and tier outputs
+    **concatenate** — the tiers are contiguous runs of the sorted
+    order, so there is no scatter anywhere (TPU scatters serialize;
+    concatenation is free).
 
 Binary matrices (graph adjacency) drop the value arrays for per-row
 degree masks, halving streamed bytes (same rule as ops/hyb.py).
@@ -43,7 +45,13 @@ from scipy import sparse
 from arrow_matrix_tpu.io.graphio import CsrLike, num_rows
 from arrow_matrix_tpu.obs.metrics import get_registry
 from arrow_matrix_tpu.obs.tracer import get_tracer
-from arrow_matrix_tpu.ops.ell import SLOT_ALIGN, align_up, ell_spmm_t
+from arrow_matrix_tpu.ops.ell import (
+    SLOT_ALIGN,
+    align_up,
+    ell_spmm_t,
+    feature_major_chunk,
+    lane_pack,
+)
 
 
 @struct.dataclass
@@ -225,10 +233,16 @@ def sell_spmm_t(m: SellMatrix, x_t: jax.Array,
     other kernels (reference GPU OOM-model tiling,
     spmm_petsc.py:323-395); an explicit ``chunk`` overrides it for
     every tier.
-    """
-    from arrow_matrix_tpu.ops.ell import feature_major_chunk
 
+    When k divides the 128-lane tile, the operand is lane-packed once
+    here and every tier gathers whole packed rows (``ell_spmm_t``); the
+    ``sell.packed_slots`` gauge records the slot-rows that take that
+    form (0 when it does not engage), once per trace.
+    """
     k = x_t.shape[0]
+    packed = lane_pack(x_t)
+    get_registry().gauge("sell.packed_slots").set(
+        0 if packed is None else m.n_slots)
     outs = []
     for t, cols in enumerate(m.cols):
         m_t, n_t = cols.shape
@@ -243,7 +257,7 @@ def sell_spmm_t(m: SellMatrix, x_t: jax.Array,
             cols, x_t,
             data=None if m.data is None else m.data[t],
             deg=None if m.deg is None else m.deg[t],
-            chunk=c))
+            chunk=c, packed=packed))
     return jnp.concatenate(outs, axis=1)
 
 

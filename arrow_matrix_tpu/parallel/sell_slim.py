@@ -59,6 +59,7 @@ from arrow_matrix_tpu.ops.ell import (
     block_index_dtype,
     ell_spmm_t,
     feature_major_chunk,
+    lane_pack,
 )
 
 
@@ -294,6 +295,7 @@ def _stack_spmm_t(stack: SellShardStack, z_t: jax.Array,
     bounds each tier's gather intermediate (None: unbounded)."""
     chunks = tier_chunks(stack, z_t.shape[0],
                          jnp.dtype(z_t.dtype).itemsize, gather_budget)
+    packed = lane_pack(z_t)
     outs = []
     for t, (cols, (m_t, n_t, chunk)) in enumerate(zip(stack.cols,
                                                        chunks)):
@@ -303,7 +305,7 @@ def _stack_spmm_t(stack: SellShardStack, z_t: jax.Array,
         outs.append(ell_spmm_t(
             cols[0], z_t,
             data=None if stack.data is None else stack.data[t][0],
-            deg=stack.deg[t][0], chunk=chunk))
+            deg=stack.deg[t][0], chunk=chunk, packed=packed))
     return jnp.concatenate(outs, axis=1)
 
 

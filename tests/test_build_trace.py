@@ -73,6 +73,20 @@ def test_sell_gauges_equal_sell_stats(fresh_obs, levels, binary):
     assert ml.blocks[0].n_slots == sum(stats["slots"])
 
 
+@pytest.mark.parametrize("k,packed", [(16, True), (128, False)])
+def test_fold_records_packed_slots(fresh_obs, levels, k, packed):
+    """Tracing the fold step records how many slot-rows take the
+    lane-packed gather: all of them at k=16, none at k=128."""
+    _, reg = fresh_obs
+    ml = MultiLevelArrow(levels, 32, mesh=None, fmt="fold")
+    xt = ml.set_features(random_dense(ml.n, k, seed=4))
+    assert reg.gauge("sell.packed_slots").value is None
+    ml.run(xt, 2)
+    slots = reg.gauge("sell.slots").value
+    assert slots == ml.blocks[0].n_slots > 0
+    assert reg.gauge("sell.packed_slots").value == (slots if packed else 0)
+
+
 def test_run_lowering_names_no_span(fresh_obs, levels):
     tracer, _ = fresh_obs
     ml = MultiLevelArrow(levels, 32, mesh=None, fmt="fold")

@@ -1,11 +1,14 @@
 """SELL (sliced-ELL) kernel tests (ops/sell.py): the degree-sorted
 tiered format behind the folded single-chip execution."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from scipy import sparse
 
+from arrow_matrix_tpu.obs import metrics as metrics_mod
+from arrow_matrix_tpu.ops import ell
 from arrow_matrix_tpu.ops.sell import (
     SellMatrix,
     sell_from_csr,
@@ -148,3 +151,110 @@ def test_fold_from_memmapped_artifact(tmp_path):
     x = random_dense(600, 8, seed=2)
     out = ml.gather_result(ml.step(ml.set_features(x)))
     np.testing.assert_allclose(out, a @ x, rtol=1e-4, atol=1e-4)
+
+
+# -- lane-packed gathers at k < 128 -------------------------------------
+
+@pytest.fixture
+def registry(monkeypatch):
+    """A metrics registry of the test's own, restored after."""
+    monkeypatch.setattr(metrics_mod, "_DEFAULT", metrics_mod.MetricsRegistry())
+    return metrics_mod.get_registry()
+
+
+# A gather of whole 128-lane rows of the packed operand, as a jaxpr
+# prints it (the k-wide gather takes (k, 1) columns of x_t).
+PACKED_GATHER = "slice_sizes=(1, 128)"
+
+
+def _packed_case(binary: bool) -> sparse.csr_matrix:
+    """301 rows (a multiple of no p = 128 / k > 1), positive values, a
+    hub row in a tier of its own and an empty row."""
+    rng = np.random.default_rng(11)
+    n = 301
+    a = sparse.random(n, n, density=0.03, format="lil", random_state=rng,
+                      dtype=np.float32)
+    a[5, :] = rng.random(n, dtype=np.float32) + 0.5
+    a[0, :] = 0.0
+    a = a.tocsr()
+    a.eliminate_zeros()
+    if binary:
+        a.data[:] = 1.0
+    return a
+
+
+def _sorted_spmm(sell, xt, chunk):
+    return np.asarray(sell_spmm_t(sell, xt, chunk=chunk), dtype=np.float32)
+
+
+@pytest.mark.parametrize("chunk", [None, 1, 8])
+@pytest.mark.parametrize("binary", [True, False])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("k", [1, 8, 16, 32, 64])
+def test_lane_packed_gather(monkeypatch, registry, k, dtype, binary, chunk):
+    """At k dividing 128 every tier gathers whole packed rows and keeps
+    each slot's own lanes: the result is the k-wide gather's, bit for
+    bit (the same addends summed in the same order), and the float64
+    product's to rounding."""
+    a = _packed_case(binary)
+    sell, order = sell_from_csr(a)
+    assert sell.binary == binary
+    assert max(c.shape[0] for c in sell.cols) > 8     # chunk 8 scans
+    x = random_dense(a.shape[0], k, seed=k)
+    xt = jnp.asarray(x[order].T, dtype=dtype)
+
+    jaxpr = str(jax.make_jaxpr(lambda v: sell_spmm_t(sell, v, chunk=chunk))(xt))
+    assert PACKED_GATHER in jaxpr                     # whole-row gathers
+    # The select must not round f32 to bf16 on the chip's MXU.
+    assert "precision=(Precision.HIGHEST, Precision.HIGHEST)" in jaxpr
+    assert registry.gauge("sell.packed_slots").value == sell.n_slots
+    got = _sorted_spmm(sell, xt, chunk)
+
+    monkeypatch.setattr(ell, "lane_pack_factor", lambda k: 1)
+    today = _sorted_spmm(sell, xt, chunk)
+    assert registry.gauge("sell.packed_slots").value == 0
+
+    # The float64 product of the carried (possibly bf16-rounded) x, in
+    # the sorted coordinates the operator runs in.
+    x64 = np.asarray(xt.astype(jnp.float32), dtype=np.float64).T
+    want = (a.astype(np.float64) @ x64[order.argsort()])[order].T
+    np.testing.assert_array_equal(got, today)
+    rel = 1e-5 if dtype == "float32" else 2.0 ** -7
+    assert np.abs(got - want).max() <= rel * np.abs(want).max()
+
+
+@pytest.mark.parametrize("k", [48, 128])
+def test_lane_pack_needs_k_dividing_the_tile(registry, k):
+    """k = 128, or k not dividing 128, keeps the k-wide gather: no
+    packed gather or select matmul in the program, and a zero gauge."""
+    a = _packed_case(True)
+    sell, order = sell_from_csr(a)
+    x = random_dense(a.shape[0], k, seed=3)
+    xt = jnp.asarray(x[order].T)
+    jaxpr = str(jax.make_jaxpr(lambda v: sell_spmm_t(sell, v))(xt))
+    assert PACKED_GATHER not in jaxpr and "dot_general" not in jaxpr
+    assert registry.gauge("sell.packed_slots").value == 0
+    out = np.empty_like(x)
+    out[order] = np.asarray(sell_spmm_t(sell, xt)).T
+    want = a @ x
+    assert np.abs(out - want).max() <= 1e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("k", [1, 16, 64])
+def test_packed_tier_chunks_as_at_k128(k):
+    """The gather intermediate is budgeted at its physical 128 lanes: a
+    2^22-row, 8-slot tier under the fold's budget on a 16 GB v5e (half
+    its HBM, ``gather_budget_for``) runs as single-slot gathers at any
+    packed k, as at k=128, on one chip and on a mesh stack alike."""
+    from arrow_matrix_tpu.parallel.multi_level import gather_budget_for
+    from arrow_matrix_tpu.parallel.sell_slim import SellShardStack, tier_chunks
+
+    budget = gather_budget_for(8 << 30)
+    rows, m = 1 << 22, 8
+    assert ell.feature_major_chunk(rows, 128, m, budget) == 1
+    assert ell.feature_major_chunk(rows, k, m, budget) == 1
+    stack = SellShardStack(
+        cols=(jax.ShapeDtypeStruct((4, m, rows), jnp.int32),),
+        deg=(jax.ShapeDtypeStruct((4, rows), jnp.int32),))
+    assert (tier_chunks(stack, k, 4, budget)
+            == tier_chunks(stack, 128, 4, budget) == [(m, rows, 1)])
