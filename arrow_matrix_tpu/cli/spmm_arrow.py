@@ -154,16 +154,15 @@ def build_parser() -> argparse.ArgumentParser:
                              "to override) and degrades LOUDLY to c=1 "
                              "when nothing bigger fits.")
     parser.add_argument("--fold_growth", type=float, default=1.2,
-                        help="fmt=fold tier growth factor: padded "
-                             "slots <= growth x nnz by construction. "
-                             "1.1 with --fold_align 1 is the "
-                             "'fold_tight' bench candidate (-17%% "
-                             "logical slots at the protocol config).")
+                        help="fmt=fold tier growth factor: fixes the "
+                             "tier count, as many tiers as splitting "
+                             "at this degree ratio makes; the tiers "
+                             "are then placed to minimise padded "
+                             "slots.")
     parser.add_argument("--fold_align", type=int, default=None,
-                        help="fmt=fold slot alignment (default: the "
-                             "8-sublane tile; 1 = no alignment — "
-                             "fewest logical gather slots, the bench's "
-                             "fold_tight packing).")
+                        help="fmt=fold slot alignment (default 1: "
+                             "tiers of exact degrees; 8 pads each row "
+                             "to the 8-sublane tile, more gathers).")
     parser.add_argument("--memmap", type=str2bool, nargs="?",
                         default=False, const=True,
                         help="Memory-map the decomposition artifact and "

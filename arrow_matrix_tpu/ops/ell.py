@@ -30,9 +30,10 @@ import jax.numpy as jnp
 import numpy as np
 from scipy import sparse
 
-# Slot counts, gather chunks and the packed gather's tier rows are
-# multiples of this (one sublane tile: the second-minor dimension of a
-# TPU array pads to it).
+# Gather chunks, the packed gather's tier rows and the slot counts of
+# the arrow-block ELLs are multiples of this (one sublane tile: the
+# second-minor dimension of a TPU array pads to it).  The fold's tiers
+# hold exact degrees: a padded slot is gathered like a real one.
 SLOT_ALIGN = 8
 # Width of a TPU vector register and of the minor tile dimension: a
 # gathered row narrower than this costs about as much as a whole one.
@@ -168,6 +169,21 @@ def feature_major_chunk(rows: int, k: int, m: int, budget_bytes: int,
     if c < SLOT_ALIGN:
         return 1
     return None if c >= m else c
+
+
+def slot_runs(m: int, chunk: Optional[int]) -> list[tuple[int, int, int]]:
+    """How :func:`ell_spmm_t` walks ``m`` slots under ``chunk``: runs
+    ``(lo, hi, c)``, slots ``lo .. hi - 1`` gathered ``c`` at a time.
+    The whole chunks come first; when ``chunk`` does not divide ``m``
+    the last ``m % chunk`` slots follow one at a time, as 2-D gathers,
+    so that no padded slot is gathered."""
+    if m == 0:
+        return []
+    c = m if chunk is None else min(chunk, m)
+    whole = m - m % c
+    if whole == m:
+        return [(0, m, c)]
+    return [(0, whole, c), (whole, m, 1)]
 
 
 def auto_chunk(rows: int, k: int, m: int, budget_bytes: int,
@@ -339,7 +355,9 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
     :param deg:  (rows,) int32 valid-slot counts (binary mode only).
     :param chunk: slot-axis chunk bounding the gather intermediate
         (chunk * rows gathered rows, see :func:`feature_major_chunk`);
-        None processes all slots at once.
+        None processes all slots at once.  When it does not divide m,
+        the slots past the last whole chunk are gathered one at a time
+        after the chunks (:func:`slot_runs`): nothing is padded.
     :param packed: ``lane_pack(x_t)``, for callers that run several
         tiers over one operand; built here when not given.
     :returns: (k, rows) result, feature-major.
@@ -361,14 +379,6 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
                 data = jnp.pad(data, ((0, 0), (0, rows - rows_out)))
             else:
                 deg = jnp.pad(deg, (0, rows - rows_out))
-    c = m if chunk is None else min(chunk, m)
-    n_chunks = align_up(m, c) // c
-    pad = n_chunks * c - m
-    if pad:
-        cols = jnp.pad(cols, ((0, pad), (0, 0)))
-        if data is not None:
-            data = jnp.pad(data, ((0, pad), (0, 0)))
-
     def gather(cols_c):
         """``x_t[:, cols_c]``: (k, *cols_c.shape) in x_t's dtype."""
         if packed is not None:
@@ -378,7 +388,7 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
         return jnp.take(x_t, cols_c.reshape(-1), axis=1).reshape(
             (k,) + cols_c.shape)
 
-    def contribution(cols_c, w_c):
+    def contribution(cols_c, w_c, c):
         if c == 1:
             # One slot per step: a 2-D (k, rows) gather.  A (k, 1, rows)
             # intermediate would pad its slot axis to a whole sublane
@@ -395,32 +405,46 @@ def ell_spmm_t(cols: jax.Array, x_t: jax.Array,
         # resolve_feature_dtype.
         return (g * w_c[None]).sum(axis=1, dtype=jnp.float32)
 
-    if n_chunks == 1:
-        if data is not None:
-            w = data
-        else:
-            w = (jnp.arange(m + pad, dtype=deg.dtype)[:, None]
-                 < deg[None, :])
-        acc = contribution(cols, w)
-    else:
-        cols_c = cols.reshape(n_chunks, c, rows)
-        if data is not None:
+    def run_sum(lo, hi, c):
+        """f32 (k, rows): the sum over slots lo..hi-1, in chunks of c."""
+        n_chunks = (hi - lo) // c
+        cols_r, data_r = cols, data
+        if (lo, hi) != (0, m):
+            cols_r = cols[lo:hi]
+            data_r = None if data is None else data[lo:hi]
+        if n_chunks == 1:
+            if data_r is not None:
+                w = data_r
+            else:
+                w = (jnp.arange(lo, hi, dtype=deg.dtype)[:, None]
+                     < deg[None, :])
+            return contribution(cols_r, w, c)
+        cols_c = cols_r.reshape(n_chunks, c, rows)
+        if data_r is not None:
             def body(acc, xs):
                 cc, dc = xs
-                return acc + contribution(cc, dc), None
-            xs = (cols_c, data.reshape(n_chunks, c, rows))
+                return acc + contribution(cc, dc, c), None
+            xs = (cols_c, data_r.reshape(n_chunks, c, rows))
         else:
             offsets = jnp.arange(n_chunks, dtype=deg.dtype) * c
+            if lo:
+                offsets = offsets + lo
 
             def body(acc, xs):
                 cc, off = xs
                 w = (off + jnp.arange(c, dtype=deg.dtype)[:, None]
                      < deg[None, :])
-                return acc + contribution(cc, w), None
+                return acc + contribution(cc, w, c), None
             xs = (cols_c, offsets)
 
         acc0 = jnp.zeros((k, rows), dtype=jnp.float32)
         acc, _ = jax.lax.scan(body, acc0, xs)
+        return acc
+
+    acc = None
+    for lo, hi, c in slot_runs(m, chunk):
+        part = run_sum(lo, hi, c)
+        acc = part if acc is None else acc + part
     if rows > rows_out:
         acc = acc[:, :rows_out]
     return acc.astype(x_t.dtype)

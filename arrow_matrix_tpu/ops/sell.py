@@ -11,10 +11,11 @@ for TPU lanes:
     already carries features in an arbitrary permuted order (level-0
     order), so the sort is composed into that permutation once on the
     host and the operator is conjugated into sorted coordinates;
-  * the sorted rows are partitioned into *tiers* at geometric degree
-    boundaries (close a tier when the next aligned degree exceeds
-    ``growth`` times the tier's smallest) — padded slots <= growth x
-    nonzeros by construction;
+  * the sorted rows are partitioned into *tiers* of whole degree
+    values, as many as the geometric ``growth`` rule would make, placed
+    to minimise the padded slots (``fold_tiers``): each tier's rows pad
+    to its largest degree, and a padded slot costs a gather like a
+    real one;
   * each tier is one slot-major (m_t, n_t) ELL computed feature-major
     (ops/ell.py ``ell_spmm_t``: every stored array keeps its large
     dimension minor, and features narrower than the 128-lane tile are
@@ -46,11 +47,10 @@ from arrow_matrix_tpu.io.graphio import CsrLike, num_rows
 from arrow_matrix_tpu.obs.metrics import get_registry
 from arrow_matrix_tpu.obs.tracer import get_tracer
 from arrow_matrix_tpu.ops.ell import (
-    SLOT_ALIGN,
-    align_up,
     ell_spmm_t,
     feature_major_chunk,
     lane_pack,
+    slot_runs,
 )
 
 
@@ -92,12 +92,11 @@ class SellMatrix:
 
 def tier_boundaries(sorted_aligned_deg: np.ndarray,
                     growth: float = 1.2) -> list[int]:
-    # Default 1.2 measured at n=1M BA-8: 1.25x nnz padded slots over 28
-    # tiers, vs 1.61x at growth=1.5 — padded slots ARE the gather cost.
     """Tier start indices over ascending aligned degrees: a new tier
     starts whenever the degree exceeds ``growth`` times the tier's
     first degree (so within-tier ELL padding is < growth), with the
-    zero-degree prefix always its own tier."""
+    zero-degree prefix always its own tier.  The fold uses it only for
+    its tier count (:func:`fold_tiers`)."""
     starts = [0]
     n = sorted_aligned_deg.size
     if n == 0:
@@ -113,9 +112,87 @@ def tier_boundaries(sorted_aligned_deg: np.ndarray,
     return starts
 
 
+def optimal_tier_starts(sorted_deg: np.ndarray, n_tiers: int) -> list[int]:
+    """Start indices of at most ``n_tiers`` tiers over ascending degrees
+    that minimise the slots ``sum(m_t * n_t)`` (m_t the tier's largest
+    degree, n_t its rows), with the zero-degree prefix its own tier.
+
+    A DP over the D distinct nonzero degrees: the fewest slots that
+    cover degrees 0..j in g + 1 tiers is the least, over the last
+    tier's first degree i, of the best g-tier cover of 0..i-1 plus
+    ``deg[j] * rows(i..j)``.  Each of the T steps is a vectorized
+    minimum over a (D, D) array, taken in blocks of degrees j of at
+    most 2^22 entries: O(T * D^2) time, bounded memory (D = 1,141 at a
+    2^22-row BA fold: one block).  Any partition into tiers of
+    whole degree values, such as :func:`tier_boundaries`', is a
+    candidate, so the result never holds more slots than it at the
+    same tier count; with at most ``n_tiers`` distinct degrees every
+    degree is a tier of its own and the slots equal the nonzeros.
+    Ties go to the earliest start, so the same degrees give the same
+    tiers.
+    """
+    if sorted_deg.size == 0:
+        return [0]
+    vals, first, counts = np.unique(sorted_deg, return_index=True,
+                                    return_counts=True)
+    prefix = []
+    if vals[0] == 0:                       # the zero-degree tier
+        prefix, vals, first, counts = [0], vals[1:], first[1:], counts[1:]
+        n_tiers -= 1
+    d = vals.size
+    if d == 0:
+        return prefix
+    groups = max(1, min(n_tiers, d))
+    if groups == d:
+        return prefix + first.tolist()
+
+    vals = vals.astype(np.int64)
+    rows = np.concatenate([[0], np.cumsum(counts, dtype=np.int64)])
+    block = max(1, (1 << 22) // d)         # degrees j per (j, i) array
+    best = vals * rows[1:]                 # one tier over degrees 0..j
+    choice = []                            # per step: the last tier's start
+    for g in range(1, groups):
+        # Starts i >= g: best[i - 1] covers 0..i-1 in g tiers.
+        starts_i = np.arange(g, d)
+        base = best[None, g - 1:d - 1]
+        arg = np.empty(d - g, dtype=np.int64)
+        new = np.zeros(d, dtype=np.int64)
+        for j0 in range(g, d, block):
+            j = np.arange(j0, min(j0 + block, d))
+            # best[i - 1] + deg[j] * (rows(0..j) - rows(0..i-1)) over
+            # i <= j; deg[j] * rows(0..j) is added after the minimum.
+            cost = base - vals[j, None] * rows[None, g:d]
+            cost[starts_i[None, :] > j[:, None]] = np.int64(1) << 61
+            a = cost.argmin(axis=1)
+            arg[j - g] = a
+            new[j] = cost[np.arange(j.size), a] + vals[j] * rows[j + 1]
+        best = new
+        choice.append(arg + g)
+    starts, j = [], d - 1
+    for g in range(groups - 1, 0, -1):
+        j = int(choice[g - 1][j - g])
+        starts.append(j)
+        j -= 1
+    return prefix + first[[0] + starts[::-1]].tolist()
+
+
+def fold_tiers(sorted_deg: np.ndarray, growth: float = 1.2,
+               slot_align: int = 1) -> tuple[np.ndarray, list[int]]:
+    """``(aligned, starts)``: the ascending degrees rounded up to
+    ``slot_align`` (each tier's slot count is its last row's), and the
+    tier starts the fold packs.  ``growth`` fixes the tier count, that
+    of :func:`tier_boundaries`; :func:`optimal_tier_starts` places the
+    tiers.  The XLA step gathers slot by slot, so alignment above 1
+    only adds gathers."""
+    aligned = (align_up_vec(sorted_deg, slot_align) if slot_align > 1
+               else sorted_deg)
+    n_tiers = len(tier_boundaries(aligned, growth))
+    return aligned, optimal_tier_starts(aligned, n_tiers)
+
+
 def sell_from_csr(matrix: CsrLike, pad_rows_to: Optional[int] = None,
                   dtype=np.float32, binary: Union[str, bool] = "auto",
-                  growth: float = 1.2, slot_align: int = SLOT_ALIGN,
+                  growth: float = 1.2, slot_align: int = 1,
                   ) -> tuple[SellMatrix, np.ndarray]:
     """Pack a CSR (or memmapped triplet) into sorted sliced-ELL.
 
@@ -162,14 +239,8 @@ def _pack(matrix: CsrLike, pad_rows_to, dtype, binary, growth,
 
     order = np.argsort(degrees, kind="stable").astype(np.int64)
     inv_order = np.argsort(order).astype(np.int32)
-    # slot_align trades physical tile friendliness against LOGICAL
-    # slots: tile padding costs no gathers, padded slots do.  Measured
-    # at n=2^20 BA-8: align 8 / growth 1.2 -> 21.0M slots (1.25x nnz);
-    # align 1 / growth 1.1 -> 17.4M (1.04x) over ~60 tiers — the
-    # "fold_tight" bench candidate races the two on chip.
-    aligned = (align_up_vec(degrees[order], slot_align)
-               if slot_align > 1 else degrees[order])
-    starts = tier_boundaries(aligned, growth) + [total]
+    aligned, starts = fold_tiers(degrees[order], growth, slot_align)
+    starts = starts + [total]
 
     nnz = int(indptr[-1])
     all_cols = inv_order[np.asarray(indices[:nnz])]
@@ -237,13 +308,16 @@ def sell_spmm_t(m: SellMatrix, x_t: jax.Array,
     When k divides the 128-lane tile, the operand is lane-packed once
     here and every tier gathers whole packed rows (``ell_spmm_t``); the
     ``sell.packed_slots`` gauge records the slot-rows that take that
-    form (0 when it does not engage), once per trace.
+    form (0 when it does not engage), once per trace.  The
+    ``sell.gathered_slots`` gauge records the slot-rows the step
+    gathers, over each tier's rows: its slots as ``ell_spmm_t`` walks
+    them under the tier's chunk (``slot_runs``).
     """
     k = x_t.shape[0]
     packed = lane_pack(x_t)
-    get_registry().gauge("sell.packed_slots").set(
-        0 if packed is None else m.n_slots)
-    outs = []
+    reg = get_registry()
+    reg.gauge("sell.packed_slots").set(0 if packed is None else m.n_slots)
+    outs, gathered = [], 0
     for t, cols in enumerate(m.cols):
         m_t, n_t = cols.shape
         if m_t == 0:
@@ -253,11 +327,13 @@ def sell_spmm_t(m: SellMatrix, x_t: jax.Array,
         if c is None and gather_budget is not None:
             c = feature_major_chunk(n_t, k, m_t, gather_budget,
                                     jnp.dtype(x_t.dtype).itemsize)
+        gathered += n_t * sum(hi - lo for lo, hi, _ in slot_runs(m_t, c))
         outs.append(ell_spmm_t(
             cols, x_t,
             data=None if m.data is None else m.data[t],
             deg=None if m.deg is None else m.deg[t],
             chunk=c, packed=packed))
+    reg.gauge("sell.gathered_slots").set(gathered)
     return jnp.concatenate(outs, axis=1)
 
 

@@ -622,13 +622,12 @@ class MultiLevelArrow:
 
         # SELL packing in degree-sorted coordinates; the sort permutation
         # is composed into the carried ordering (set_features/
-        # gather_result), so it is free at runtime.
-        if slot_align is None:   # follow the library-wide tile alignment
-            from arrow_matrix_tpu.ops.ell import SLOT_ALIGN
-            slot_align = SLOT_ALIGN
+        # gather_result), so it is free at runtime.  Tiers hold exact
+        # degrees unless the caller aligns them.
         sell, order = sell_from_csr(folded, pad_rows_to=self.total_rows,
                                     dtype=dtype, binary=self.binary,
-                                    growth=growth, slot_align=slot_align)
+                                    growth=growth,
+                                    slot_align=slot_align or 1)
         self.perm0 = self.perm0[order]
         self.inv_perm0 = np.argsort(self.perm0)
         self._finalize_folded(sell, chunk, gather_budget)

@@ -101,25 +101,22 @@ def structure_fingerprint(levels, width: int, dtype=np.float32,
     split a hash."""
     from arrow_matrix_tpu.io.graphio import num_rows
     from arrow_matrix_tpu.obs.imbalance import summarize_units
-    from arrow_matrix_tpu.ops.ell import SLOT_ALIGN
-    from arrow_matrix_tpu.ops.sell import align_up_vec, tier_boundaries
+    from arrow_matrix_tpu.ops.sell import fold_tiers
     from arrow_matrix_tpu.parallel.multi_level import (
         resolve_block_dtype,
         resolve_levels_binary,
     )
 
     if slot_align is None:
-        slot_align = SLOT_ALIGN
+        slot_align = 1
     dtype = resolve_block_dtype(dtype)
     total = folded_total_rows(levels, width)
     deg = folded_degrees(levels, total)
 
-    # The exact ladder the SELL packer would build: ascending aligned
-    # degrees, tiers split at the growth ratio.
+    # The exact ladder the SELL packer builds (ops/sell.fold_tiers).
     sorted_deg = np.sort(deg, kind="stable")
-    aligned = (align_up_vec(sorted_deg, slot_align) if slot_align > 1
-               else sorted_deg)
-    starts = tier_boundaries(aligned, growth) + [total]
+    aligned, starts = fold_tiers(sorted_deg, growth, slot_align)
+    starts = starts + [total]
     tier_rows, tier_nnz, tier_slots, tier_width = [], [], [], []
     for lo, hi in zip(starts[:-1], starts[1:]):
         m_t = int(aligned[hi - 1]) if hi > lo else 0

@@ -76,7 +76,7 @@ class TunePlan:
     kernel: str = "xla"
     chunk: Any = "auto"
     fold_growth: float = 1.2
-    fold_align: Optional[int] = None       # None -> ops/ell.SLOT_ALIGN
+    fold_align: Optional[int] = None       # None -> the executor's own
     feature_dtype: Optional[str] = None    # None -> f32 carriage
     overlap_slabs: int = 1
     repl: int = 1

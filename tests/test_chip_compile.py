@@ -137,6 +137,27 @@ def _xla_fold(one_chip):
     return f, (m, _spec(one_chip, (16, n), jnp.float32))
 
 
+def _xla_fold_tails(one_chip):
+    """The XLA fold step at k=128 over exact-degree tiers whose slot
+    counts the gather budget's chunk of 8 does not divide: each tier's
+    last m mod 8 slots are gathered after its whole chunks."""
+    tiers = [(10, 114540), (12, 69089), (19, 41117), (1157, 354)]
+    n = sum(r for _, r in tiers)
+    starts = tuple(int(s) for s in np.cumsum([0] + [r for _, r in
+                                                     tiers[:-1]]))
+    m = SellMatrix(
+        cols=tuple(_spec(one_chip, (m_t, r), jnp.int32)
+                   for m_t, r in tiers),
+        data=None,
+        deg=tuple(_spec(one_chip, (r,), jnp.int32) for _, r in tiers),
+        n_rows=n, row_starts=starts)
+
+    def f(m, x_t):
+        return sell_spmm_t(m, x_t, gather_budget=1 << 29)
+
+    return f, (m, _spec(one_chip, (128, n), jnp.float32))
+
+
 CASES = {
     **{f"sell_stream_k{k}_{c}": (lambda oc, k=k, c=c: _sell_stream(oc, k, c),
                                  True)
@@ -146,6 +167,7 @@ CASES = {
     "head": (_head, True),
     "synth_schedule": (_synth_schedule, True),
     "xla_fold_n2e20": (_xla_fold, False),
+    "xla_fold_tails": (_xla_fold_tails, False),
 }
 
 

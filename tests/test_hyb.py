@@ -229,13 +229,15 @@ def test_fold_export_load_roundtrip(tmp_path):
 
 def test_fold_tight_packing_matches_golden():
     """fold_align=1 / fold_growth=1.1 (the 'fold_tight' bench
-    candidate): fewer padded slots, BIT-equivalent math — tile
-    padding costs no gathers, logical slots do (ops/sell.py)."""
+    candidate): fewer padded slots than tiers aligned to 8, the same
+    addends — tile padding costs no gathers, logical slots do
+    (ops/sell.py)."""
     n, width = 480, 32
     a = barabasi_albert(n, 6, seed=19)
     levels = arrow_decomposition(a, width, max_levels=3,
                                  block_diagonal=True, seed=2)
-    ml = MultiLevelArrow(levels, width, mesh=None, fmt="fold")
+    ml = MultiLevelArrow(levels, width, mesh=None, fmt="fold",
+                         fold_align=8)
     tight = MultiLevelArrow(levels, width, mesh=None, fmt="fold",
                             fold_growth=1.1, fold_align=1)
     assert tight.blocks[0].n_slots < ml.blocks[0].n_slots

@@ -69,7 +69,7 @@ def test_weighted_matches_golden():
 
 def test_full_matrix_via_sell_from_csr():
     # End-to-end against the packed multi-tier format the fold executor
-    # actually carries (zero tier + growth ladder + alignment padding).
+    # actually carries (zero tier + slot-optimal exact-degree tiers).
     a = barabasi_albert(3000, 5, seed=7)
     sell, order = sell_from_csr(a, pad_rows_to=3072)
     x = random_dense(3072, 16, seed=8)[order]

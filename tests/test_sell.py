@@ -11,12 +11,14 @@ from arrow_matrix_tpu.obs import metrics as metrics_mod
 from arrow_matrix_tpu.ops import ell
 from arrow_matrix_tpu.ops.sell import (
     SellMatrix,
+    fold_tiers,
+    optimal_tier_starts,
     sell_from_csr,
     sell_spmm_t,
     tier_boundaries,
 )
 from arrow_matrix_tpu.utils import barabasi_albert, random_dense
-from arrow_matrix_tpu.utils.graphs import random_csr
+from arrow_matrix_tpu.utils.graphs import grid_graph, random_csr
 
 
 def spmm_via_sell(a, x, **kw):
@@ -36,6 +38,105 @@ def test_tier_boundaries():
     assert starts == [0, 2, 5, 7]
     assert tier_boundaries(np.array([], dtype=np.int64)) == [0]
     assert tier_boundaries(np.array([8, 8, 8])) == [0]
+
+
+# -- slot-optimal tiers ---------------------------------------------------
+
+def _slots(sorted_deg, starts):
+    """Slots of the tiers starting at ``starts``: each row padded to its
+    tier's largest degree."""
+    ends = list(starts[1:]) + [sorted_deg.size]
+    return sum(int(sorted_deg[hi - 1]) * (hi - lo)
+               for lo, hi in zip(starts, ends))
+
+
+def _histogram(kind, seed):
+    rng = np.random.default_rng(seed)
+    if kind == "uniform":
+        deg = rng.integers(0, 300, 4000)
+    elif kind == "sparse":              # few rows over a wide range
+        deg = rng.integers(1, 5000, 60)
+    elif kind == "wide":                # 2,200 distinct: the DP in blocks
+        deg = np.concatenate([np.arange(1000, 3200),
+                              rng.integers(1000, 3200, 2000)])
+    else:                               # power law, with empty rows
+        a = barabasi_albert(6000, 4, seed=seed)
+        deg = np.concatenate([np.diff(a.indptr), np.zeros(50, np.int64)])
+    return np.sort(deg)
+
+
+@pytest.mark.parametrize("growth", [1.1, 1.2, 1.5])
+@pytest.mark.parametrize("kind,seed", [("uniform", 0), ("uniform", 1),
+                                       ("sparse", 2), ("powerlaw", 3),
+                                       ("powerlaw", 4), ("wide", 5)])
+def test_optimal_tiers_never_beat_by_growth_rule(kind, seed, growth):
+    """At the growth rule's tier count the DP's tiers hold no more slots
+    than the growth rule's (which is one of its candidates), cover whole
+    degree values, and keep the zero-degree prefix a tier of its own."""
+    deg = _histogram(kind, seed)
+    rule = tier_boundaries(deg, growth)
+    aligned, starts = fold_tiers(deg, growth)
+    assert aligned is deg
+    assert len(starts) <= len(rule)
+    assert _slots(deg, starts) <= _slots(deg, rule)
+    assert starts[0] == 0 and starts == sorted(set(starts))
+    assert all(deg[s - 1] < deg[s] for s in starts[1:])
+    zeros = int(np.count_nonzero(deg == 0))
+    if zeros:
+        assert starts[1] == zeros
+
+
+def _brute_force_slots(sorted_deg, n_tiers):
+    """Fewest slots over every placement of at most n_tiers tiers of
+    whole degree values (zero-degree rows a tier of their own)."""
+    import itertools
+
+    zeros = int(np.count_nonzero(sorted_deg == 0))
+    cuts = [int(i) for i in np.flatnonzero(np.diff(sorted_deg)) + 1
+            if i > zeros]
+    free = n_tiers - 1 - (zeros > 0)       # tiers past the first nonzero
+    head = [0, zeros] if zeros else [0]
+    return min(_slots(sorted_deg, head + list(c))
+               for r in range(min(free, len(cuts)) + 1)
+               for c in itertools.combinations(cuts, r))
+
+
+@pytest.mark.parametrize("n_tiers", [2, 3, 4, 6])
+@pytest.mark.parametrize("seed", range(4))
+def test_optimal_tiers_match_brute_force(seed, n_tiers):
+    rng = np.random.default_rng(100 + seed)
+    deg = np.sort(rng.choice([0, 1, 2, 3, 5, 8, 9, 13, 21, 40],
+                             size=int(rng.integers(8, 60))))
+    starts = optimal_tier_starts(deg, n_tiers)
+    assert len(starts) <= n_tiers
+    assert _slots(deg, starts) == _brute_force_slots(deg, n_tiers)
+
+
+@pytest.mark.parametrize("deg,n_tiers", [
+    ([0, 0, 2, 2, 3, 4, 4, 4], 4),      # the lattice: pad rows, 2, 3, 4
+    ([2, 3, 3, 4], 3),
+    ([5, 5, 5], 1),
+    ([0, 7, 9, 11, 30], 8),
+])
+def test_optimal_tiers_exact_when_few_degrees(deg, n_tiers):
+    """With no more distinct degrees than tiers, every degree is a tier
+    of its own: the slots are the nonzeros."""
+    deg = np.asarray(deg)
+    starts = optimal_tier_starts(deg, n_tiers)
+    assert _slots(deg, starts) == deg.sum()
+    assert starts == [int(i) for i in np.unique(deg, return_index=True)[1]]
+
+
+def test_fold_tiers_of_a_lattice():
+    """The growth rule gives the lattice (pad rows, then degrees 2-4)
+    four tiers; over exact degrees the fold packs no padded slot, and an
+    explicit alignment of 8 pads each row to 8."""
+    deg = np.sort(np.diff(grid_graph(40).indptr))
+    deg = np.concatenate([np.zeros(24, np.int64), deg])
+    aligned, starts = fold_tiers(deg)
+    assert len(starts) == 4 and _slots(aligned, starts) == deg.sum()
+    aligned8, starts8 = fold_tiers(deg, slot_align=8)
+    assert _slots(aligned8, starts8) == 8 * np.count_nonzero(deg)
 
 
 def test_sell_matches_scipy_weighted():
@@ -258,3 +359,90 @@ def test_packed_tier_chunks_as_at_k128(k):
         deg=(jax.ShapeDtypeStruct((4, rows), jnp.int32),))
     assert (tier_chunks(stack, k, 4, budget)
             == tier_chunks(stack, 128, 4, budget) == [(m, rows, 1)])
+
+
+# -- a chunked tier's last partial chunk ---------------------------------
+
+def _tier(form, m, rows=96, n=384, seed=0):
+    """One (m, rows) tier over n columns: ``(x_t, kwargs)`` for
+    ``ell_spmm_t``.  Rows and columns are multiples of 8, so nothing is
+    padded but what the chunking would pad."""
+    rng = np.random.default_rng(seed)
+    k = 16 if form == "packed" else 128
+    cols = rng.integers(0, n, (m, rows)).astype(np.int32)
+    deg = rng.integers(0, m + 1, rows).astype(np.int32)
+    deg[:4] = m                              # some rows fill every slot
+    live = np.arange(m)[:, None] < deg[None, :]
+    cols[~live] = 0
+    kw = {"cols": jnp.asarray(cols)}
+    if form == "weighted":
+        kw["data"] = jnp.asarray(rng.random((m, rows), dtype=np.float32)
+                                 * live)
+    else:
+        kw["deg"] = jnp.asarray(deg)
+    x_t = jnp.asarray(rng.random((k, n), dtype=np.float32))
+    return x_t, kw
+
+
+@pytest.mark.parametrize("m,chunk", [(11, 8), (13, 4), (9, 8), (21, 8),
+                                     (7, 3)])
+@pytest.mark.parametrize("form", ["weighted", "binary", "packed"])
+def test_chunk_tail_matches_unchunked(form, m, chunk):
+    """When the chunk does not divide the slots, the last m mod c slots
+    are gathered after the whole chunks, one at a time: nothing is
+    padded, and the result is the unchunked one to f32 rounding."""
+    x_t, kw = _tier(form, m)
+    assert ell.slot_runs(m, chunk) == [(0, m - m % chunk, chunk),
+                                       (m - m % chunk, m, 1)]
+    jaxpr = str(jax.make_jaxpr(
+        lambda v: ell.ell_spmm_t(x_t=v, chunk=chunk, **kw))(x_t))
+    assert "pad[" not in jaxpr
+    assert (PACKED_GATHER in jaxpr) == (form == "packed")
+    got = np.asarray(ell.ell_spmm_t(x_t=x_t, chunk=chunk, **kw))
+    want = np.asarray(ell.ell_spmm_t(x_t=x_t, chunk=None, **kw))
+    np.testing.assert_allclose(got, want, rtol=4e-6, atol=1e-6)
+    w = (np.asarray(kw["data"]) if "data" in kw else
+         (np.arange(m)[:, None] < np.asarray(kw["deg"])[None, :]))
+    x64 = np.asarray(x_t, dtype=np.float64)
+    ref = np.einsum("mr,kmr->kr", w, x64[:, np.asarray(kw["cols"])])
+    np.testing.assert_allclose(got, ref, rtol=4e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("form", ["weighted", "binary", "packed"])
+@pytest.mark.parametrize("m,chunk", [(16, 8), (8, 8), (12, 1), (12, None)])
+def test_aligned_chunks_lower_without_tail_code(monkeypatch, form, m,
+                                                chunk):
+    """Where the chunk divides the slots (every tier of the parent's
+    packing), the step lowers as it does with the tail code taken out:
+    one run over whole chunks, no slice of the slot axis."""
+    x_t, kw = _tier(form, m)
+
+    def lowered():
+        return jax.jit(lambda v: ell.ell_spmm_t(x_t=v, chunk=chunk, **kw)
+                       ).lower(x_t).as_text()
+
+    with_tail = lowered()
+    monkeypatch.setattr(ell, "slot_runs",
+                        lambda m, chunk: [(0, m, chunk or m)])
+    assert lowered() == with_tail
+
+
+@pytest.mark.parametrize("k", [16, 128])
+@pytest.mark.parametrize("chunk", [None, 1, 3, 8])
+def test_gathered_slots_gauge_counts_no_padding(registry, k, chunk):
+    """``sell.gathered_slots`` counts the slot-rows the step gathers,
+    each tier's slots as ``slot_runs`` walks them: with the tail it
+    equals the packed ``sell.slots`` at every chunk."""
+    a = _packed_case(False)
+    sell, order = sell_from_csr(a)
+    ms = [c.shape[0] for c in sell.cols if c.shape[0]]
+    if chunk not in (None, 1):
+        assert any(m % chunk for m in ms)    # some tier takes a tail
+    x = random_dense(a.shape[0], k, seed=4)
+    out = np.empty_like(x)
+    out[order] = np.asarray(
+        sell_spmm_t(sell, jnp.asarray(x[order].T), chunk=chunk)).T
+    assert (registry.gauge("sell.gathered_slots").value
+            == registry.gauge("sell.slots").value == sell.n_slots)
+    want = a @ x
+    assert np.abs(out - want).max() <= 1e-5 * np.abs(want).max()

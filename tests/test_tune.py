@@ -73,8 +73,24 @@ def test_hash_sensitive_to_knobs_that_change_the_operator():
     base = structure_hash(levels, 16)
     assert structure_hash(levels, 32) != base          # fold width
     assert structure_hash(levels, 16, growth=1.5) != base   # tier split
-    assert structure_hash(levels, 16, slot_align=1) != base
+    assert structure_hash(levels, 16, slot_align=8) != base
     assert structure_hash(levels, 16, dtype="bf16") != base  # carriage
+
+
+@pytest.mark.parametrize("growth,align", [(1.2, None), (1.1, 1), (1.2, 8)])
+def test_fingerprint_ladder_is_the_built_one(growth, align):
+    """The hashed ladder is the fold's own tiering (``ops/sell.fold_tiers``
+    in both), so a plan keyed on it names the operator that is built."""
+    from arrow_matrix_tpu.parallel import MultiLevelArrow
+
+    levels = _levels()
+    fp = structure_fingerprint(levels, 16, growth=growth, slot_align=align)
+    ml = MultiLevelArrow(levels, 16, mesh=None, fmt="fold",
+                         fold_growth=growth, fold_align=align)
+    sell = ml.blocks[0]
+    assert fp["ladder"]["tier_starts"] == list(sell.row_starts)
+    assert fp["ladder"]["slot_width"] == [c.shape[0] for c in sell.cols]
+    assert sum(fp["ladder"]["slots"]) == sell.n_slots
 
 
 def test_fingerprint_schema_and_k_independence():
