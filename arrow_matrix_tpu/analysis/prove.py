@@ -601,7 +601,7 @@ def _entries(n: int, width: int, k: int, n_dev: int):
             yield (f"sell_multi[c={c},S={s}]",
                    ml.collective_contract(k), progs)
 
-    # -- multi_level: a2a mesh (c=1) and single-chip fold (c via repl) -
+    # -- multi_level: a2a mesh (c=1) and the single-chip fold ---------
     from arrow_matrix_tpu.parallel.multi_level import MultiLevelArrow
 
     meshb = make_mesh((n_dev,), ("blocks",), devices=devs)
@@ -616,19 +616,16 @@ def _entries(n: int, width: int, k: int, n_dev: int):
                    "scan": (ml._scan_steps_donated, args, {"n": 2}),
                })
     yield ("multi_level_a2a[c=2]", None,
-           "MultiLevelArrow repl>1 requires fmt='fold' (mesh "
-           "replication is the SellSlim/SellMultiLevel repl_axis mode)")
+           "MultiLevelArrow has no replica axis (mesh replication is "
+           "the SellSlim/SellMultiLevel repl_axis mode)")
 
-    for c in (1, 2):
-        mf = MultiLevelArrow(levels, width, mesh=None, fmt="fold",
-                             repl=c)
-        xf = mf.set_features(x_host[:ba.shape[0]])
-        args = (xf,) + mf.step_operands()
-        yield (f"multi_level_fold[c={c},S=1]",
-               mf.collective_contract(k), {
-                   "step": (mf._step, args, {}),
-                   "scan": (mf._scan_steps_donated, args, {"n": 2}),
-               })
+    mf = MultiLevelArrow(levels, width, mesh=None, fmt="fold")
+    xf = mf.set_features(x_host[:ba.shape[0]])
+    args = (xf,) + mf.step_operands()
+    yield ("multi_level_fold", mf.collective_contract(k), {
+        "step": (mf._step, args, {}),
+        "scan": (mf._scan_steps_donated, args, {"n": 2}),
+    })
 
     # -- graft-classes approx carriage (H4') ---------------------------
     # The traffic-class entries: the mesh executors at bf16 (real
@@ -655,7 +652,7 @@ def _entries(n: int, width: int, k: int, n_dev: int):
                           feature_dtype="int8")
     xfi = mfi.set_features(x_host[:ba.shape[0]])
     args = (xfi,) + mfi.step_operands()
-    yield ("multi_level_fold[c=1,S=1,int8]", mfi.collective_contract(k), {
+    yield ("multi_level_fold[int8]", mfi.collective_contract(k), {
         "step": (mfi._step, args, {}),
         "scan": (mfi._scan_steps_donated, args, {"n": 2}),
     })
@@ -681,13 +678,13 @@ def _entries(n: int, width: int, k: int, n_dev: int):
                                            "schedule": sched})
         xfs = mfs.set_features(x_host[:ba.shape[0]])
         args = (xfs,) + mfs.step_operands()
-        yield ("multi_level_fold[c=1,S=1,synth]",
+        yield ("multi_level_fold[synth]",
                mfs.collective_contract(k), {
                    "step": (mfs._step, args, {}),
                    "scan": (mfs._scan_steps_donated, args, {"n": 2}),
                })
     else:
-        yield ("multi_level_fold[c=1,S=1,synth]", None,
+        yield ("multi_level_fold[synth]", None,
                "the prove-scale structure synthesized an empty "
                "schedule (no non-zero ladder tiers)")
 

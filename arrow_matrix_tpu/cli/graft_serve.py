@@ -50,17 +50,17 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--fmt", type=str, default="fold",
                    choices=["fold", "ell"],
                    help="resident executor format: 'fold' is the "
-                        "single-chip SELL fold (full degradation "
-                        "ladder), 'ell' shards level blocks over a "
-                        "--devices mesh")
+                        "single-chip SELL fold (ladder pallas_sell -> "
+                        "xla), 'ell' shards level blocks over a "
+                        "--devices mesh (ladder overlap S -> 1)")
     p.add_argument("--kernel", type=str, default="xla",
                    choices=["xla", "pallas_sell"],
                    help="base rung kernel (fold only); faults degrade "
                         "pallas_sell -> xla")
-    p.add_argument("--repl", type=int, default=1,
-                   help="base rung 2.5D column replication (fold)")
     p.add_argument("--overlap_slabs", type=int, default=1,
-                   help="base rung overlap sub-slabs")
+                   help="base rung overlap sub-slabs (--fmt ell on a "
+                        "mesh; the one-chip fold has no exchange to "
+                        "overlap and refuses S > 1)")
     p.add_argument("--queue", type=int, default=16,
                    help="bounded queue capacity; overflow sheds "
                         "explicitly")
@@ -143,7 +143,7 @@ def main(argv=None) -> int:
         mesh = make_mesh((n_dev,), ("blocks",))
     factory, n_rows = ba_executor_factory(
         args.vertices, args.width, args.seed, fmt=args.fmt, mesh=mesh)
-    base_cfg = ExecConfig(kernel=args.kernel, repl=args.repl,
+    base_cfg = ExecConfig(kernel=args.kernel,
                           overlap_slabs=args.overlap_slabs)
     policy = RetryPolicy.from_args(args)
     budget = (int(args.hbm_budget_mb * 2**20)

@@ -72,15 +72,14 @@ def build_parser() -> argparse.ArgumentParser:
                              "slim, arrow_dec_mpi.py:131).  Default: "
                              "true.")
     parser.add_argument("--fmt", type=str, default=None,
-                        choices=["auto", "dense", "ell", "hyb", "fold",
+                        choices=["auto", "dense", "ell", "fold",
                                  "sell"],
                         help="Device block format (TPU-specific: dense = "
                              "MXU batched matmuls, ell = gather path, "
-                             "hyb = whole-level split-ELL, fold = the "
-                             "whole decomposition composed into one "
-                             "degree-sorted sliced-ELL operator with "
-                             "zero inter-level routing (single-chip, "
-                             "like hyb), sell = the padding-free "
+                             "fold = the whole decomposition composed "
+                             "into one degree-sorted sliced-ELL "
+                             "operator with zero inter-level routing "
+                             "(single-chip), sell = the padding-free "
                              "feature-major mesh orchestration "
                              "(SellMultiLevel time-shared, "
                              "SellSpaceShared with --mode space; mesh "
@@ -145,14 +144,13 @@ def build_parser() -> argparse.ArgumentParser:
                              "per-step exchange's bytes by c at c-fold "
                              "operator memory plus one masked-psum "
                              "merge at gather time.  Composes with "
-                             "--fmt sell on a mesh (c must divide the "
-                             "device count and --features; --routing "
-                             "a2a only) and with --fmt fold on one "
-                             "chip (sequential column groups, zero "
-                             "comm).  'auto' runs the obs/comm T(c) "
-                             "model under the HBM budget (AMT_HBM_GB "
-                             "to override) and degrades LOUDLY to c=1 "
-                             "when nothing bigger fits.")
+                             "--fmt sell on a mesh only (c must divide "
+                             "the device count and --features; "
+                             "--routing a2a only): one chip has no "
+                             "exchange to cut.  'auto' runs the "
+                             "obs/comm T(c) model under the HBM budget "
+                             "(AMT_HBM_GB to override) and degrades "
+                             "LOUDLY to c=1 when nothing bigger fits.")
     parser.add_argument("--fold_growth", type=float, default=1.2,
                         help="fmt=fold tier growth factor: fixes the "
                              "tier count, as many tiers as splitting "
@@ -262,7 +260,7 @@ def main(argv=None) -> int:
             raise SystemExit(
                 "--slim false (wide layout) runs time-shared; "
                 "--mode space shards its per-level groups slim-style")
-        if args.fmt is not None and args.fmt in ("sell", "fold", "hyb"):
+        if args.fmt is not None and args.fmt in ("sell", "fold"):
             raise SystemExit(
                 f"--slim false (wide layout) needs a stacked block "
                 f"format (--fmt auto/dense/ell), not {args.fmt!r}")
@@ -271,7 +269,7 @@ def main(argv=None) -> int:
                 "--slim false (wide layout) composes with --routing "
                 "gather (the a2a tables cover the slim sharding)")
     if args.mode == "space":
-        if args.fmt is not None and args.fmt in ("hyb", "fold"):
+        if args.fmt == "fold":
             raise SystemExit(
                 f"--fmt {args.fmt} is a single-chip kernel; "
                 "--mode space runs levels on disjoint device groups — "
@@ -347,11 +345,11 @@ def main(argv=None) -> int:
         ok = "sell" if args.mode == "space" else "fold or sell"
         raise SystemExit(f"--feature_dtype bf16 needs --fmt {ok} "
                          f"(the other formats carry f32)")
-    if args.repl != "1" and args.fmt not in ("sell", "fold"):
+    if args.repl != "1" and args.fmt != "sell":
         raise SystemExit(
-            f"--repl needs --fmt sell (mesh replica groups) or fold "
-            f"(single-chip column groups); --fmt {args.fmt} has no "
-            f"2.5D mode")
+            f"--repl needs --fmt sell: replica groups are the mesh "
+            f"executors' (SellMultiLevel/SellSlim repl_axis); --fmt "
+            f"{args.fmt} has no 2.5D mode")
 
     width = args.width
     if args.path is None:
@@ -511,7 +509,7 @@ def main(argv=None) -> int:
                 multi = SpaceSharedArrow(levels, width, fmt=args.fmt,
                                          mesh=space_mesh)
         else:
-            if args.fmt in ("hyb", "fold") and n_dev > 1:
+            if args.fmt == "fold" and n_dev > 1:
                 raise SystemExit(
                     f"--fmt {args.fmt} is single-chip only; rerun with "
                     f"--devices 1 (or pick --fmt auto/dense/ell/sell "
@@ -555,8 +553,7 @@ def main(argv=None) -> int:
                     routing=(args.routing if mesh is not None
                              else "gather"),
                     fold_growth=args.fold_growth,
-                    fold_align=args.fold_align,
-                    repl=repl_c)
+                    fold_align=args.fold_align)
 
     # Untimed warmup: trace + compile must not pollute iteration 0's
     # spmm_time (the sibling baseline CLIs warm up the same way).

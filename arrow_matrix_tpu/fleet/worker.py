@@ -163,8 +163,7 @@ class FleetWorker:
         k = int(msg.get("k", 1))
         executor = self.server._executors.get(self.server.base_config)
         price = request_price_bytes(
-            executor, k, itemsize=self.server.itemsize,
-            repl=self.server.base_config.repl)
+            executor, k, itemsize=self.server.itemsize)
         acct = self.server.accountant
         return {"ok": True, "worker_id": self.worker_id, "k": k,
                 "bytes": int(price or 0),

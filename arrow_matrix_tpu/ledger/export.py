@@ -143,7 +143,6 @@ def ingest_tune_plans(ledger: store.Ledger,
                        "kernel": plan.get("kernel"),
                        "fmt": plan.get("fmt"),
                        "chunk": plan.get("chunk"),
-                       "overlap_slabs": plan.get("overlap_slabs"),
                        "feature_dtype": plan.get("feature_dtype")},
                 payload={"default_ms": plan.get("default_ms"),
                          "margin": plan.get("margin"),

@@ -51,7 +51,7 @@ def _check_not_folded(multi: MultiLevelArrow, what: str) -> None:
         raise ValueError(
             f"{what} does not support fmt='fold' (feature-major "
             f"step/run-only execution); build the MultiLevelArrow with "
-            f"fmt='auto'/'hyb'/'ell'/'dense' instead")
+            f"fmt='auto'/'ell'/'dense' instead")
 
 
 def sgc_init(rng: jax.Array, k_in: int, k_out: int,

@@ -6,7 +6,7 @@ ratio is bounded by construction — but ELL-family padding can still
 inflate a shard's *gathered slots* well past its nonzeros (the
 layout-padding law, PERFORMANCE.md: up to 8x from slot alignment
 alone).  This module turns the packed arrays' own metadata (degree
-masks, value stacks, slot shapes — ops/{ell,sell,hyb,arrow_blocks})
+masks, value stacks, slot shapes — ops/{ell,sell,arrow_blocks})
 into three first-class metrics per algorithm:
 
   * ``shard_nnz_max_over_mean``  — the paper's imbalance bound, as

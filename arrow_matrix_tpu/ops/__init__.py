@@ -14,7 +14,6 @@ from arrow_matrix_tpu.ops.arrow_blocks import (
     block_features,
     unblock_features,
 )
-from arrow_matrix_tpu.ops.hyb import HybLevel, hyb_from_csr, hyb_spmm
 from arrow_matrix_tpu.ops.sell import (
     SellMatrix,
     sell_from_csr,
@@ -36,10 +35,7 @@ __all__ = [
     "ell_spmm_t",
     "ArrowBlocks",
     "arrow_blocks_from_csr",
-    "HybLevel",
     "SellMatrix",
-    "hyb_from_csr",
-    "hyb_spmm",
     "sell_from_csr",
     "sell_spmm_t",
     "arrow_spmm",

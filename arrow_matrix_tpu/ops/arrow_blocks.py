@@ -262,7 +262,7 @@ def resolve_blocks_binary(matrix: CsrLike, fmt: str, binary) -> bool:
     blocks always carry values (the MXU multiplies anyway)."""
     if fmt == "dense":
         return False
-    from arrow_matrix_tpu.ops.hyb import resolve_binary
+    from arrow_matrix_tpu.ops.sell import resolve_binary
 
     if isinstance(matrix, sparse.csr_matrix):
         return resolve_binary(binary, matrix.data, nnz=matrix.nnz)

@@ -25,7 +25,8 @@ for TPU lanes:
     concatenation is free).
 
 Binary matrices (graph adjacency) drop the value arrays for per-row
-degree masks, halving streamed bytes (same rule as ops/hyb.py).
+degree masks, halving streamed bytes (``resolve_binary``: one rule
+for every packer).
 
 This is the device kernel of the folded single-chip execution
 (``MultiLevelArrow(fmt="fold")``), playing the role of the reference's
@@ -220,12 +221,44 @@ def upload_sell(sell: SellMatrix) -> SellMatrix:
                                                             sell))
 
 
+def resolve_binary(binary: Union[str, bool], data,
+                   nnz: Optional[int] = None,
+                   chunk: int = 1 << 24) -> bool:
+    """One binary-mode rule: ``data is None`` (memmap implicit ones) is
+    always binary; "auto" detects all-ones values; forcing ``True`` on
+    non-unit values is an error (the degree mask would silently drop
+    them).  ``nnz`` bounds the inspected prefix — value files may carry
+    slack beyond ``indptr[-1]`` which must not affect the decision.
+
+    The scan is chunked with early exit so memmapped >RAM value files
+    are never materialized at once (the streamed packer's contract,
+    ops/arrow_blocks.py ``arrow_blocks_streamed``); weighted data
+    usually fails on the first chunk.
+    """
+    if data is None:
+        return True
+    if binary is False:
+        return False
+
+    def all_ones() -> bool:
+        end = len(data) if nnz is None else nnz
+        for off in range(0, end, chunk):
+            if not np.all(np.asarray(data[off:min(off + chunk, end)])
+                          == 1.0):
+                return False
+        return True
+
+    if binary == "auto":
+        return all_ones()
+    if not all_ones():
+        raise ValueError("binary=True but the matrix has non-unit values")
+    return True
+
+
 def _pack(matrix: CsrLike, pad_rows_to, dtype, binary, growth,
           slot_align) -> tuple[SellMatrix, np.ndarray]:
     """``sell_from_csr``'s host work: ``(sell, order)`` with the tiers
     as numpy arrays."""
-    from arrow_matrix_tpu.ops.hyb import resolve_binary
-
     n = num_rows(matrix)
     total = max(pad_rows_to or n, n)
     if isinstance(matrix, sparse.csr_matrix):

@@ -59,7 +59,6 @@ from arrow_matrix_tpu.ops.arrow_blocks import (
     arrow_blocks_streamed,
     arrow_spmm,
 )
-from arrow_matrix_tpu.ops.hyb import HybLevel
 from arrow_matrix_tpu.parallel.mesh import (
     fetch_replicated,
     pad_to_multiple,
@@ -205,7 +204,7 @@ class MultiLevelArrow:
                  layout: str = "slim", arm_axis: str = "arm",
                  fold_growth: float = 1.2,
                  fold_align: Optional[int] = None,
-                 overlap_slabs: int = 1, repl: int = 1,
+                 overlap_slabs: int = 1,
                  plan=None, plan_k: Optional[int] = None,
                  kernel_opts: Optional[dict] = None,
                  exchange_scratch_budget: int = 0,
@@ -251,8 +250,6 @@ class MultiLevelArrow:
                     fold_growth = bk["fold_growth"]
                     fold_align = bk["fold_align"]
                     feature_dtype = bk["feature_dtype"]
-                    overlap_slabs = bk["overlap_slabs"]
-                    repl = bk["repl"]
                     # Explicit kernel_opts beat the plan's (a caller
                     # overriding one fused-kernel knob keeps the rest).
                     self.kernel_opts = {**resolved.kernel_opts(),
@@ -273,13 +270,14 @@ class MultiLevelArrow:
                 "head_fmt='gell' is the single-chip head layout (its "
                 "gather reads the whole feature array); use 'flat', "
                 "'ell' or 'auto' on a mesh")
-        if fmt in ("hyb", "fold") and mesh is not None:
+        if fmt == "fold" and mesh is not None:
             raise ValueError(
-                f"fmt={fmt!r} is a single-chip whole-level kernel (the "
-                "arrow block structure exists to shape communication; "
-                "within one chip a general split-ELL SpMM replaces it, "
-                "the way the reference's per-rank cuSPARSE CSRMM does "
-                "— sp2cp.py:6-16); use 'auto'/'dense'/'ell' on a mesh")
+                "fmt='fold' is a single-chip whole-decomposition kernel "
+                "(the arrow block structure exists to shape "
+                "communication; within one chip one general SELL SpMM "
+                "replaces it, the way the reference's per-rank "
+                "cuSPARSE CSRMM does — sp2cp.py:6-16); use "
+                "'auto'/'dense'/'ell' on a mesh")
         if routing == "a2a" and mesh is None:
             raise ValueError("routing='a2a' requires a mesh")
         # graft-reshard consumer (b): a positive budget splits every
@@ -352,29 +350,17 @@ class MultiLevelArrow:
         if overlap_slabs < 1:
             raise ValueError(f"overlap_slabs must be >= 1, got "
                              f"{overlap_slabs}")
+        # The overlap schedule splits the features so a slab's
+        # exchange can fly while another slab computes: on one chip the
+        # fold has no exchange to hide, and every slab would gather
+        # every slot again.
+        if overlap_slabs > 1 and fmt == "fold":
+            raise ValueError(
+                f"overlap_slabs={overlap_slabs} hides a mesh's "
+                "exchanges; the one-chip fold has none — use the mesh "
+                "executors (SellMultiLevel/SellSlim overlap_slabs, or "
+                "MultiLevelArrow on a mesh)")
         self.overlap_slabs = int(overlap_slabs)
-        # 2.5D replication factor (graft-repl).  On one chip this is
-        # the column-group schedule of the replicated scheme with the
-        # communication already at zero: the carried features split
-        # into c static column groups, each running the full fold step
-        # — bit-identical f32 (no accumulation regroups) and the
-        # degenerate proof point of the T(c) model's zero-comm end.
-        # The mesh-replicated executors live in SellSlim/SellMultiLevel
-        # (repl_axis on a make_repl_mesh mesh); this class's mesh path
-        # carries row-major features the slab split predates.
-        if repl < 1:
-            raise ValueError(f"repl must be >= 1, got {repl}")
-        if repl > 1 and mesh is not None:
-            raise ValueError(
-                "repl>1 on a mesh is the SellMultiLevel/SellSlim "
-                "repl_axis mode (build the mesh with make_repl_mesh); "
-                "MultiLevelArrow supports repl on the single-chip "
-                "fold path only")
-        if repl > 1 and fmt != "fold":
-            raise ValueError(
-                f"repl={repl} requires fmt='fold' (the single-chip "
-                f"column-group schedule), got fmt={fmt!r}")
-        self.repl = int(repl)
         self.width = width
         self.mesh = mesh
         self.axis = axis
@@ -481,12 +467,6 @@ class MultiLevelArrow:
             return "gell" if gell_bytes <= dense_budget // 8 else "auto"
 
         def build(lvl, w, bd, f):
-            if f == "hyb":
-                from arrow_matrix_tpu.ops.hyb import hyb_from_csr
-
-                return hyb_from_csr(lvl.matrix,
-                                    pad_rows_to=self.total_rows,
-                                    dtype=dtype, binary=self.binary)
             hf = resolve_head_fmt(lvl, w, f)
             if mesh is not None and not isinstance(lvl.matrix,
                                                    sparse.csr_matrix):
@@ -604,7 +584,7 @@ class MultiLevelArrow:
         ``A = sum_i P_i^T B_i P_i`` (the decomposition partitions the
         edge set — reference tests/test_arrowdecomposition.py:93-99), so
         the host reconstructs A conjugated into level-0 order and packs
-        it as one HybLevel; the step becomes a single general SpMM with
+        it as one SELL operator; the step becomes a single general SpMM with
         zero routing (the honest single-chip execution — the reference
         at one rank likewise runs its whole share as one CSRMM).
         Binary (all-ones) level data folds to a binary operator: levels
@@ -630,7 +610,9 @@ class MultiLevelArrow:
                                     slot_align=slot_align or 1)
         self.perm0 = self.perm0[order]
         self.inv_perm0 = np.argsort(self.perm0)
-        self._finalize_folded(sell, chunk, gather_budget)
+        self._finalize_folded(sell, chunk, gather_budget,
+                              kernel=self.kernel,
+                              kernel_opts=self.kernel_opts)
 
     def _compose_folded(self, levels) -> sparse.csr_matrix:
         """Every level's triplets conjugated into level-0 order as one
@@ -676,9 +658,14 @@ class MultiLevelArrow:
                 "edge-disjoint levels folded to duplicate positions")
         return folded
 
-    def _finalize_folded(self, sell, chunk, gather_budget: int) -> None:
+    def _finalize_folded(self, sell, chunk, gather_budget: int, *,
+                         kernel: str, kernel_opts: Optional[dict]
+                         ) -> None:
         """Install a packed SELL operator as the fold execution state
-        (shared by the levels build and ``load_folded``)."""
+        (shared by the levels build and ``load_folded``).  One step is
+        one SpMM of the whole carriage: the XLA ``sell_spmm_t`` under
+        the device-derived gather budget, or the fused ``pallas_sell``
+        kernel; an int8 carriage wraps it in the requantisation."""
         from arrow_matrix_tpu.ops.sell import sell_spmm_t
 
         self.blocks = [sell]
@@ -686,20 +673,17 @@ class MultiLevelArrow:
         self.routing = "none"
         self.fwd = self.bwd = ()
         self._ideal_route_units = 0  # single-chip fold: zero routing
-
-        kernel = getattr(self, "kernel", "xla")
-        slabs = int(getattr(self, "overlap_slabs", 1))
-        repl = int(getattr(self, "repl", 1))
+        self.kernel = kernel
         # Tuned fused-kernel call knobs (graft-tune): row_block / wave
         # / smem_cols_budget / ring, captured at build time — no env
         # reads inside the jitted step (lint R9).
-        kopts = dict(getattr(self, "kernel_opts", None) or {})
+        self.kernel_opts = kopts = dict(kernel_opts or {})
 
         int8_carry = (self.feature_dtype is not None
                       and np.dtype(self.feature_dtype)
                       == np.dtype(np.int8))
 
-        def fold_slab(xt, blocks):
+        def fold_step(xt, fwd, bwd, blocks):
             if xt.dtype == jnp.int8:
                 if kernel == "pallas_sell":
                     # Fused (q, scale) carriage: the quantized table
@@ -716,8 +700,8 @@ class MultiLevelArrow:
                     return sell_spmm_t_pallas(blocks[0], xt,
                                               feature_dtype="int8",
                                               **opts)
-                # Per-slab f32 transient: the FULL carriage stays int8
-                # in HBM; only one overlap/repl slab widens at a time.
+                # f32 transient of the step's input: the carriage
+                # itself stays int8 in HBM between steps.
                 xt = xt.astype(jnp.float32)
             if kernel == "pallas_sell":
                 # Fused gather->FMA kernel: no materialized gather
@@ -742,36 +726,6 @@ class MultiLevelArrow:
                 return sell_spmm_t(blocks[0], xt,
                                    gather_budget=gather_budget)
             return sell_spmm_t(blocks[0], xt, chunk=chunk)
-
-        def fold_group(xt, blocks):
-            if slabs <= 1:
-                return fold_slab(xt, blocks)
-            # Single-chip fold has no collectives to hide; the split
-            # still runs (one sub-step per slab) so --overlap_slabs
-            # sweeps stay shape-uniform across formats.
-            from arrow_matrix_tpu.parallel.routing import overlap_slices
-
-            outs = [fold_slab(xt[lo:hi], blocks)
-                    for lo, hi in overlap_slices(xt.shape[0], slabs)]
-            return jnp.concatenate(outs, axis=0)
-
-        def fold_step(xt, fwd, bwd, blocks):
-            if repl <= 1:
-                return fold_group(xt, blocks)
-            # 2.5D column-group schedule (graft-repl), repl outermost:
-            # each replica group owns a static k/c feature slab and
-            # runs the full overlap schedule on it (S must divide
-            # k/c).  SpMM is column-separable, so the groups never
-            # interact and the f32 result is bit-identical to repl=1.
-            from arrow_matrix_tpu.parallel.routing import repl_slab_width
-
-            kc = repl_slab_width(xt.shape[0], repl)
-            outs = []
-            for j in range(repl):
-                with jax.named_scope(f"repl_group_{j}"):
-                    outs.append(fold_group(xt[j * kc:(j + 1) * kc],
-                                           blocks))
-            return jnp.concatenate(outs, axis=0)
 
         def fold_step_q(carry, fwd, bwd, blocks):
             # int8 carriage (graft-classes): the carry is a symmetric
@@ -842,8 +796,8 @@ class MultiLevelArrow:
     @classmethod
     def load_folded(cls, in_dir: str, feature_dtype="keep",
                     chunk="auto", gather_budget: int = 1 << 30,
-                    device_put: bool = True, kernel: str = "xla",
-                    overlap_slabs: int = 1) -> "MultiLevelArrow":
+                    device_put: bool = True, kernel: str = "xla"
+                    ) -> "MultiLevelArrow":
         """Rebuild a fold executor from an ``export_folded`` directory
         without the source decomposition.  ``feature_dtype="keep"``
         uses the exported carriage dtype; ``device_put=False`` keeps
@@ -862,8 +816,7 @@ class MultiLevelArrow:
         self.axis = "blocks"
         self.folded = True
         self.carries_feature_major = True
-        self.kernel = kernel
-        self.overlap_slabs = int(overlap_slabs)
+        self.overlap_slabs = 1
         if feature_dtype == "keep":
             feature_dtype = meta["feature_dtype"]
         self.feature_dtype = resolve_feature_dtype(feature_dtype)
@@ -887,7 +840,8 @@ class MultiLevelArrow:
             row_starts=tuple(meta["row_starts"]))
         if device_put:
             sell = upload_sell(sell)
-        self._finalize_folded(sell, chunk, gather_budget)
+        self._finalize_folded(sell, chunk, gather_budget, kernel=kernel,
+                              kernel_opts=None)
         return self
 
     # -- feature placement -------------------------------------------------
@@ -1020,10 +974,9 @@ class MultiLevelArrow:
         return self._ideal_route_units * k * itemsize
 
     def reduce_comm_bytes(self, k: int, itemsize: int = 4) -> int:
-        """2.5D final-reduction bytes: always 0 here — the single-chip
-        column-group schedule concatenates disjoint slabs (no merge),
-        and the mesh path has no replica axis (see SellSlim/
-        SellMultiLevel.reduce_comm_bytes for the mesh scheme)."""
+        """2.5D final-reduction bytes: always 0 here — this class has
+        no replica axis (see SellSlim/SellMultiLevel.reduce_comm_bytes
+        for the mesh scheme)."""
         return 0
 
     def collective_contract(self, k: int, itemsize: int = None):
@@ -1038,15 +991,14 @@ class MultiLevelArrow:
         lower to all-reduce/collective-permute — declared, so H1 trips
         only on a genuine surprise all-gather); the gather routing
         leaves the exchanges to GSPMD entirely; a single chip (and
-        fmt='fold', including its repl>1 column-group schedule) is the
-        zero-communication end of the T(c) model.  The donated scan
-        entry carries the features as flat param 0 (H5)."""
+        fmt='fold') is the zero-communication end of the T(c) model.
+        The donated scan entry carries the features as flat param 0
+        (H5)."""
         from arrow_matrix_tpu.analysis.contracts import CollectiveContract
 
         if itemsize is None:
             itemsize = np.dtype(self.feature_dtype or np.float32).itemsize
-        single_chip = self.mesh is None or getattr(
-            self, "routing", "none") == "none"
+        single_chip = self.mesh is None or self.routing == "none"
         if single_chip:
             lowered_kinds = compiled_kinds = ()
         elif self.routing == "a2a":
@@ -1061,7 +1013,7 @@ class MultiLevelArrow:
             algorithm="multi_level",
             step_bytes=self.ideal_comm_bytes(k, itemsize),
             reduce_bytes=self.reduce_comm_bytes(k, itemsize),
-            repl=self.repl,
+            repl=1,
             overlap_slabs=self.overlap_slabs,
             dtype=np.dtype(self.feature_dtype or np.float32).name
             .replace("float", "f").replace("bfloat", "bf"),
@@ -1082,11 +1034,6 @@ class MultiLevelArrow:
             hot_copy_budget=(16 + 8 * len(
                 self.kernel_opts.get("schedule") or ()))
             * self.overlap_slabs,
-            h3_exempt=("single-chip fold repl is a column-group "
-                       "schedule over ZERO collectives: there is no "
-                       "exchange to carry a slab and no merge to price "
-                       "(disjoint slabs concatenate)"
-                       if single_chip and self.repl > 1 else ""),
             notes="flat row-major carriage: the routed a2a moves "
                   "(rows, k) slices, so the ÷c slab law lives in the "
                   "SELL feature-major executors")
@@ -1101,7 +1048,7 @@ class MultiLevelArrow:
         budget).  Zero for routing='gather' (GSPMD owns the exchange —
         its all-gather scratch is judged by obs/comm, not priced here)
         and on a single chip / fmt='fold' (no exchange at all)."""
-        if getattr(self, "routing", "none") != "a2a" or not self.fwd:
+        if self.routing != "a2a" or not self.fwd:
             return 0
         return max(2 * r.device_bytes_per_exchange(k, itemsize)
                    for r in list(self.fwd) + list(self.bwd))
@@ -1116,9 +1063,7 @@ class MultiLevelArrow:
         payload; bounded by the declared budget when staged).
         obs/memview judges the compiled executable against this.
         ``repl`` is the 2.5D planning multiplier (operator + carriage
-        grow exactly ×c per device at replication c on a mesh; the
-        single-chip column schedule is footprint-neutral but keeps the
-        uniform ×c planning convention)."""
+        grow exactly ×c per device at replication c on a mesh)."""
         from arrow_matrix_tpu.obs.memview import tree_device_bytes
 
         n_dev = self.mesh.shape[self.axis] if self.mesh is not None else 1
@@ -1133,9 +1078,8 @@ class MultiLevelArrow:
         """This executor's carriage as a graft-reshard
         :class:`~arrow_matrix_tpu.parallel.reshard.Layout`: padded rows
         in level-0 order, sharded over the mesh's block axis.  ``repl``
-        is the replica-expanded view for planned 2.5D growth (the
-        single-chip fold column schedule carries ONE copy, so its
-        honest layout is always repl=1).  The carried row order is
+        is the replica-expanded view for planned 2.5D growth.  The
+        carried row order is
         ``self.perm0`` — redistribution_plan's ``perm_map`` between two
         executors of the same problem is
         ``inv_perm0_src[perm0_dst]`` masked to real rows."""
@@ -1196,19 +1140,16 @@ class MultiLevelArrow:
 
 def _block_unit_stats(blk) -> dict:
     """Per-unit (rows, nnz, slots) of one level's packed operator,
-    dispatched on its layout type (arrow block grid / SELL tiers / hyb
-    split) — shared by ``MultiLevelArrow.shard_report`` and
+    dispatched on its layout type (arrow block grid / SELL tiers) —
+    shared by ``MultiLevelArrow.shard_report`` and
     ``arrow_layout.arrow_blocks_shard_report``."""
     from arrow_matrix_tpu.ops.arrow_blocks import block_row_stats
-    from arrow_matrix_tpu.ops.hyb import HybLevel, hyb_stats
     from arrow_matrix_tpu.ops.sell import SellMatrix, sell_stats
 
     if isinstance(blk, ArrowBlocks):
         return block_row_stats(blk)
     if isinstance(blk, SellMatrix):
         return sell_stats(blk)
-    if isinstance(blk, HybLevel):
-        return hyb_stats(blk)
     raise TypeError(f"no unit stats for {type(blk).__name__}")
 
 
@@ -1281,19 +1222,6 @@ def multi_level_spmm(x: jax.Array, fwd, bwd,
             with jax.named_scope(f"route_forward_{i}"):
                 x_cur = routed_or_take(x_cur, fwd[i - 1], mesh, axis)
         with jax.named_scope(f"level_{i}_spmm"):
-            if isinstance(blocks[i], HybLevel):
-                # Whole-level split-ELL on flat features (single chip;
-                # no blocking — see ops/hyb.py).
-                from arrow_matrix_tpu.ops.ell import auto_chunk
-                from arrow_matrix_tpu.ops.hyb import hyb_spmm
-
-                m0 = blocks[i].light_cols.shape[0]  # slot-major (m0, rows)
-                hyb_chunk = (auto_chunk(total, k, m0, gather_budget)
-                             if chunk == "auto" else chunk)
-                partials.append(hyb_spmm(blocks[i], x_cur,
-                                         chunk=hyb_chunk,
-                                         heavy_chunk=hyb_chunk))
-                continue
             w = widths[i]
             xb = x_cur.reshape(total // w, w, k)
             use_pallas = False

@@ -413,7 +413,7 @@ class _SliceSource:
             self._csr = None
 
     def resolve_binary(self, binary) -> bool:
-        from arrow_matrix_tpu.ops.hyb import resolve_binary
+        from arrow_matrix_tpu.ops.sell import resolve_binary
 
         return resolve_binary(binary, self._binary_data, nnz=self.nnz)
 
@@ -1005,9 +1005,10 @@ class SellSlim:
                  repl_axis: Optional[str] = None,
                  plan=None, plan_k: Optional[int] = None):
         # graft-tune consumption: the plan's structural knobs map onto
-        # this executor's vocabulary — tier split -> ladder, overlap S,
-        # carriage dtype.  (repl stays mesh-determined via repl_axis;
-        # the fused kernel knobs are fold-path-only.)  A single matrix
+        # this executor's vocabulary — tier split -> ladder, carriage
+        # dtype.  (repl stays mesh-determined via repl_axis, overlap S
+        # is this executor's own argument; the fused kernel knobs are
+        # fold-path-only.)  A single matrix
         # has no levels to hash, so plan='auto' is a loud error here —
         # pass a TunePlan/dict, or use SellMultiLevel/MultiLevelArrow.
         self.tune_plan = None
@@ -1020,7 +1021,6 @@ class SellSlim:
                 ladder = (resolved.fold_growth,
                           SLOT_ALIGN if resolved.fold_align is None
                           else resolved.fold_align)
-                overlap_slabs = resolved.overlap_slabs
                 feature_dtype = resolved.feature_dtype
         # The source canonicalizes (in-memory CSR up front, memmapped
         # triplets per slice): binary detection must see canonical
@@ -1244,7 +1244,6 @@ class SellMultiLevel:
                 ladder = (resolved.fold_growth,
                           SLOT_ALIGN if resolved.fold_align is None
                           else resolved.fold_align)
-                overlap_slabs = resolved.overlap_slabs
                 feature_dtype = resolved.feature_dtype
 
         if routing not in ("gather", "a2a"):

@@ -13,7 +13,7 @@ requests runs over it, each under graft-heal supervision.  The pieces:
     deterministic FIFO scheduler with dynamic feature-axis batching,
     per-request Supervisor (watchdog / seeded-backoff retry /
     sha256-verified checkpoint resume), and the graceful-degradation
-    ladder pallas_sell -> xla, repl=c -> 1, overlap S -> 1.
+    ladder pallas_sell -> xla, overlap S -> 1.
   * :mod:`~arrow_matrix_tpu.serve.loadgen` — deterministic synthetic
     traces and the SLO report (requests/s, p50/p99, shed counts, HBM
     occupancy) obs_gate validates — one field vocabulary with the

@@ -169,7 +169,7 @@ def ba_executor_factory(n: int, width: int, seed: int,
     levels.  Returns ``(factory, n_rows)``.
 
     ``plan`` (graft-tune) threads into every rung's build: the rung's
-    ExecConfig still wins on kernel/overlap/repl — the degradation
+    ExecConfig still wins on kernel/overlap — the degradation
     ladder must be able to step a tuned knob down — while the plan
     contributes the structural knobs (tier split, chunk, carriage
     dtype) and the fused-kernel call opts."""
@@ -207,7 +207,6 @@ def ba_executor_factory(n: int, width: int, seed: int,
         return MultiLevelArrow(levels, width, mesh=mesh,
                                kernel=cfg.kernel,
                                overlap_slabs=cfg.overlap_slabs,
-                               repl=cfg.repl,
                                kernel_opts=kernel_opts,
                                **kwargs)
 

@@ -17,14 +17,14 @@ manager inside a long-lived server:
     and already-completed requests replay for free from their final
     saves.
   * **graceful degradation** — repeated faults on a tenant's requests
-    walk that tenant down the ladder pallas_sell -> xla, repl=c -> 1,
-    overlap S -> 1 (:func:`degradation_ladder`) instead of failing the
+    walk that tenant down the ladder pallas_sell -> xla, overlap
+    S -> 1 (:func:`degradation_ladder`) instead of failing the
     request; only a tenant already on the last rung can fail.
   * **dynamic batching** — compatible queued requests (same effective
     execution config, same iteration count) are concatenated along the
     feature axis and split back after the run.  SpMM is
-    column-separable (the graft-repl/graft-stream slab law:
-    ``routing.overlap_slices`` / ``repl_slab_width``), so each
+    column-separable (the graft-stream slab law:
+    ``routing.overlap_slices``), so each
     request's slice of the batched result is bit-identical to running
     it alone — asserted by tools/serve_gate.py.
 
@@ -60,10 +60,12 @@ from arrow_matrix_tpu.utils.checkpoint import CheckpointIntegrityError
 
 @dataclasses.dataclass(frozen=True)
 class ExecConfig:
-    """One rung of the execution ladder: the three knobs graceful
-    degradation can trade away (fused kernel, 2.5D column replication,
-    overlap sub-slabs) without changing the result's row order or the
-    carriage layout — a degraded rerun resumes the same checkpoints.
+    """One rung of the execution ladder: the two knobs graceful
+    degradation can trade away without changing the result's row order
+    or the carriage layout — a degraded rerun resumes the same
+    checkpoints.  ``kernel`` is the one-chip fold's fused
+    ``pallas_sell`` kernel or XLA; ``overlap_slabs`` is the overlap
+    schedule of the mesh executors (the one-chip fold refuses S > 1).
 
     ``feature_dtype`` (graft-classes) is NOT a degradation knob: it is
     the carriage dtype of the traffic class a request is served under
@@ -73,30 +75,23 @@ class ExecConfig:
     (or a batch) with an exact one."""
 
     kernel: str = "xla"
-    repl: int = 1
     overlap_slabs: int = 1
     feature_dtype: Optional[str] = None
 
     def accepts_k(self, k: int) -> bool:
         """Whether a feature width is schedulable under this config
-        (the graft-repl/graft-stream divisibility contracts: c | k and
-        S | k/c)."""
-        if k <= 0 or k % self.repl:
-            return False
-        return (k // self.repl) % self.overlap_slabs == 0
+        (the graft-stream divisibility contract: S | k)."""
+        return k > 0 and k % self.overlap_slabs == 0
 
 
 def degradation_ladder(base: ExecConfig) -> Tuple[ExecConfig, ...]:
     """Cumulative degradation rungs from ``base`` down to the plain
-    XLA c=1 S=1 executor: fused kernel first (cheapest to give up),
-    then replication, then overlap."""
+    XLA S=1 executor: fused kernel first (cheapest to give up), then
+    overlap."""
     rungs = [base]
     cur = base
     if cur.kernel != "xla":
         cur = dataclasses.replace(cur, kernel="xla")
-        rungs.append(cur)
-    if cur.repl > 1:
-        cur = dataclasses.replace(cur, repl=1)
         rungs.append(cur)
     if cur.overlap_slabs > 1:
         cur = dataclasses.replace(cur, overlap_slabs=1)
@@ -166,7 +161,7 @@ class ArrowServer:
                  reshard_budget_bytes: int = 1 << 20):
         # graft-tune pickup: a cached TunePlan (or its dict) becomes
         # the BASE ladder rung — admitted requests run the tuned
-        # kernel/repl/overlap at zero search cost, and the degradation
+        # kernel at zero search cost, and the degradation
         # ladder below still steps every tuned knob back down under
         # pressure.  The executor_factory sees the tuned ExecConfig
         # like any other rung; factories that also consume the plan's
@@ -280,8 +275,8 @@ class ArrowServer:
                                         registry=registry, name=name)
         from arrow_matrix_tpu.obs.memview import predicted_bytes_for
 
-        resident = predicted_bytes_for(base, 0, itemsize=self.itemsize,
-                                       repl=base_config.repl) or 0
+        resident = predicted_bytes_for(base, 0,
+                                       itemsize=self.itemsize) or 0
         self.accountant.charge_resident(resident)
         self._event("started", resident_bytes=resident,
                     budget_bytes=self.accountant.budget_bytes,
@@ -371,8 +366,8 @@ class ArrowServer:
     def _effective_config(self, ticket: rq.Ticket) -> ExecConfig:
         """The ladder rung this ticket runs on: its tenant's current
         rung, or the terminal rung when the request's feature width
-        fails the rung's divisibility contract (repl/overlap need
-        c | k and S | k/c; the terminal rung accepts every k).
+        fails the rung's divisibility contract (overlap needs S | k;
+        the terminal rung accepts every k).
         Approx-served tickets get the class carriage dtype stamped on
         the rung — a distinct executor cache key, so exact and approx
         never share a compiled step or a batch."""
@@ -479,7 +474,7 @@ class ArrowServer:
                     if served == "approx" else self.itemsize)
         price = request_price_bytes(
             self._build_executor(self.base_config), request.k,
-            itemsize=itemsize, repl=self.base_config.repl)
+            itemsize=itemsize)
         ticket.predicted_bytes = price
         with self._cond:
             if self._stop:
@@ -1030,10 +1025,9 @@ class ArrowServer:
         from arrow_matrix_tpu.obs.memview import predicted_bytes_for
 
         old_res = predicted_bytes_for(
-            old_exec, 0, itemsize=self.itemsize,
-            repl=self.base_config.repl) or 0
+            old_exec, 0, itemsize=self.itemsize) or 0
         new_res = predicted_bytes_for(
-            new_exec, 0, itemsize=self.itemsize, repl=cfg.repl) or 0
+            new_exec, 0, itemsize=self.itemsize) or 0
         try:
             self.accountant.swap_resident(old_res, new_res)
         except ServeCapacityError as e:

@@ -1,10 +1,10 @@
 """Versioned, persisted tuning plans (graft-tune).
 
 A :class:`TunePlan` is ONE planned configuration: every knob the
-executors previously took as five independent arguments — format /
-tier split, kernel choice, chunking, carriage dtype, overlap ``S``,
-replication ``c``, and the fused kernel's ``row_block`` / ``wave`` /
-``smem_cols_budget`` / ``ring`` — plus the provenance that justifies
+executors previously took as independent arguments — format / tier
+split, kernel choice, chunking, carriage dtype, and the fused kernel's
+``row_block`` / ``wave`` / ``smem_cols_budget`` / ``ring`` — plus the
+provenance that justifies
 it (measured ms vs the default, margin, bit-identity verdict,
 host-load context, evaluator platform).
 
@@ -12,7 +12,7 @@ Plans persist as one JSON file per structure hash under
 ``bench_cache/tune_plans/`` (override: ``AMT_TUNE_PLAN_DIR``), with
 per-feature-width entries::
 
-    {"version": 1, "structure_hash": "...",
+    {"version": 2, "structure_hash": "...",
      "fingerprint": {...}, "plans": {"16": {...}, "128": {...}}}
 
 Consumption contract (wired through ``MultiLevelArrow`` /
@@ -39,7 +39,8 @@ from arrow_matrix_tpu.utils.artifacts import (
 
 #: Bump when the TunePlan schema or knob semantics change; a cached
 #: plan from another version is a loud miss, never a silent apply.
-PLAN_VERSION = 1
+#: Version 2 dropped the one-chip fold's ``overlap_slabs`` / ``repl``.
+PLAN_VERSION = 2
 
 DEFAULT_PLAN_DIR = os.path.join("bench_cache", "tune_plans")
 
@@ -78,8 +79,6 @@ class TunePlan:
     fold_growth: float = 1.2
     fold_align: Optional[int] = None       # None -> the executor's own
     feature_dtype: Optional[str] = None    # None -> f32 carriage
-    overlap_slabs: int = 1
-    repl: int = 1
 
     # --- knobs (fused pallas_sell kernel call) ---
     row_block: int = 256
@@ -122,8 +121,6 @@ class TunePlan:
             "fold_growth": self.fold_growth,
             "fold_align": self.fold_align,
             "feature_dtype": self.feature_dtype,
-            "overlap_slabs": self.overlap_slabs,
-            "repl": self.repl,
         }
 
     def kernel_opts(self) -> Dict[str, Any]:
@@ -144,8 +141,7 @@ class TunePlan:
         these knobs back down under pressure."""
         from arrow_matrix_tpu.serve.scheduler import ExecConfig
 
-        return ExecConfig(kernel=self.kernel, repl=self.repl,
-                          overlap_slabs=self.overlap_slabs,
+        return ExecConfig(kernel=self.kernel,
                           feature_dtype=self.feature_dtype)
 
     def to_dict(self) -> dict:

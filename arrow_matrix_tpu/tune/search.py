@@ -362,7 +362,7 @@ def search(source: dict, k: int, *, iters: int = 3,
                              for c in generated))
             extra = list(extra or []) + generated
     cands, pruned = enumerate_candidates(
-        fp, k, platform=platform, budget_bytes=found["hbm_budget"],
+        fp, k, platform=platform,
         allow_int8=allow_int8, restrict=restrict, traffic_class=traffic_class, extra=extra,
         lens_model=lens_model)
     for name, why in pruned.items():
@@ -506,7 +506,6 @@ def search(source: dict, k: int, *, iters: int = 3,
             knobs={"k": int(k), "candidate": winner.name,
                    "kernel": plan.kernel, "fmt": plan.fmt,
                    "chunk": plan.chunk,
-                   "overlap_slabs": plan.overlap_slabs,
                    "feature_dtype": plan.feature_dtype,
                    "traffic_class": traffic_class},
             payload={"default_ms": default_ms, "margin": margin,

@@ -1,26 +1,14 @@
 """The discrete plan space + feasibility pruning (graft-tune).
 
 Candidates are the small set of configurations worth racing for one
-(structure, k): tier-split variants of the SELL fold (the
-``fold_tight`` / single-tier-ELL / HYB axes), chunking, the fused
-``pallas_sell`` kernel with its slab/SMEM/ring knobs, overlap ``S``,
-2.5D replication ``c``, and the carriage-dtype experiments (bf16,
-plus opt-in int8).
+(structure, k) on the one-chip fold: tier-split variants of the SELL
+fold (the ``fold_tight`` / single-tier-ELL axes), chunking, the fused
+``pallas_sell`` kernel with its slab/SMEM/ring knobs, and the
+carriage-dtype experiments (bf16, plus opt-in int8).
 
 Pruning happens BEFORE any child is spawned, with the models the repo
 already trusts:
 
-* the HBM certificate (``obs/memview.largest_fitting_repl`` over the
-  fingerprint's slot-count byte model) rejects replication factors
-  whose ×c footprint cannot fit the device budget
-  (``obs/comm.hbm_budget_bytes``);
-* divisibility (``c | k``, ``S | (k/c)``) rejects schedules the
-  column-group split cannot express — the same predicate
-  ``serve/scheduler.ExecConfig.accepts_k`` applies at admission;
-* the ``repl_predict_ms`` / ``exposed_comm_ms`` cost models screen
-  out candidates whose *modeled* step time is far beyond the default
-  configuration's model (3x slack — the models rank, the bench race
-  decides);
 * evaluator capability: the streaming pallas path needs
   ``k % 16 == 0`` on a real chip — read from the ONE predicate the
   kernel itself validates with
@@ -65,24 +53,9 @@ class Candidate:
     note: str = ""
 
 
-def predicted_operator_bytes(fp: dict, k: int,
-                             feature_itemsize: int = 4) -> int:
-    """Static footprint model from the fingerprint alone: packed SELL
-    slots (int32 cols + data unless binary) plus one carried feature
-    array — the number the HBM certificate multiplies by c."""
-    slots = int(sum(fp["ladder"]["slots"]))
-    rows = int(fp["total_rows"])
-    cols_b = slots * 4
-    data_b = 0 if fp["binary"] else slots * 4
-    deg_b = rows * 4 if fp["binary"] else 0
-    carriage = rows * int(k) * feature_itemsize
-    return cols_b + data_b + deg_b + carriage
-
-
 def enumerate_candidates(fp: dict, k: int, *,
                          platform: str = "cpu",
                          allow_int8: bool = False,
-                         budget_bytes: Optional[int] = None,
                          restrict: Optional[List[str]] = None,
                          traffic_class: str = "exact",
                          extra: Optional[List[Candidate]] = None,
@@ -121,9 +94,6 @@ def enumerate_candidates(fp: dict, k: int, *,
         raise ValueError(f"unknown traffic class {traffic_class!r} "
                          f"(expected one of {TRAFFIC_CLASSES})")
     approx = traffic_class == "approx"
-    from arrow_matrix_tpu.obs.comm import hbm_budget_bytes, repl_predict_ms
-    from arrow_matrix_tpu.obs.memview import largest_fitting_repl
-
     interpret = platform == "cpu"
     raw: List[Candidate] = [
         Candidate("default", note="the hand-tuned baseline; always "
@@ -138,9 +108,6 @@ def enumerate_candidates(fp: dict, k: int, *,
                   build={"fold_growth": 1e9, "fold_align": 1},
                   note="degenerate tier split: one ELL tier "
                        "(plus the zero-degree prefix)"),
-        Candidate("hyb",
-                  build={"fmt": "hyb"},
-                  note="split ELL+COO whole-level kernel"),
         Candidate("chunk_4096",
                   build={"chunk": 4096},
                   note="fixed gather chunk vs the auto budget"),
@@ -172,12 +139,6 @@ def enumerate_candidates(fp: dict, k: int, *,
                         "tolerance-gated winner" if approx else
                         "fused kernel, bf16 carriage diagnostic "
                         "(never f32 bit-identical; cannot win)")),
-        Candidate("overlap2",
-                  build={"overlap_slabs": 2},
-                  note="S=2 chunked overlap schedule"),
-        Candidate("repl2",
-                  build={"repl": 2},
-                  note="2.5D column groups, c=2"),
         Candidate("bf16",
                   build={"feature_dtype": "bf16"}, eligible=approx,
                   note=("bf16 carriage: approx-class candidate "
@@ -209,12 +170,6 @@ def enumerate_candidates(fp: dict, k: int, *,
     if extra:
         raw.extend(extra)
 
-    budget = hbm_budget_bytes(budget_bytes)
-    base_bytes = predicted_operator_bytes(fp, k)
-    # Modeled default step time: slots streamed once at the comm-model
-    # link rate — only used as the 3x cost-model screen's yardstick.
-    default_ms = repl_predict_ms(1, 0, compute_ms=0.0)
-
     lens_base = 0.0
     if lens_model is not None:
         from arrow_matrix_tpu.obs.costmodel import predict_candidate_ms
@@ -224,29 +179,6 @@ def enumerate_candidates(fp: dict, k: int, *,
     for c in raw:
         if restrict is not None and c.name not in restrict:
             pruned[c.name] = "not in restricted candidate set"
-            continue
-        repl = int(c.build.get("repl", 1))
-        slabs = int(c.build.get("overlap_slabs", 1))
-        if repl > 1:
-            if k % repl:
-                pruned[c.name] = (f"repl={repl} needs repl | k "
-                                  f"(k={k})")
-                continue
-            fit = largest_fitting_repl(base_bytes, budget,
-                                       choices=(1, repl))
-            if fit < repl:
-                pruned[c.name] = (
-                    f"HBM certificate: {base_bytes} B x{repl} exceeds "
-                    f"budget {budget} B")
-                continue
-            predicted = repl_predict_ms(repl, 0, compute_ms=default_ms)
-            if predicted > 3.0 * max(default_ms, 1e-9):
-                pruned[c.name] = (f"cost model: predicted "
-                                  f"{predicted:.3f} ms > 3x default")
-                continue
-        if slabs > 1 and (k // repl) % slabs:
-            pruned[c.name] = (f"overlap S={slabs} needs S | (k/c) "
-                              f"(k={k}, c={repl})")
             continue
         if c.build.get("kernel") == "pallas_sell":
             # The ONE streaming-gate predicate: the kernel's own

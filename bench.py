@@ -198,7 +198,7 @@ def _bench_config(platform: str) -> dict:
         cfg = dict(n=1 << 17, m=8, width=2048, k=16, iters=5, fmt="fold")
     elif cpu and os.environ.get("AMT_BENCH_FULL") != "1":
         # CPU rehearsal: full protocol scale, single known-best
-        # candidate (racing hyb/auto on one host CPU costs ~15 min for
+        # candidate (racing auto on one host CPU costs ~15 min for
         # numbers that only restate the fold win).
         cfg = dict(n=1 << 20, m=8, width=2048, k=16, iters=10,
                    fmt="fold")
@@ -222,15 +222,6 @@ def _bench_config(platform: str) -> dict:
     cfg["k128"] = (cfg["k"] != 128
                    and os.environ.get("AMT_BENCH_K128",
                                       k128_default) == "1")
-    # Chunked overlap schedule (graft-stream): S static feature
-    # sub-slabs per step so slab i+1's exchange overlaps slab i's
-    # compute.  1 = the serial baseline; must divide k.
-    cfg["overlap_slabs"] = max(
-        int(os.environ.get("AMT_BENCH_OVERLAP_SLABS", "1")), 1)
-    # 2.5D replication factor (graft-repl): fold candidates run the
-    # sequential column-group schedule (bit-identical by construction,
-    # column-separable SpMM); must divide k.  1 = unreplicated.
-    cfg["repl"] = max(int(os.environ.get("AMT_BENCH_REPL", "1")), 1)
     return cfg
 
 
@@ -286,14 +277,6 @@ def run_one_candidate(fmt: str) -> None:
     budget = device_memory_budget(jax.devices()[0])
 
     build_kwargs = dict(CANDIDATE_KWARGS.get(fmt, dict(fmt=fmt)))
-    slabs = max(int(cfg.get("overlap_slabs", 1)), 1)
-    if slabs > 1:
-        build_kwargs["overlap_slabs"] = slabs
-    # repl composes with the fold schedule only (MultiLevelArrow
-    # validates the same) — never silently attach it to hyb/auto.
-    repl = max(int(cfg.get("repl", 1)), 1)
-    if repl > 1 and build_kwargs.get("fmt") == "fold":
-        build_kwargs["repl"] = repl
     t0 = time.perf_counter()
     multi = MultiLevelArrow(levels, cfg["width"], mesh=None,
                             dense_budget=budget, **build_kwargs)
@@ -309,10 +292,6 @@ def run_one_candidate(fmt: str) -> None:
         # carries the host contention it was taken under.
         "host_load": host_load(),
     }
-    if slabs > 1:
-        out["overlap_slabs"] = slabs
-    if "repl" in build_kwargs:
-        out["repl"] = build_kwargs["repl"]
     if cfg.get("k128_run"):
         # Second headline feature width (the north-star metric names 16
         # AND 128 features; BASELINE configs 3/5 are k=128), measured
@@ -476,7 +455,7 @@ def race_candidates(result: dict, cfg: dict, finalize,
     a deadline alarm (or any crash) mid-race must not discard a
     headline number a finished candidate already earned."""
     if cfg["fmt"] == "auto":
-        candidates = ["fold", "fold_tight", "pallas_sell", "hyb", "auto"]
+        candidates = ["fold", "fold_tight", "pallas_sell", "auto"]
     else:
         # Comma list supported (race a subset without paying for the
         # known-slower formats); items are stripped, and an empty spec
@@ -504,10 +483,6 @@ def run_bench(result: dict, platform: str, device_kind: str) -> None:
                         "iterations": iters, "ba_neighbors": cfg["m"]}
     result["platform"] = platform
     result["device_kind"] = device_kind
-    if cfg["overlap_slabs"] > 1:
-        result["overlap_slabs"] = cfg["overlap_slabs"]
-    if cfg["repl"] > 1:
-        result["repl"] = cfg["repl"]
     # Measurement hygiene (VERDICT item 6): the committed line records
     # the host contention at race start — a loaded host explains an
     # anomalous CPU baseline or build time without re-running anything.
@@ -677,74 +652,6 @@ def run_bench(result: dict, platform: str, device_kind: str) -> None:
             result["k128_error"] = (rerun.get("k128_error")
                                     or rerun.get("error"))
 
-    # --- --overlap_slabs sweep (graft-stream): re-measure the winning
-    # format at each requested sub-slab count S, so the committed
-    # artifact carries the overlap-vs-serial curve (VERDICT item 5).  Each point is its own subprocess with its own timeout
-    # and correctness gate; one bad point costs only that point.
-    sweep_spec = os.environ.get("AMT_BENCH_OVERLAP_SWEEP", "")
-    if sweep_spec:
-        fmt_sweep = result.get("fmt_used") or "fold"
-        sweep = result["overlap_sweep"] = {"fmt": fmt_sweep}
-        for tok in sweep_spec.split(","):
-            tok = tok.strip()
-            if not tok:
-                continue
-            if not tok.isdigit() or int(tok) < 1:
-                sweep[tok] = {"error": "not a positive integer"}
-                continue
-            s = int(tok)
-            if k % s != 0:
-                sweep[str(s)] = {"error": f"S={s} does not divide k={k}"}
-                continue
-            _progress(f"overlap sweep: fmt={fmt_sweep} S={s}")
-            run = _spawn_candidate(
-                fmt_sweep, dict(cfg, overlap_slabs=s, k128=False),
-                timeout_s=900.0)
-            point = {kk: run[kk]
-                     for kk in ("ms", "err", "error", "host_load")
-                     if run.get(kk) is not None}
-            if ("err" in point and np.isfinite(point["err"])
-                    and point["err"] > tol):
-                point["gate_missed"] = tol
-            sweep[str(s)] = point
-
-    # --- --repl sweep (graft-repl): re-measure the winning fold-family
-    # format at each requested replication factor c.  On one chip the
-    # c-group column schedule is bit-identical by construction, so the
-    # sweep is the wall-clock cost curve of the 2.5D carve-up — the
-    # compute-side half of the T(c) model (the wire-side 1/c cut needs
-    # a mesh; dryrun_multichip's repl rung measures that one).  Same
-    # per-point subprocess/timeout/gate contract as the overlap sweep.
-    repl_spec = os.environ.get("AMT_BENCH_REPL_SWEEP", "")
-    if repl_spec:
-        fmt_sweep = result.get("fmt_used") or "fold"
-        if not str(fmt_sweep).startswith("fold"):
-            fmt_sweep = "fold"   # repl composes with the fold schedule
-        sweep = result["repl_sweep"] = {"fmt": fmt_sweep}
-        for tok in repl_spec.split(","):
-            tok = tok.strip()
-            if not tok:
-                continue
-            if not tok.isdigit() or int(tok) < 1:
-                sweep[tok] = {"error": "not a positive integer"}
-                continue
-            rc = int(tok)
-            if k % rc != 0:
-                sweep[str(rc)] = {"error": f"c={rc} does not divide "
-                                           f"k={k}"}
-                continue
-            _progress(f"repl sweep: fmt={fmt_sweep} c={rc}")
-            run = _spawn_candidate(
-                fmt_sweep, dict(cfg, repl=rc, k128=False),
-                timeout_s=900.0)
-            point = {kk: run[kk]
-                     for kk in ("ms", "err", "error", "host_load")
-                     if run.get(kk) is not None}
-            if ("err" in point and np.isfinite(point["err"])
-                    and point["err"] > tol):
-                point["gate_missed"] = tol
-            sweep[str(rc)] = point
-
 
 # Ordered most-informative-first: the total budget may cut the tail,
 # and the gather-family variants are cheap (small uploads, fast
@@ -759,7 +666,6 @@ COMPARE_VARIANTS = {
     # gathered row — the amortization lever where the gather turns
     # bandwidth-bound (k=128); outside the f32 gate, diagnostics only.
     "fold_featbf16": dict(fmt="fold", feature_dtype="bf16"),
-    "hyb": dict(fmt="hyb"),
     "ell": dict(fmt="ell"),               # platform-aware auto head
     # Head-stack kernel isolation: flat-COO head = scatter-add (TPU
     # scatters serialize), ELL/gell heads = gather + reduce.  The
@@ -864,27 +770,6 @@ def main() -> None:
     if len(sys.argv) == 3 and sys.argv[1] == "--candidate":
         run_one_candidate(sys.argv[2])
         return
-    # --overlap_slabs 1,2,4: sweep the winning format over the listed
-    # sub-slab counts after the race (graft-stream).  Threaded through
-    # the environment so candidate subprocesses and tests share one
-    # spelling (AMT_BENCH_OVERLAP_SWEEP works without the flag).
-    if "--overlap_slabs" in sys.argv:
-        i = sys.argv.index("--overlap_slabs")
-        if i + 1 >= len(sys.argv):
-            print("--overlap_slabs needs a comma list, e.g. 1,2,4",
-                  file=sys.stderr)
-            raise SystemExit(2)
-        os.environ["AMT_BENCH_OVERLAP_SWEEP"] = sys.argv[i + 1]
-    # --repl 1,2,4: sweep the winning fold format over the listed 2.5D
-    # replication factors after the race (graft-repl) — same env
-    # threading as the overlap sweep.
-    if "--repl" in sys.argv:
-        i = sys.argv.index("--repl")
-        if i + 1 >= len(sys.argv):
-            print("--repl needs a comma list, e.g. 1,2,4",
-                  file=sys.stderr)
-            raise SystemExit(2)
-        os.environ["AMT_BENCH_REPL_SWEEP"] = sys.argv[i + 1]
     # Deadline alarm: the parent spends its time in subprocess waits
     # (interruptible), so SIGALRM fires reliably here even while a
     # child is stuck inside native code.  AMT_BENCH_DEADLINE=0

@@ -102,15 +102,13 @@ def test_failed_race_exits_nonzero_with_error_json(tmp_path):
 
 def test_bench_config_overlap_and_pallas_sell_candidate(monkeypatch):
     """graft-stream bench surface: the pallas_sell race candidate
-    exists (fold build + fused kernel), and AMT_BENCH_OVERLAP_SLABS
-    threads the static slab count into the candidate config."""
+    exists (fold build + fused kernel).  The one-chip fold takes no
+    overlap slabs, so the candidate config carries none."""
     sys.path.insert(0, REPO)
     import bench as bench_mod
 
     kw = bench_mod.CANDIDATE_KWARGS["pallas_sell"]
     assert kw["fmt"] == "fold" and kw["kernel"] == "pallas_sell"
+    assert "overlap_slabs" not in kw
     monkeypatch.setenv("AMT_BENCH_OVERLAP_SLABS", "4")
-    cfg = bench_mod._bench_config("cpu")
-    assert cfg["overlap_slabs"] == 4
-    monkeypatch.delenv("AMT_BENCH_OVERLAP_SLABS")
-    assert bench_mod._bench_config("cpu")["overlap_slabs"] == 1
+    assert "overlap_slabs" not in bench_mod._bench_config("cpu")

@@ -83,30 +83,6 @@ def test_multi_level_a2a_overlap_bit_identical(mesh, problem, s):
     np.testing.assert_array_equal(got, ref)
 
 
-def test_fold_overlap_bit_identical_and_pallas_sell(problem):
-    """Single-chip fold: the overlap split slices the feature-major
-    carriage — bit-identical under the XLA kernel; the fused
-    pallas_sell kernel composes with the split within the numerics
-    gate (different accumulation order is allowed across KERNELS,
-    never across S)."""
-    from arrow_matrix_tpu.utils import numerics
-
-    levels, x = problem
-    base = MultiLevelArrow(levels, 64, mesh=None, fmt="fold")
-    ref = np.asarray(base.gather_result(base.step(base.set_features(x))))
-    f2 = MultiLevelArrow(levels, 64, mesh=None, fmt="fold",
-                         overlap_slabs=2)
-    got = np.asarray(f2.gather_result(f2.step(f2.set_features(x))))
-    np.testing.assert_array_equal(got, ref)
-
-    fp = MultiLevelArrow(levels, 64, mesh=None, fmt="fold",
-                         kernel="pallas_sell", overlap_slabs=2)
-    gotp = np.asarray(fp.gather_result(fp.step(fp.set_features(x))))
-    nnz = sum(int(lvl.matrix.nnz) for lvl in levels)
-    err = numerics.relative_error(gotp, ref)
-    assert err <= numerics.relative_tolerance(nnz / max(len(ref), 1))
-
-
 def test_overlap_zero_recompiles(mesh, problem):
     """S is a static schedule: iterating the overlapped step must not
     recompile (the recompile audit is the acceptance gate — a dynamic

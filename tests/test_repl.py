@@ -10,8 +10,9 @@ to the arrow decomposition):
     measured collective bytes divided by EXACTLY c (each replica
     group runs the identical exchange program on a static k/c
     feature slab);
-  * the single-chip ``fold`` column-group schedule (``repl=c`` with
-    ``mesh=None``) is bit-identical by construction at zero comm;
+  * replication is the mesh executors' (``repl_axis``):
+    ``MultiLevelArrow`` takes no ``repl`` (one chip has no exchange to
+    cut);
   * validation — c must divide the device count and the feature
     width, ``repl_axis`` composes with ``feat_axis=None`` and
     ``routing="a2a"`` only (the GSPMD gather lowering assumes a
@@ -191,31 +192,19 @@ def problem():
     return levels, x
 
 
-def test_fold_repl_bit_identical(problem):
-    """The single-chip column-group schedule: repl=c sweeps c static
-    k/c slabs through the same fold step — column-separable SpMM, so
-    bit-identical to repl=1 at every c."""
-    levels, x = problem
-    want = decomposition_spmm(levels, x)
-    base = None
-    for c in (1, 2, 4):
-        ml = MultiLevelArrow(levels, 32, mesh=None, fmt="fold", repl=c)
-        got = ml.gather_result(ml.step(ml.set_features(x)))
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-        if base is None:
-            base = got
-        assert np.array_equal(got, base), f"fold repl c={c} diverged"
-
-
 def test_fold_repl_validation(problem):
+    """MultiLevelArrow has no replica groups: the fold refuses ``repl``
+    outright, one chip or mesh, any format (the 2.5D executors are
+    SellMultiLevel/SellSlim on a ``make_repl_mesh`` mesh)."""
     levels, _ = problem
-    with pytest.raises(ValueError, match="fold"):
+    for c in (0, 1, 2):
+        with pytest.raises(TypeError, match="repl"):
+            MultiLevelArrow(levels, 32, mesh=None, fmt="fold", repl=c)
+    with pytest.raises(TypeError, match="repl"):
         MultiLevelArrow(levels, 32, mesh=None, fmt="ell", repl=2)
-    with pytest.raises(ValueError, match="mesh"):
+    with pytest.raises(TypeError, match="repl"):
         MultiLevelArrow(levels, 32, mesh=make_mesh((4,), ("blocks",)),
                         fmt="ell", repl=2)
-    with pytest.raises(ValueError, match=">= 1"):
-        MultiLevelArrow(levels, 32, mesh=None, fmt="fold", repl=0)
 
 
 def test_sell_repl_same_B_bit_identical_and_bytes_div_c(problem):
@@ -383,19 +372,16 @@ def test_account_collectives_defaults_carry_repl_fields():
 
 
 def test_dryrun_repl_rung_enforces_contract(monkeypatch):
-    """The scale-ladder repl rung at logic-validation size: fold and
-    sell ladders both bit-identical at every c, sell bytes exactly
-    ÷c, plus the 8-device c=1 production reference."""
+    """The scale-ladder repl rung at logic-validation size: the sell
+    ladder bit-identical at every c, its bytes exactly ÷c, plus the
+    8-device c=1 production reference."""
     import __graft_entry__ as ge
 
     monkeypatch.setenv("AMT_DRYRUN_MID_LOGN", "11")
     out = ge.dryrun_multichip(8, scale="repl")
     assert out["scale"] == "repl" and out["B"] == 2
-    fold = out["algorithms"]["fold_repl"]
     sell = out["algorithms"]["sell_a2a_repl"]
     for c in ("1", "2", "4"):
-        assert fold[c]["bit_identical_to_c1"]
-        assert fold[c]["measured_bytes"] == 0
         assert sell[c]["bit_identical_to_c1"]
     b1 = sell["1"]["measured_bytes"]
     assert sell["2"]["measured_bytes"] * 2 == b1

@@ -25,7 +25,7 @@ def spmm_via_sell(a, x, **kw):
     sell, order = sell_from_csr(a, **kw)
     y = x[order] if x.shape[0] == sell.n_rows else None
     assert y is not None
-    out_sorted = np.asarray(sell_spmm_t(sell, jnp.asarray(y.T)))
+    out_sorted = np.asarray(_jit_spmm(sell, jnp.asarray(y.T)))
     out = np.empty_like(out_sorted.T)
     out[order] = out_sorted.T
     return out, sell
@@ -174,8 +174,8 @@ def test_sell_pad_rows_and_budget_chunking():
     x = random_dense(128, 4, seed=3)
     y = x[order]
     # Tiny budget forces slot chunking inside every tier.
-    out_sorted = np.asarray(sell_spmm_t(sell, jnp.asarray(y.T),
-                                        gather_budget=1 << 12))
+    out_sorted = np.asarray(_jit_spmm(sell, jnp.asarray(y.T),
+                                      gather_budget=1 << 12))
     out = np.empty_like(x)
     out[order] = out_sorted.T
     np.testing.assert_allclose(out[:100], a @ x[:100], rtol=1e-5, atol=1e-5)
@@ -284,8 +284,11 @@ def _packed_case(binary: bool) -> sparse.csr_matrix:
     return a
 
 
-def _sorted_spmm(sell, xt, chunk):
-    return np.asarray(sell_spmm_t(sell, xt, chunk=chunk), dtype=np.float32)
+def _jit_spmm(sell, xt, **kw):
+    """``sell_spmm_t`` through a fresh ``jax.jit``: traced anew on each
+    call, so trace-time gauges and monkeypatches still apply, and run
+    as one program (op-by-op dispatch costs seconds a case)."""
+    return jax.jit(lambda s, v: sell_spmm_t(s, v, **kw))(sell, xt)
 
 
 @pytest.mark.parametrize("chunk", [None, 1, 8])
@@ -304,16 +307,27 @@ def test_lane_packed_gather(monkeypatch, registry, k, dtype, binary, chunk):
     x = random_dense(a.shape[0], k, seed=k)
     xt = jnp.asarray(x[order].T, dtype=dtype)
 
-    jaxpr = str(jax.make_jaxpr(lambda v: sell_spmm_t(sell, v, chunk=chunk))(xt))
+    # One program holds both steps, traced in turn: the lane-packed
+    # gather, then the k-wide one with packing patched off — one trace
+    # and one compile a case, where eager dispatch cost seconds.
+    gauges = []
+
+    def both(s, v):
+        got = sell_spmm_t(s, v, chunk=chunk)
+        gauges.append(registry.gauge("sell.packed_slots").value)
+        monkeypatch.setattr(ell, "lane_pack_factor", lambda k: 1)
+        today = sell_spmm_t(s, v, chunk=chunk)
+        gauges.append(registry.gauge("sell.packed_slots").value)
+        return got, today
+
+    traced = jax.jit(both).trace(sell, xt)
+    jaxpr = str(traced.jaxpr)
     assert PACKED_GATHER in jaxpr                     # whole-row gathers
     # The select must not round f32 to bf16 on the chip's MXU.
     assert "precision=(Precision.HIGHEST, Precision.HIGHEST)" in jaxpr
-    assert registry.gauge("sell.packed_slots").value == sell.n_slots
-    got = _sorted_spmm(sell, xt, chunk)
-
-    monkeypatch.setattr(ell, "lane_pack_factor", lambda k: 1)
-    today = _sorted_spmm(sell, xt, chunk)
-    assert registry.gauge("sell.packed_slots").value == 0
+    assert gauges == [sell.n_slots, 0]
+    got, today = (np.asarray(r, dtype=np.float32)
+                  for r in traced.lower().compile()(sell, xt))
 
     # The float64 product of the carried (possibly bf16-rounded) x, in
     # the sorted coordinates the operator runs in.
@@ -336,7 +350,7 @@ def test_lane_pack_needs_k_dividing_the_tile(registry, k):
     assert PACKED_GATHER not in jaxpr and "dot_general" not in jaxpr
     assert registry.gauge("sell.packed_slots").value == 0
     out = np.empty_like(x)
-    out[order] = np.asarray(sell_spmm_t(sell, xt)).T
+    out[order] = np.asarray(_jit_spmm(sell, xt)).T
     want = a @ x
     assert np.abs(out - want).max() <= 1e-5 * np.abs(want).max()
 
@@ -359,6 +373,28 @@ def test_packed_tier_chunks_as_at_k128(k):
         deg=(jax.ShapeDtypeStruct((4, rows), jnp.int32),))
     assert (tier_chunks(stack, k, 4, budget)
             == tier_chunks(stack, 128, 4, budget) == [(m, rows, 1)])
+
+
+@pytest.mark.parametrize("k", [16, 128])
+def test_fold_step_gathers_packed_only_below_the_tile(registry, k):
+    """The one-chip fold's step program runs the whole carriage through
+    ``sell_spmm_t``: at k=16 every tier gathers lane-packed rows, at
+    k=128 none does (a split of the features would silently switch a
+    k=128 step onto the packed form and gather twice the rows)."""
+    from arrow_matrix_tpu.decomposition import arrow_decomposition
+    from arrow_matrix_tpu.parallel import MultiLevelArrow
+
+    a = barabasi_albert(480, 6, seed=19)
+    levels = arrow_decomposition(a, 32, max_levels=3, block_diagonal=True,
+                                 seed=2)
+    ml = MultiLevelArrow(levels, 32, mesh=None, fmt="fold")
+    xd = ml.set_features(random_dense(480, k, seed=1))
+    jaxpr = str(jax.make_jaxpr(ml.step_fn)(xd, *ml.step_operands()))
+    slots = ml.blocks[0].n_slots
+    assert registry.gauge("sell.slots").value == slots
+    assert registry.gauge("sell.packed_slots").value == (
+        slots if k < 128 else 0)
+    assert (PACKED_GATHER in jaxpr) == (k < 128)
 
 
 # -- a chunked tier's last partial chunk ---------------------------------
@@ -398,8 +434,10 @@ def test_chunk_tail_matches_unchunked(form, m, chunk):
         lambda v: ell.ell_spmm_t(x_t=v, chunk=chunk, **kw))(x_t))
     assert "pad[" not in jaxpr
     assert (PACKED_GATHER in jaxpr) == (form == "packed")
-    got = np.asarray(ell.ell_spmm_t(x_t=x_t, chunk=chunk, **kw))
-    want = np.asarray(ell.ell_spmm_t(x_t=x_t, chunk=None, **kw))
+    got = np.asarray(jax.jit(
+        lambda v: ell.ell_spmm_t(x_t=v, chunk=chunk, **kw))(x_t))
+    want = np.asarray(jax.jit(
+        lambda v: ell.ell_spmm_t(x_t=v, chunk=None, **kw))(x_t))
     np.testing.assert_allclose(got, want, rtol=4e-6, atol=1e-6)
     w = (np.asarray(kw["data"]) if "data" in kw else
          (np.arange(m)[:, None] < np.asarray(kw["deg"])[None, :]))
@@ -441,7 +479,7 @@ def test_gathered_slots_gauge_counts_no_padding(registry, k, chunk):
     x = random_dense(a.shape[0], k, seed=4)
     out = np.empty_like(x)
     out[order] = np.asarray(
-        sell_spmm_t(sell, jnp.asarray(x[order].T), chunk=chunk)).T
+        _jit_spmm(sell, jnp.asarray(x[order].T), chunk=chunk)).T
     assert (registry.gauge("sell.gathered_slots").value
             == registry.gauge("sell.slots").value == sell.n_slots)
     want = a @ x

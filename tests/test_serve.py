@@ -246,32 +246,33 @@ def test_worker_thread_mode_completes(factory):
 
 def test_degradation_ladder_order():
     ladder = degradation_ladder(
-        ExecConfig(kernel="pallas_sell", repl=2, overlap_slabs=2))
-    assert [(c.kernel, c.repl, c.overlap_slabs) for c in ladder] == [
-        ("pallas_sell", 2, 2), ("xla", 2, 2), ("xla", 1, 2),
-        ("xla", 1, 1)]
+        ExecConfig(kernel="pallas_sell", overlap_slabs=2))
+    assert [(c.kernel, c.overlap_slabs) for c in ladder] == [
+        ("pallas_sell", 2), ("xla", 2), ("xla", 1)]
     assert degradation_ladder(ExecConfig()) == (ExecConfig(),)
+    assert not hasattr(ExecConfig(), "repl")
 
 
 def test_exec_config_divisibility():
-    cfg = ExecConfig(repl=2, overlap_slabs=2)
-    assert cfg.accepts_k(4) and cfg.accepts_k(8)
-    assert not cfg.accepts_k(2)   # S=2 does not divide k/c = 1
-    assert not cfg.accepts_k(3)   # c=2 does not divide 3
+    cfg = ExecConfig(overlap_slabs=2)
+    assert cfg.accepts_k(4) and cfg.accepts_k(2)
+    assert not cfg.accepts_k(3)   # S=2 does not divide 3
+    assert not cfg.accepts_k(0)
     assert ExecConfig().accepts_k(1)
 
 
 def test_repeated_faults_degrade_then_complete(factory):
     """Retries exhausted on the base rung: the tenant degrades one
-    rung (overlap S=2 -> 1) and the request completes there,
-    bit-identical — only a terminal-rung tenant can fail."""
+    rung (pallas_sell -> xla, the one-chip fold's rung) and the
+    request completes there, bit-identical — only a terminal-rung
+    tenant can fail."""
     fac, n_rows = factory
     trace = _trace(n_rows, requests=1, tenants=1)
     _, ref = _serve(factory, _trace(n_rows, requests=1, tenants=1),
-                    base_config=ExecConfig(overlap_slabs=2))
+                    base_config=ExecConfig(kernel="pallas_sell"))
     faults.set_plan({"scenario": "error", "site": "multi_level.step",
                      "after": 0, "count": 2})
-    srv = ArrowServer(fac, ExecConfig(overlap_slabs=2),
+    srv = ArrowServer(fac, ExecConfig(kernel="pallas_sell"),
                       policy=RetryPolicy(max_retries=1,
                                          backoff_s=0.001),
                       degrade_after=1)
@@ -282,8 +283,7 @@ def test_repeated_faults_degrade_then_complete(factory):
     assert s["faults_seen"] >= 2
     tenant = s["tenants"][trace[0].tenant]
     assert tenant["rung"] == 1
-    assert tenant["config"] == {"kernel": "xla", "repl": 1,
-                                "overlap_slabs": 1,
+    assert tenant["config"] == {"kernel": "xla", "overlap_slabs": 1,
                                 "feature_dtype": None}
     assert tenant["degradations"]
     assert tickets[0].result.tobytes() == ref[0].result.tobytes()

@@ -1,6 +1,6 @@
 """One-process format sweep on the live chip at protocol scale.
 
-Races the single-chip execution configs (auto=ELL+platform heads, hyb,
+Races the single-chip execution configs (fold, auto=ELL+platform heads,
 and optionally dense/bf16 when they fit) over one cached decomposition,
 printing ms/iter per config — the data that decides bench.py's default
 format.  Run on the chip:
@@ -46,11 +46,9 @@ def main() -> None:
 
     configs = {
         "fold": dict(fmt="fold"),
-        "hyb": dict(fmt="hyb"),
         "auto": dict(fmt="auto"),
         "ell_headflat": dict(fmt="ell", head_fmt="flat"),
         "ell_headgell": dict(fmt="ell", head_fmt="gell"),
-        "hyb_bf16": dict(fmt="hyb", dtype="bf16"),
     }
     for name, kw in configs.items():
         try:

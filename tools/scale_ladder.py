@@ -402,7 +402,7 @@ def rung_dryrun_multichip_mid() -> dict:
 
 
 def rung_dryrun_repl_sweep() -> dict:
-    """2.5D replication sweep (graft-repl): fold + fixed-B sell-a2a at
+    """2.5D replication sweep (graft-repl): fixed-B sell-a2a at
     c in {1,2,4} on an 8-device virtual CPU mesh, enforcing the honest
     contract — bit-identical results at every c and measured wire
     bytes exactly 1/c — plus the 8-device c=1 production reference.

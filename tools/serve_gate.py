@@ -255,10 +255,11 @@ def scenario_slo_burn_degrade(factory, n_rows):
     from arrow_matrix_tpu.obs import flight, pulse
     from arrow_matrix_tpu.serve import ArrowServer, ExecConfig
 
-    # overlap_slabs=2 gives the ladder a second rung (-> overlap 1)
-    # that accepts the same K, so a forced degradation has somewhere
-    # to land without changing kernels.
-    base_cfg = ExecConfig(overlap_slabs=2)
+    # The fused pallas_sell kernel gives the ladder a second rung
+    # (-> xla) that accepts the same K, so a forced degradation has
+    # somewhere to land; at this size both kernels sum every slot in
+    # the same order, so the results stay bit-identical.
+    base_cfg = ExecConfig(kernel="pallas_sell")
 
     def one_pass(inject):
         now = [0.0]
@@ -294,7 +295,7 @@ def scenario_slo_burn_degrade(factory, n_rows):
     ref_srv, _, ref_tickets = one_pass(inject=False)
     if ref_srv.summary()["completed"] != REQUESTS:
         return ["slo_burn_degrade: fault-free reference run on the "
-                "overlap base rung did not complete every request"]
+                "pallas_sell base rung did not complete every request"]
     ref = _result_bytes(ref_tickets)
 
     srv, mon, tickets = one_pass(inject=True)
@@ -335,10 +336,10 @@ def scenario_slo_burn_degrade(factory, n_rows):
     else:
         name, d = burn_hits[0]
         if s["tenants"][name]["rung"] != 1 \
-                or d["to"]["overlap_slabs"] != 1:
+                or d["to"]["kernel"] != "xla":
             problems.append(
                 f"slo_burn_degrade: tenant {name} should sit on rung "
-                f"1 (overlap_slabs=1), got rung "
+                f"1 (kernel xla), got rung "
                 f"{s['tenants'][name]['rung']} -> {d['to']}")
 
     if _result_bytes(tickets) != ref:
